@@ -1,8 +1,10 @@
 """Paper Fig. 17: multi-device scaling — instance-parallel AND graph-sharded.
 
-Runs subprocesses with ``--xla_force_host_platform_device_count=N`` so the
-parent process keeps its single-device view (per the dry-run isolation
-rule).  Wall-clock on shared host cores is not a throughput claim — the
+Off the TPU each point runs in a subprocess with
+``--xla_force_host_platform_device_count=N`` so the parent process keeps
+its single-device view; on the TPU it runs in this process on the chips it
+holds, since a child could not reach a chip this process has taken.
+Wall-clock on shared host cores is not a throughput claim — the
 host devices time-slice the same physical cores — so the reported figures
 are the *work and memory distribution*: instances per device for the
 zero-comm instance-parallel mode, and per-device CSR bytes (∝ 1/D) plus
@@ -14,6 +16,7 @@ must fall with D while the drain keeps walking the full pl50k edge set.
 from __future__ import annotations
 
 import json
+import os
 import pathlib
 import subprocess
 import sys
@@ -22,99 +25,130 @@ import jax
 
 from benchmarks.common import row
 
-OUT_PATH = pathlib.Path(__file__).resolve().parents[1] / "BENCH_shard.json"
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+OUT_PATH = ROOT / "BENCH_shard.json"
 
+def _instance_parallel(n: int) -> dict:
+    """Zero-collective instance-parallel walk on an ``n``-device mesh."""
+    import time
+
+    from repro.core import algorithms as alg
+    from repro.core.distributed import instance_parallel_walk
+    from repro.graph import powerlaw_graph
+
+    g = powerlaw_graph(20000, exponent=2.1, seed=7, weighted=True)
+    mesh = jax.make_mesh((n,), ("data",), devices=jax.devices()[:n])
+    key = jax.random.PRNGKey(0)
+    seeds = jax.random.randint(key, (4096,), 0, g.num_vertices)
+    md = min(g.max_degree(), 512)
+    run = lambda: instance_parallel_walk(mesh, g, seeds, key, depth=32,  # noqa: E731
+                                         spec=alg.biased_random_walk(), max_degree=md)
+    jax.block_until_ready(run().walks)
+    t0 = time.perf_counter()
+    res = run()
+    jax.block_until_ready(res.walks)
+    secs = time.perf_counter() - t0
+    return {"devices": n, "secs": secs, "edges": int(res.sampled_edges)}
+
+
+def _sharded(n: int, v: int, hub_bytes) -> dict:
+    """Owner-routed sharded walk on an ``n``-device mesh over a ``v``-vertex
+    power-law graph (``hub_bytes``: None = default hub budget, 0 = off)."""
+    import time
+
+    from repro.core import algorithms as alg
+    from repro.core import backend as bk
+    from repro.graph import powerlaw_graph
+    from repro.graph.partition import PartitionMap, partition_by_vertex_range
+    from repro.shard import sharded_random_walk
+
+    g = powerlaw_graph(v, exponent=2.1, seed=7, weighted=True)  # 50000 = BENCH_GRAPHS["pl50k"]
+    mesh = jax.make_mesh((n,), ("data",), devices=jax.devices()[:n])
+    key = jax.random.PRNGKey(0)
+    seeds = jax.random.randint(key, (2048,), 0, g.num_vertices)
+    md = g.max_degree()
+    # what one device holds: compact local-id CSR + aligned global-id edge
+    # array — same layout arithmetic sharded_random_walk stages
+    seg_big = max(bk.walk_bucket_plan(md)[0])
+    pm = PartitionMap.create(g.num_vertices, n)
+    parts = partition_by_vertex_range(g, n)
+    pad_e = max((p.edge_lo % seg_big) + p.num_edges for p in parts)
+    # indptr + 4 edge arrays: local ids, global ids, weights, and the sliced
+    # flat bias (the benchmarked spec is flat-bias; window mode ships 3)
+    bytes_per_device = 4 * ((pm.range_size + 2) + 4 * pad_e)
+    run = lambda: sharded_random_walk(mesh, g, seeds, key, depth=32,  # noqa: E731
+                                      spec=alg.biased_random_walk(), max_degree=md,
+                                      hub_bytes=hub_bytes)
+    jax.block_until_ready(run().walks)  # compile + first drain
+    t0 = time.perf_counter()
+    res = run()
+    jax.block_until_ready(res.walks)
+    secs = time.perf_counter() - t0
+    st = res.stats or {}
+    return {"devices": n, "secs": secs, "edges": int(res.sampled_edges),
+            "bytes_per_device": int(bytes_per_device),
+            "local_edges_max": int(pad_e), "total_edges": int(g.num_edges),
+            "exchanged_entries": int(st.get("exchanged_entries", 0)),
+            "exchange_bytes": int(st.get("exchange_bytes", 0)),
+            "hub_hops": int(st.get("hub_hops", 0)),
+            "num_hubs": int(st.get("num_hubs", 0)),
+            "hub_replicated_edges": int(st.get("hub_replicated_edges", 0))}
+
+
+# a child process gets n forced host devices: XLA_FLAGS must be set before
+# the child first imports jax
 _CHILD = r"""
-import os, sys, json, time
+import os, sys, json
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%d"
-import jax, jax.numpy as jnp
-from repro.graph import powerlaw_graph
-from repro.core import algorithms as alg
-from repro.core.distributed import instance_parallel_walk
-
-n = %d
-g = powerlaw_graph(20000, exponent=2.1, seed=7, weighted=True)
-mesh = jax.make_mesh((n,), ("data",))
-key = jax.random.PRNGKey(0)
-seeds = jax.random.randint(key, (4096,), 0, g.num_vertices)
-md = min(g.max_degree(), 512)
-run = lambda: instance_parallel_walk(mesh, g, seeds, key, depth=32,
-                                     spec=alg.biased_random_walk(), max_degree=md)
-jax.block_until_ready(run().walks)
-t0 = time.perf_counter()
-res = run()
-jax.block_until_ready(res.walks)
-secs = time.perf_counter() - t0
-print(json.dumps({"devices": n, "secs": secs, "edges": int(res.sampled_edges)}))
-"""
-
-_CHILD_SHARDED = r"""
-import os, sys, json, time
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%d"
-import jax, jax.numpy as jnp, numpy as np
-from repro.graph import powerlaw_graph
-from repro.core import algorithms as alg
-from repro.graph.partition import PartitionMap, partition_by_vertex_range
-from repro.shard import sharded_random_walk
-
-n = %d
-g = powerlaw_graph(%d, exponent=2.1, seed=7, weighted=True)  # 50000 = BENCH_GRAPHS["pl50k"]
-hub_bytes = %s  # None = default degree-aware hub budget, 0 = hubs off
-mesh = jax.make_mesh((n,), ("data",))
-key = jax.random.PRNGKey(0)
-seeds = jax.random.randint(key, (2048,), 0, g.num_vertices)
-md = g.max_degree()
-# what one device holds: compact local-id CSR + aligned global-id edge
-# array — same layout arithmetic sharded_random_walk stages
-from repro.core import backend as bk
-seg_big = max(bk.walk_bucket_plan(md)[0])
-pm = PartitionMap.create(g.num_vertices, n)
-parts = partition_by_vertex_range(g, n)
-pad_e = max((p.edge_lo %% seg_big) + p.num_edges for p in parts)
-# indptr + 4 edge arrays: local ids, global ids, weights, and the sliced
-# flat bias (the benchmarked spec is flat-bias; window mode ships 3)
-bytes_per_device = 4 * ((pm.range_size + 2) + 4 * pad_e)
-run = lambda: sharded_random_walk(mesh, g, seeds, key, depth=32,
-                                  spec=alg.biased_random_walk(), max_degree=md,
-                                  hub_bytes=hub_bytes)
-jax.block_until_ready(run().walks)  # compile + first drain
-t0 = time.perf_counter()
-res = run()
-jax.block_until_ready(res.walks)
-secs = time.perf_counter() - t0
-st = res.stats or {}
-print(json.dumps({"devices": n, "secs": secs, "edges": int(res.sampled_edges),
-                  "bytes_per_device": int(bytes_per_device),
-                  "local_edges_max": int(pad_e), "total_edges": int(g.num_edges),
-                  "exchanged_entries": int(st.get("exchanged_entries", 0)),
-                  "exchange_bytes": int(st.get("exchange_bytes", 0)),
-                  "hub_hops": int(st.get("hub_hops", 0)),
-                  "num_hubs": int(st.get("num_hubs", 0)),
-                  "hub_replicated_edges": int(st.get("hub_replicated_edges", 0))}))
+from benchmarks.fig17_scaling import %s
+print(json.dumps(%s(*%r)))
 """
 
 
-def _child(code: str, timeout: int = 1800) -> dict:
+def _measure(fn, *args, timeout: int = 1800) -> dict:
+    """``fn(n, ...)`` on ``n`` devices.  On the TPU it runs in this process
+    on the chips it holds (a child could not reach them); elsewhere in a
+    child with ``n`` forced host devices, so this process keeps its
+    single-device view."""
+    n = args[0]
+    if jax.default_backend() == "tpu":
+        if len(jax.devices()) < n:  # run() asks only for what _device_counts allows
+            raise RuntimeError(f"fig17 needs {n} devices, this host has {len(jax.devices())}")
+        return fn(*args)
+    code = _CHILD % (max(n, 1), fn.__name__, fn.__name__, args)
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [str(ROOT), str(ROOT / "src")] + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else [])
+    )
     out = subprocess.run(
-        [sys.executable, "-c", code], capture_output=True, text=True, timeout=timeout
+        [sys.executable, "-c", code], capture_output=True, text=True, timeout=timeout,
+        cwd=ROOT, env=env,
     )
     if out.returncode != 0:
         raise RuntimeError(out.stderr[-2000:])
     return json.loads(out.stdout.strip().splitlines()[-1])
 
 
+def _device_counts(wanted) -> tuple:
+    """The device counts of ``wanted`` this host can measure: all of them off
+    the TPU (forced host devices), only up to the chips it holds on it."""
+    if jax.default_backend() != "tpu":
+        return tuple(wanted)
+    return tuple(n for n in wanted if n <= len(jax.devices()))
+
+
 def run() -> list[str]:
     rows = []
-    for n in (1, 2, 4):
-        d = _child(_CHILD % (n, n), timeout=900)
+    for n in _device_counts((1, 2, 4)):
+        d = _measure(_instance_parallel, n, timeout=900)
         rows.append(row(
             f"fig17/devices={n}", d["secs"] * 1e6,
             f"SEPS={d['edges']/d['secs']:.3e};inst_per_dev={4096//n}",
         ))
 
     results = []
-    for n in (1, 2, 4, 8):
-        d = _child(_CHILD_SHARDED % (max(n, 1), n, 50000, "None"))
+    for n in _device_counts((1, 2, 4, 8)):
+        d = _measure(_sharded, n, 50000, None)
         rows.append(row(
             f"fig17/sharded_devices={n}", d["secs"] * 1e6,
             f"SEPS={d['edges']/d['secs']:.3e};"
@@ -139,8 +173,8 @@ def run() -> list[str]:
     # region disabled (hub_bytes=0) — exchange bytes must be measurably
     # higher without replication at the shard counts where it matters
     hub_replication = []
-    for n in (4, 8):
-        d0 = _child(_CHILD_SHARDED % (n, n, 50000, "0"))
+    for n in _device_counts((4, 8)):
+        d0 = _measure(_sharded, n, 50000, 0)
         dh = next(r for r in results if r["devices"] == n)
         rows.append(row(
             f"fig17/hub_ablation D={n}", d0["secs"] * 1e6,
@@ -164,8 +198,10 @@ def run() -> list[str]:
     # per-shard drain cost — it must stay flat while total edges explode
     # (the replicated-psum design's per-step cost grows with full V instead).
     const_shard = []
-    for v, n in ((12500, 1), (25000, 2), (50000, 4), (100000, 8)):
-        d = _child(_CHILD_SHARDED % (max(n, 1), n, v, "None"))
+    sweep = ((12500, 1), (25000, 2), (50000, 4), (100000, 8))
+    counts = _device_counts(n for _, n in sweep)
+    for v, n in (p for p in sweep if p[1] in counts):
+        d = _measure(_sharded, n, v, None)
         per_shard = d["secs"] / n
         rows.append(row(
             f"fig17/const_shard V={v} D={n}", d["secs"] * 1e6,
@@ -232,12 +268,16 @@ def check(payload: dict) -> list[str]:
     - device-sweep ``sampled_edges_per_s`` keeps at least ``SEPS_STEP_TOL``
       per doubling (cliff-guard only — host wall time is noisy by design);
     - hub replication strictly reduces exchange bytes at D=4/8.
+
+    Each gate reads only the device counts the payload holds: a host with
+    fewer chips than a gate's D records (and is held to) fewer points.
     """
     problems: list[str] = []
     cs = {r["devices"]: r["seconds_per_shard"] for r in payload["constant_shard_scaling"]}
     base = cs.get(2)
     if base is None:
-        problems.append("constant_shard_scaling has no D=2 baseline")
+        if any(dv > 2 for dv in cs):
+            problems.append("constant_shard_scaling has no D=2 baseline")
     else:
         for dv in sorted(d for d in cs if d >= 2):
             if cs[dv] > CONST_SHARD_TOL * base:

@@ -7,6 +7,7 @@ from __future__ import annotations
 
 import argparse
 import sys
+from pathlib import Path
 import traceback
 
 
@@ -15,6 +16,9 @@ def main() -> None:
     ap.add_argument("--only", default=None, help="comma-separated module keys")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache  # noqa: PLC0415
+
+    enable_compile_cache(Path(__file__).resolve().parents[1])
     from benchmarks import (  # noqa: PLC0415
         bench_serve,
         bench_walk,

@@ -105,6 +105,7 @@ def node2vec(p: float = 2.0, q: float = 0.5) -> SamplingSpec:
             bias=WindowBias(
                 edge_bias, needs_prev_neighbors=True,
                 needs_deg_u=False,  # bias reads weights/membership only
+                max_ratio=max(1.0, 1.0 / p, 1.0 / q),
             )
         ),
         name="node2vec",

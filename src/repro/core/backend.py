@@ -32,7 +32,6 @@ from repro.kernels import ref
 from repro.kernels.alias_select import alias_step_pallas
 from repro.kernels.its_select import its_select_pallas
 from repro.kernels.walk_step import (
-    _EPS,
     pad_csr_for_kernel,
     reject_step_pallas,
     walk_step_pallas,
@@ -52,6 +51,18 @@ WALK_BUCKETS = (128, 512)
 
 #: chunk width of the two-pass huge-degree scan
 CHUNK = 512
+
+#: walkers per round of a window-bias cohort (one (8, 128) walker tile of the
+#: kernels)
+WINDOW_TILE = 1024
+
+#: rejection budget of a window-bias walker on a row wider than the top
+#: bucket (hooks that declare ``WindowBias.max_ratio``)
+TAIL_REJECT_ITERS = 16
+
+#: walkers per round of the exact scan after a rejection stage: only walkers
+#: whose budget ran out are left, so rounds are narrow
+TAIL_SCAN_ROUND = 8
 
 
 def resolve_backend(backend: str = "auto") -> str:
@@ -298,18 +309,16 @@ def walk_step_flat_reference(
     """Pure-jnp mirror of :func:`walk_step_bucketed` — same bits, same picks.
 
     Replays the kernel's exact arithmetic (block-aligned window at the
-    walker's ``start % seg`` offset, masked cumsum, count-crossings pick) on
+    walker's ``start % seg`` offset, masked prefix sum, count-crossings pick) on
     the SAME padded edge arrays and the SAME ``fold_in(key, 0)`` /
     ``fold_in(key, 1)`` uniforms, so the §V drain loop gets bit-identical
     walks from ``backend="reference"`` and ``backend="pallas"`` while the
-    reference path stays kernel-free.  XLA's cumsum is position-indexed
-    (prefix ``i`` combines elements in a tree fixed by ``i`` alone), so
-    elements must sit at the kernel's window offsets — but the window TAIL
-    may be truncated: when ``max_degree`` is given the window shrinks from
-    ``2*seg`` to ``seg + min(seg, max_degree)`` without changing any prefix.
-    The selected id is gathered directly instead of through the kernel's
-    float32 one-hot reduction (identical for ids < 2^24, i.e. any graph this
-    repo can hold in f32 bias arrays).
+    reference path stays kernel-free.  The shared prefix sum
+    (``kernels.common.prefix_sum``) combines prefix ``i`` in a tree fixed by
+    ``i`` alone, so elements must sit at the kernel's window offsets — but
+    the window TAIL may be truncated: when ``max_degree`` is given the
+    window shrinks from ``2*seg`` to ``seg + min(seg, max_degree)`` without
+    changing any prefix up to the row's end.
     """
     safe = jnp.maximum(cur, 0)
     starts = indptr[safe]
@@ -371,7 +380,7 @@ def walk_step_adaptive(
     The adaptive generalization of :func:`walk_step_bucketed` /
     :func:`walk_step_flat_reference`: ``methods`` (static, from
     ``core.methods.plan_methods``) names the draw each degree cohort runs —
-    ``"its"`` (the legacy cumsum kernel/mirror), ``"alias"`` (O(1) draw from
+    ``"its"`` (the prefix-sum kernel/mirror), ``"alias"`` (O(1) draw from
     ``tables.prob``/``tables.alias``), or ``"rejection"`` (counted-budget
     envelope test against ``tables.row_max``) — one entry per bucket plus
     one for the chunked tail when present.  ONE function serves both
@@ -522,6 +531,8 @@ def walk_step_bucketed_window(
     interpret: bool | None = None,
     rand: jax.Array | None = None,
     tail_rand: jax.Array | None = None,
+    envelope: jax.Array | None = None,
+    tail_rej: jax.Array | None = None,
 ) -> jax.Array:
     """One dynamic-bias transition for all walkers, scheduled by degree.
 
@@ -530,12 +541,14 @@ def walk_step_bucketed_window(
     because the expensive, semantics-bearing part (evaluating the dynamic
     edge-bias hook) runs in shared jnp either way:
 
-    per bucket, each walker's *compact* ``(W, seg)`` row window is gathered
-    from the padded CSR arrays (``padded[seg] = (ids, weights)``,
+    per bucket, the bucket's walkers — taken ``WINDOW_TILE`` at a time in
+    degree-cohort order, so a walker pays only for its own cohort's width —
+    gather their *compact* ``(tile, seg)`` row windows from the padded CSR
+    arrays (``padded[seg] = (ids, weights)``,
     :func:`pad_walk_csr` over edge WEIGHTS, not a flat bias) and
     ``bias_of(u, w, mask, eidx) -> biases`` is evaluated on it — the narrowest
     arrays the hook (and its prev-membership search) can see.  The computed
-    bias is then re-aligned into the kernel's block-aligned ``(W, 2·seg)``
+    bias is then re-aligned into the kernel's block-aligned ``(tile, 2·seg)``
     window (one cheap row-local gather; per-edge bias values are unchanged)
     and the ITS pick runs
     :func:`~repro.kernels.walk_step.walk_step_window_pallas` under
@@ -543,10 +556,14 @@ def walk_step_bucketed_window(
     :func:`~repro.kernels.ref.walk_step_window_block_ref` mirror under
     ``"reference"`` — same bias rows, same uniforms, same picks.
 
-    Degrees above the last bucket take the two-pass chunked scan
-    (:func:`~repro.core.select.walk_transition_chunked_window`), evaluating
-    the hook chunk-by-chunk — no ``(W, max_degree)`` tensor exists on any
-    path.  Returns next vertices (W,) int32; -1 for finished walkers and
+    Degrees above the last bucket take the huge-degree tail
+    (:func:`_window_tail`): a counted rejection draw when ``envelope`` (an
+    upper bound of every bias the hook returns) is given, then the exact
+    two-pass chunked scan for the walkers still without a pick, compacted
+    into rounds so no other walker pays for a hub row — no ``(W,
+    max_degree)`` tensor exists on any path.  ``bias_of(u, w, mask, eidx,
+    rows=None)`` evaluates the hook for the walkers ``rows`` (all when
+    None).  Returns next vertices (W,) int32; -1 for finished walkers and
     dead ends.
     """
     safe = jnp.maximum(cur, 0)
@@ -556,53 +573,132 @@ def walk_step_bucketed_window(
         rand = jax.random.uniform(jax.random.fold_in(key, 0), cur.shape, dtype=jnp.float32)
     r = rand
 
-    nxt = jnp.full_like(cur, -1)
+    # each walker's cohort: its bucket, or len(buckets) for the tail and for
+    # finished walkers; an understated max_degree (possible in-memory, where
+    # the caller's bound is trusted for the exact bucket plan) degrades to
+    # NEIGHBORHOOD TRUNCATION — the dense-gather path's contract — never
+    # silent walker death: without a chunked tail the top cohort absorbs any
+    # larger degree, capped at its window
+    cohort = jnp.full(cur.shape, len(buckets), jnp.int32)
     lo = 0
     for i, seg in enumerate(buckets):
-        inds_p, wts_p = padded[seg]
-        # an understated max_degree (possible in-memory, where the caller's
-        # bound is trusted for the exact bucket plan) degrades to
-        # NEIGHBORHOOD TRUNCATION — the dense-gather path's contract — never
-        # silent walker death: without a chunked tail the top cohort absorbs
-        # any larger degree, capped at its window
         absorb = i == len(buckets) - 1 and not use_chunked
-        inb = (deg > lo) & ((deg <= seg) | absorb)
-        st = jnp.where(inb, starts, 0)
-        dg = jnp.where(inb, jnp.minimum(deg, seg), 0)
-        # compact row-aligned windows for the hook (row fits: dg <= seg, and
-        # the padded arrays keep a spare trailing block, so st+seg is safe)
-        offs_c = jnp.arange(seg, dtype=jnp.int32)
-        cmask = offs_c < dg[..., None]
-        ceidx = st[..., None] + offs_c
-        u_c = jnp.where(cmask, inds_p[ceidx], -1)
-        w_c = jnp.where(cmask, wts_p[ceidx], 0.0)
-        # the hook also receives the window's edge positions (``ceidx``) so
-        # per-edge side lanes (the sharded drain's replicated degree lane)
-        # can be gathered without row lookups; in-memory hooks ignore it
-        bias_c = jnp.where(cmask, jnp.maximum(bias_of(u_c, w_c, cmask, ceidx), 0.0), 0.0)
-        # re-align to the kernel's 2-block window at offset start % seg
-        # (same geometry the reference pick uses — shared helper keeps the
-        # bit-parity contract in one place)
-        local, _, offs, mask = ref._block_window(st, dg, seg, 2 * seg)
-        src = jnp.clip(offs - local[..., None], 0, seg - 1)
-        bias_win = jnp.where(mask, jnp.take_along_axis(bias_c, src, axis=-1), 0.0)
-        if backend == "pallas":
-            cand = walk_step_window_pallas(
-                st, dg, inds_p, bias_win, r, max_seg=seg, interpret=interpret
-            )
-        else:
-            cand = ref.walk_step_window_block_ref(st, dg, inds_p, bias_win, r, seg=seg)
-        nxt = jnp.where(inb, cand, nxt)
+        cohort = jnp.where((deg > lo) & ((deg <= seg) | absorb), i, cohort)
         lo = seg
+    # cohorts are evaluated compacted, WINDOW_TILE walkers per round, so a
+    # walker pays only for its own cohort's window width
+    w = cur.shape[-1]
+    k = min(WINDOW_TILE, w)
+    order = jnp.argsort(cohort, stable=True)
+    nxt = jnp.full_like(cur, -1)
+    begin = jnp.zeros((), jnp.int32)
+    for i, seg in enumerate(buckets):
+        inds_p, wts_p = padded[seg]
+        n = jnp.sum((cohort == i).astype(jnp.int32))
+
+        def tile(carry, seg=seg, inds_p=inds_p, wts_p=wts_p, begin=begin, n=n):
+            j, nxt = carry
+            pos = j * k + jnp.arange(k, dtype=jnp.int32)
+            live = pos < n
+            rows = order[jnp.minimum(begin + pos, w - 1)]
+            st = jnp.where(live, starts[rows], 0)
+            dg = jnp.where(live, jnp.minimum(deg[rows], seg), 0)
+            # compact row-aligned windows for the hook (row fits: dg <= seg,
+            # and the padded arrays keep a spare trailing block, so st+seg is
+            # safe)
+            offs_c = jnp.arange(seg, dtype=jnp.int32)
+            cmask = offs_c < dg[..., None]
+            ceidx = st[..., None] + offs_c
+            u_c = jnp.where(cmask, inds_p[ceidx], -1)
+            w_c = jnp.where(cmask, wts_p[ceidx], 0.0)
+            # the hook also receives the window's edge positions (``ceidx``)
+            # so per-edge side lanes (the sharded drain's replicated degree
+            # lane) can be gathered without row lookups; in-memory hooks
+            # ignore it
+            bias_c = jnp.where(
+                cmask, jnp.maximum(bias_of(u_c, w_c, cmask, ceidx, rows=rows), 0.0), 0.0
+            )
+            # re-align to the kernel's 2-block window at offset start % seg
+            # (same geometry the reference pick uses — shared helper keeps
+            # the bit-parity contract in one place)
+            local, _, offs, mask = ref._block_window(st, dg, seg, 2 * seg)
+            src = jnp.clip(offs - local[..., None], 0, seg - 1)
+            bias_win = jnp.where(mask, jnp.take_along_axis(bias_c, src, axis=-1), 0.0)
+            if backend == "pallas":
+                cand = walk_step_window_pallas(
+                    st, dg, inds_p, bias_win, r[rows], max_seg=seg, interpret=interpret
+                )
+            else:
+                cand = ref.walk_step_window_block_ref(
+                    st, dg, inds_p, bias_win, r[rows], seg=seg
+                )
+            # out-of-range index for idle slots: a clipped row may repeat
+            return j + 1, nxt.at[jnp.where(live, rows, w)].set(cand, mode="drop")
+
+        _, nxt = jax.lax.while_loop(
+            lambda c, n=n: c[0] * k < n, tile, (jnp.zeros((), jnp.int32), nxt)
+        )
+        begin = begin + n
 
     if use_chunked:
-        huge = deg > buckets[-1]
-        safe_cur = jnp.where(huge, safe, 0)
-        off = sel.walk_transition_chunked_window(
-            jax.random.fold_in(key, 1), indptr, indices, weights, safe_cur, bias_of,
-            chunk=CHUNK, rand=tail_rand,
+        nxt = _window_tail(
+            key, indices, weights, starts, deg, deg > buckets[-1], bias_of, nxt,
+            envelope=envelope, rand=tail_rand, rej=tail_rej,
         )
-        eidx = jnp.clip(indptr[safe_cur] + jnp.maximum(off, 0), 0, indices.shape[0] - 1)
+    return nxt
+
+
+def _window_tail(key, indices, weights, starts, deg, huge, bias_of, nxt, *,
+                 envelope=None, rand=None, rej=None):
+    """Next vertices of the walkers in ``huge`` (rows above the top bucket).
+
+    With an ``envelope``, every such walker first makes the counted
+    rejection draw (:func:`~repro.core.select.window_rejection_draw`,
+    budget ``rejection_randoms(fold_in(key, 2))`` unless ``rej`` overrides
+    it).  The walkers left without a pick — all of ``huge`` when there is no
+    envelope — take the exact chunked scan (uniform ``fold_in(key, 1)``
+    unless ``rand`` overrides it), widest rows first, ``TAIL_SCAN_ROUND``
+    walkers per round after a rejection stage and all of them in one round
+    otherwise.  Each round loops only to the widest row among its walkers.
+    """
+    pending = huge
+    if envelope is not None:
+        if rej is None:
+            rej = sel.rejection_randoms(
+                jax.random.fold_in(key, 2), huge.shape, iters=TAIL_REJECT_ITERS
+            )
+        cand, acc = sel.window_rejection_draw(
+            jnp.where(huge, starts, 0), jnp.where(huge, deg, 0), indices, weights,
+            bias_of, envelope, rej,
+        )
+        nxt = jnp.where(huge & acc, cand, nxt)
+        pending = huge & ~acc
+    if rand is None:
+        rand = jax.random.uniform(jax.random.fold_in(key, 1), huge.shape, dtype=jnp.float32)
+    w = huge.shape[-1]
+    k = min(TAIL_SCAN_ROUND, w) if envelope is not None else w
+    order = jnp.argsort(jnp.where(pending, -deg, 1))  # pending first, widest first
+    npend = jnp.sum(pending.astype(jnp.int32))
+
+    def scan_round(carry):
+        i, nxt = carry
+        pos = i * k + jnp.arange(k, dtype=jnp.int32)
+        live = pos < npend
+        rows = order[jnp.minimum(pos, w - 1)]
+        st = jnp.where(live, starts[rows], 0)
+        dg = jnp.where(live, deg[rows], 0)
+        off = sel.walk_transition_chunked_window(
+            None, st, dg, indices, weights,
+            lambda u, wt, m, e: bias_of(u, wt, m, e, rows=rows),
+            chunk=CHUNK, rand=rand[rows],
+        )
+        eidx = jnp.clip(st + jnp.maximum(off, 0), 0, indices.shape[0] - 1)
         cand = jnp.where(off >= 0, indices[eidx], -1)
-        nxt = jnp.where(huge, cand, nxt)
+        # out-of-range index for idle slots: a clipped row may repeat
+        nxt = nxt.at[jnp.where(live, rows, w)].set(cand, mode="drop")
+        return i + 1, nxt
+
+    _, nxt = jax.lax.while_loop(
+        lambda c: c[0] * k < npend, scan_round, (jnp.zeros((), jnp.int32), nxt)
+    )
     return nxt

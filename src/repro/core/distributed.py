@@ -21,7 +21,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.api import SamplingSpec
 from repro.core.engine import WalkResult, random_walk
-from repro.distributed.sharding import shard_map_compat
+from repro.distributed.sharding import shard_map_unchecked
 from repro.graph.csr import CSRGraph
 from repro.shard.walk import (  # noqa: F401  (re-exported for compatibility)
     replicated_psum_walk,
@@ -44,7 +44,7 @@ def instance_parallel_walk(
     """Shard instances over ``axis``; replicate the graph; zero collectives."""
 
     @functools.partial(
-        shard_map_compat,
+        shard_map_unchecked,
         mesh=mesh,
         in_specs=(P(), P(axis), P()),
         out_specs=WalkResult(P(axis), P(axis), P()),

@@ -191,14 +191,22 @@ def _is_prev_neighbor_window(indptr, ids_sorted, prow, prev, u, mask, *, steps: 
     return found & mask & (prev >= 0)[..., None] & (u >= 0)
 
 
+def _walker_rows(rows, *state):
+    """Per-walker state arrays restricted to the walkers ``rows`` (all when
+    None); scalars (a step index shared by every walker) pass through."""
+    if rows is None:
+        return state
+    return tuple(x if jnp.ndim(x) == 0 else x[rows] for x in state)
+
+
 def _window_bias_fn(graph: CSRGraph, program: tp.TransitionProgram,
                     v, prev, depth, row_of, ids_sorted,
                     max_degree: int | None = None):
     """Close the spec's dynamic edge-bias hook over the walker state so the
     backend scheduler can evaluate it on any gathered edge window.
 
-    The returned ``bias_of(u, w, mask, eidx=None)`` builds a window
-    EdgeCtx — candidate ids/weights straight off the CSR window, degrees by
+    The returned ``bias_of(u, w, mask, eidx=None, rows=None)`` builds a
+    window EdgeCtx for the walkers ``rows`` (all when None) — candidate ids/weights straight off the CSR window, degrees by
     row lookup (localized in partition mode, so non-resident neighbors read
     deg 0 off the phantom row, §V semantics), prev-membership by binary
     search — and runs ``WindowBias.fn`` on it.  ``eidx`` (the window's edge
@@ -217,25 +225,32 @@ def _window_bias_fn(graph: CSRGraph, program: tp.TransitionProgram,
     bound = int(ids_sorted.shape[0]) if max_degree is None else max(max_degree, 1)
     bs_steps = min(32, max(1, bound.bit_length()))
 
-    def bias_of(u, w, mask, eidx=None):
+    def bias_of(u, w, mask, eidx=None, rows=None):
         del eidx  # in-memory/OOM: degrees come from row lookups below
         if wb.needs_deg_u:
             uq = u if row_of is None else row_of(u)
             deg_u = jnp.where(mask, _degree(graph, uq), 0)
         else:  # declared unused — skip two window-wide indptr gathers
             deg_u = jnp.zeros(u.shape, jnp.int32)
+        v_, prev_, pq_, deg_v_, depth_ = _walker_rows(rows, v, prev, pq, deg_v, depth)
         ipn = None
         if wb.needs_prev_neighbors:
             ipn = _is_prev_neighbor_window(
-                graph.indptr, ids_sorted, pq, prev, u, mask, steps=bs_steps
+                graph.indptr, ids_sorted, pq_, prev_, u, mask, steps=bs_steps
             )
         ctx = EdgeCtx(
-            v=v, u=u, weight=w, deg_v=deg_v, deg_u=deg_u, prev=prev,
-            is_prev_neighbor=ipn, depth=depth,
+            v=v_, u=u, weight=w, deg_v=deg_v_, deg_u=deg_u, prev=prev_,
+            is_prev_neighbor=ipn, depth=depth_,
         )
         return wb.fn(ctx)
 
     return bias_of
+
+
+def window_envelope(bias: tp.WindowBias, weights) -> jax.Array:
+    """Upper bound of every bias a ``max_ratio`` hook returns on ``weights``
+    (the huge-degree tail's rejection envelope)."""
+    return jnp.float32(bias.max_ratio) * jnp.maximum(jnp.max(weights), 0.0)
 
 
 def walk_window_transition(key: jax.Array, graph: CSRGraph, indices_out: jax.Array,
@@ -255,9 +270,12 @@ def walk_window_transition(key: jax.Array, graph: CSRGraph, indices_out: jax.Arr
     bias_of = _window_bias_fn(
         graph, program, v, prev, depth, row_of, indices_out, max_degree
     )
+    envelope = None
+    if use_chunked and program.bias.max_ratio is not None:
+        envelope = window_envelope(program.bias, graph.weights)
     u = bk.walk_step_bucketed_window(
         kf, graph.indptr, indices_out, graph.weights, padded, vq, bias_of,
-        buckets=buckets, use_chunked=use_chunked, backend=be,
+        buckets=buckets, use_chunked=use_chunked, backend=be, envelope=envelope,
     )
     return _select_epilogue(key, graph, program, spec, v, prev, depth, u, vq, row_of, home)
 

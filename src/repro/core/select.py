@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.common import its_ctps
+
 SelectMethod = Literal["its_brs", "repeated", "updated", "gumbel"]
 
 _EPS = 1e-12
@@ -41,14 +43,13 @@ def build_ctps(biases: jax.Array, mask: jax.Array | None = None) -> jax.Array:
     """Inclusive normalized prefix sum of biases: the CTPS (paper Eq. 1).
 
     Region of candidate ``j`` is ``[ctps[j-1], ctps[j])`` with ``ctps[-1]=0``.
-    Masked/zero-bias candidates get zero-width regions (unselectable).
+    Masked/zero-bias candidates get zero-width regions (unselectable).  The
+    sum is the ITS kernel's own (``kernels.common.its_ctps``), so the
+    reference and Pallas selection backends agree bit-for-bit.
     """
     if mask is not None:
         biases = jnp.where(mask, biases, 0.0)
-    biases = jnp.maximum(biases.astype(jnp.float32), 0.0)
-    sums = jnp.cumsum(biases, axis=-1)
-    total = sums[..., -1:]
-    return sums / jnp.maximum(total, _EPS)
+    return its_ctps(jnp.maximum(biases.astype(jnp.float32), 0.0))
 
 
 def its_search(ctps: jax.Array, r: jax.Array) -> jax.Array:
@@ -282,45 +283,35 @@ def walk_transition_chunked(
     """
     start = indptr[cur]
     deg = indptr[cur + 1] - start
-    nchunks = (jnp.max(deg) + chunk - 1) // chunk  # dynamic upper bound is fine under scan-with-cond
-    nchunks = jnp.maximum(nchunks, 1)
+    # the loops run to the widest row in the batch, not to E/chunk: a bound
+    # fixed by the edge count costs E/chunk loop trips per step on any graph
+    nchunks = jnp.maximum((jnp.max(deg) + chunk - 1) // chunk, 1)
 
-    def chunk_sum(c, carry):
-        tot = carry
+    def p1_body(c, tot):
         offs = c * chunk + jnp.arange(chunk, dtype=jnp.int32)
         m = offs < deg[..., None]
         w = jnp.where(m, weights[jnp.where(m, start[..., None] + offs, 0)], 0.0)
         return tot + jnp.sum(w, axis=-1)
 
-    max_iters = (weights.shape[0] + chunk - 1) // chunk
-
-    def p1_body(c, tot):
-        return jax.lax.cond(c < nchunks, lambda t: chunk_sum(c, t), lambda t: t, tot)
-
-    total = jax.lax.fori_loop(0, max_iters, p1_body, jnp.zeros(cur.shape, jnp.float32))
+    total = jax.lax.fori_loop(0, nchunks, p1_body, jnp.zeros(cur.shape, jnp.float32))
     r = jax.random.uniform(key, cur.shape, dtype=jnp.float32) if rand is None else rand
     target = r * total
 
     def p2_body(c, carry):
         cum, found = carry
-
-        def step(args):
-            cum, found = args
-            offs = c * chunk + jnp.arange(chunk, dtype=jnp.int32)
-            m = offs < deg[..., None]
-            w = jnp.where(m, weights[jnp.where(m, start[..., None] + offs, 0)], 0.0)
-            cw = jnp.cumsum(w, axis=-1) + cum[..., None]
-            hit = (cw > target[..., None]) & m & (found[..., None] < 0)
-            any_hit = jnp.any(hit, axis=-1)
-            first = jnp.argmax(hit, axis=-1) + c * chunk
-            found = jnp.where((found < 0) & any_hit, first, found)
-            return cw[..., -1], found
-
-        return jax.lax.cond(c < nchunks, step, lambda a: a, (cum, found))
+        offs = c * chunk + jnp.arange(chunk, dtype=jnp.int32)
+        m = offs < deg[..., None]
+        w = jnp.where(m, weights[jnp.where(m, start[..., None] + offs, 0)], 0.0)
+        cw = jnp.cumsum(w, axis=-1) + cum[..., None]
+        hit = (cw > target[..., None]) & m & (found[..., None] < 0)
+        any_hit = jnp.any(hit, axis=-1)
+        first = jnp.argmax(hit, axis=-1) + c * chunk
+        found = jnp.where((found < 0) & any_hit, first, found)
+        return cw[..., -1], found
 
     cum0 = jnp.zeros(cur.shape, jnp.float32)
     found0 = jnp.full(cur.shape, -1, jnp.int32)
-    _, found = jax.lax.fori_loop(0, max_iters, p2_body, (cum0, found0))
+    _, found = jax.lax.fori_loop(0, nchunks, p2_body, (cum0, found0))
     # numerical edge: r*total == total -> take last valid edge
     found = jnp.where((found < 0) & (deg > 0) & (total > 0), deg - 1, found)
     return jnp.where((deg > 0) & (total > 0), found, -1)
@@ -328,10 +319,10 @@ def walk_transition_chunked(
 
 def walk_transition_chunked_window(
     key: jax.Array,
-    indptr: jax.Array,
+    start: jax.Array,
+    deg: jax.Array,
     indices: jax.Array,
     weights: jax.Array,
-    cur: jax.Array,
     bias_of,
     chunk: int = 512,
     rand: jax.Array | None = None,
@@ -345,14 +336,12 @@ def walk_transition_chunked_window(
     padding masked).  Both passes evaluate the (pure) hook on identical
     windows, so pass-2 crossings agree with pass-1 totals exactly.  Pure jnp,
     shared verbatim by both backends (the huge-degree tail of the bucketed
-    window scheduler).  Returns per-row edge offsets, -1 for dead ends.
-    Not jitted here: ``bias_of`` is a closure — callers jit the enclosing
-    step.
+    window scheduler).  Rows are given by their edge ``start`` and ``deg``
+    (0 for walkers that take no part).  Returns per-row edge offsets, -1 for
+    dead ends.  Not jitted here: ``bias_of`` is a closure — callers jit the
+    enclosing step.
     """
-    start = indptr[cur]
-    deg = indptr[cur + 1] - start
     nchunks = jnp.maximum((jnp.max(deg) + chunk - 1) // chunk, 1)
-    max_iters = (weights.shape[0] + chunk - 1) // chunk
 
     def chunk_bias(c):
         offs = c * chunk + jnp.arange(chunk, dtype=jnp.int32)
@@ -363,34 +352,62 @@ def walk_transition_chunked_window(
         return jnp.where(m, jnp.maximum(bias_of(u, w, m, eidx), 0.0), 0.0), m
 
     def p1_body(c, tot):
-        def step(t):
-            b, _ = chunk_bias(c)
-            return t + jnp.sum(b, axis=-1)
+        b, _ = chunk_bias(c)
+        return tot + jnp.sum(b, axis=-1)
 
-        return jax.lax.cond(c < nchunks, step, lambda t: t, tot)
-
-    total = jax.lax.fori_loop(0, max_iters, p1_body, jnp.zeros(cur.shape, jnp.float32))
-    r = jax.random.uniform(key, cur.shape, dtype=jnp.float32) if rand is None else rand
+    total = jax.lax.fori_loop(0, nchunks, p1_body, jnp.zeros(deg.shape, jnp.float32))
+    r = jax.random.uniform(key, deg.shape, dtype=jnp.float32) if rand is None else rand
     target = r * total
 
     def p2_body(c, carry):
-        def step(args):
-            cum, found = args
-            b, m = chunk_bias(c)
-            cw = jnp.cumsum(b, axis=-1) + cum[..., None]
-            hit = (cw > target[..., None]) & m & (found[..., None] < 0)
-            any_hit = jnp.any(hit, axis=-1)
-            first = jnp.argmax(hit, axis=-1) + c * chunk
-            found = jnp.where((found < 0) & any_hit, first, found)
-            return cw[..., -1], found
+        cum, found = carry
+        b, m = chunk_bias(c)
+        cw = jnp.cumsum(b, axis=-1) + cum[..., None]
+        hit = (cw > target[..., None]) & m & (found[..., None] < 0)
+        any_hit = jnp.any(hit, axis=-1)
+        first = jnp.argmax(hit, axis=-1) + c * chunk
+        found = jnp.where((found < 0) & any_hit, first, found)
+        return cw[..., -1], found
 
-        return jax.lax.cond(c < nchunks, step, lambda a: a, carry)
-
-    cum0 = jnp.zeros(cur.shape, jnp.float32)
-    found0 = jnp.full(cur.shape, -1, jnp.int32)
-    _, found = jax.lax.fori_loop(0, max_iters, p2_body, (cum0, found0))
+    cum0 = jnp.zeros(deg.shape, jnp.float32)
+    found0 = jnp.full(deg.shape, -1, jnp.int32)
+    _, found = jax.lax.fori_loop(0, nchunks, p2_body, (cum0, found0))
     found = jnp.where((found < 0) & (deg > 0) & (total > 0), deg - 1, found)
     return jnp.where((deg > 0) & (total > 0), found, -1)
+
+
+def window_rejection_draw(
+    start: jax.Array,
+    deg: jax.Array,
+    indices: jax.Array,
+    weights: jax.Array,
+    bias_of,
+    envelope: jax.Array,
+    rej: jax.Array,
+) -> tuple[jax.Array, jax.Array]:
+    """Counted-budget rejection draw for a dynamic (window) bias.
+
+    ``rej`` is a :func:`rejection_randoms` budget ``(W, iters, 2)``.  Trial
+    ``t`` proposes ``slot = floor(r_slot * deg)`` (uniform over the row) and
+    accepts iff ``r_acc * envelope < bias(slot)``, where ``envelope`` bounds
+    every bias the hook can return; the first accepted trial wins, so an
+    accepted pick is distributed exactly as the bias.  All trials are
+    evaluated as one ``(W, iters)`` window of the hook.  Returns the picked
+    neighbour ids and whether any trial was accepted — walkers without an
+    acceptance (or with ``deg == 0``) must draw another way.
+    """
+    degf = deg.astype(jnp.float32)[..., None]
+    last = jnp.maximum(deg - 1, 0)[..., None]
+    slot = jnp.minimum((rej[..., 0] * degf).astype(jnp.int32), last)
+    m = jnp.broadcast_to((deg > 0)[..., None], slot.shape)
+    eidx = jnp.where(m, start[..., None] + slot, 0)
+    u = jnp.where(m, indices[eidx], -1)
+    w = jnp.where(m, weights[eidx], 0.0)
+    b = jnp.where(m, jnp.maximum(bias_of(u, w, m, eidx), 0.0), 0.0)
+    acc = rej[..., 1] * envelope < b
+    first = jnp.argmax(acc, axis=-1)
+    cand = jnp.take_along_axis(u, first[..., None], axis=-1)[..., 0]
+    return cand, jnp.any(acc, axis=-1)
 
 
 # ---------------------------------------------------------------------------
@@ -434,7 +451,8 @@ def build_alias(indptr, bias) -> tuple[np.ndarray, np.ndarray]:
             continue
         starts, w, tot = starts[ok], w[ok], tot[ok]
         r = starts.shape[0]
-        p = w * (d / tot[:, None])  # scaled to sum d
+        # normalize first: d / tot overflows for a subnormal row total
+        p = (w / tot[:, None]) * d  # scaled to sum d
         alias = np.full((r, d), -1, dtype=np.int32)
         active = np.ones((r, d), dtype=bool)
         for _ in range(max(d - 1, 0)):
@@ -464,11 +482,13 @@ def build_row_max(indptr, bias) -> np.ndarray:
     indptr = np.asarray(indptr)
     bias = np.maximum(np.asarray(bias, dtype=np.float64), 0.0)
     deg = np.diff(indptr)
-    if bias.shape[0] == 0:
-        return np.zeros(deg.shape[0], dtype=np.float32)
-    starts = np.minimum(indptr[:-1], bias.shape[0] - 1)
-    rm = np.maximum.reduceat(bias, starts)
-    return np.where(deg > 0, rm, 0.0).astype(np.float32)
+    rm = np.zeros(deg.shape[0], dtype=np.float32)
+    live = deg > 0
+    if live.any():
+        # segments between consecutive non-empty row starts are exactly
+        # those rows (empty rows own no edges)
+        rm[live] = np.maximum.reduceat(bias[: indptr[-1]], indptr[:-1][live])
+    return rm
 
 
 def rejection_randoms(key: jax.Array, batch_shape: tuple, iters: int = REJECT_ITERS) -> jax.Array:

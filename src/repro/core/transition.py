@@ -92,11 +92,18 @@ class WindowBias:
     O(D²) compare).  ``needs_deg_u=False`` declares the hook never reads
     ``ctx.deg_u`` and skips two window-wide degree gathers per cohort (it
     reads as zeros).
+
+    ``max_ratio`` declares ``fn(ctx) <= max_ratio * ctx.weight`` on every
+    edge.  With it, a walker on a row wider than the top bucket draws by
+    rejection against ``max_ratio * max(weight)`` — a few hook evaluations
+    instead of a scan of the whole row — and only walkers whose counted
+    budget runs out take the exact two-pass scan, so the draw stays exact.
     """
 
     fn: Callable[[EdgeCtx], jax.Array]
     needs_prev_neighbors: bool = False
     needs_deg_u: bool = True
+    max_ratio: float | None = None
 
 
 @dataclasses.dataclass(frozen=True)

@@ -15,47 +15,14 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, axis_names=None):
-    """Version-spanning ``shard_map``: the API moved from
-    ``jax.experimental.shard_map`` (replication check kwarg ``check_rep``,
-    partial-manual via the complement set ``auto=``) to ``jax.shard_map``
-    (kwarg ``check_vma``, partial-manual via ``axis_names=``).  Both checks
-    are disabled — the psum-merge patterns in this repo are intentionally
-    unreplicated.  ``axis_names`` takes the NEW-API meaning: the mesh axes
-    that become manual (None = all of them)."""
-    if hasattr(jax, "shard_map"):
-        kw = {} if axis_names is None else {"axis_names": axis_names}
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False, **kw,
-        )
-    from jax.experimental.shard_map import shard_map  # noqa: PLC0415
-
-    kw = {}
-    if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False, **kw,
+def shard_map_unchecked(f, *, mesh, in_specs, out_specs, axis_names=None):
+    """``jax.shard_map`` with the replication check off — the psum-merge
+    patterns in this repo are intentionally unreplicated.  ``axis_names``:
+    the mesh axes that become manual (None = all of them)."""
+    kw = {} if axis_names is None else {"axis_names": axis_names}
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False, **kw
     )
-
-
-def abstract_mesh(axis_names: Sequence[str], axis_sizes: Sequence[int]):
-    """Version-portable ``AbstractMesh`` constructor.
-
-    The AbstractMesh signature drifted across JAX releases — older versions
-    take ``shape_tuple`` (name, size) pairs, newer ones keyword
-    ``axis_sizes``/``axis_names`` — so spec-logic tests that only need an
-    abstract mesh construct it through this shim.
-    """
-    from jax.sharding import AbstractMesh
-
-    try:
-        return AbstractMesh(
-            axis_sizes=tuple(axis_sizes), axis_names=tuple(axis_names)
-        )
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
 
 
 def fsdp_axes(mesh: Mesh) -> tuple:

@@ -158,7 +158,7 @@ class RangePartition:
 
         ``edge_align`` > 0 prepends ``edge_lo % edge_align`` inert edges so
         every row keeps its *global* block offset (``start % edge_align``
-        unchanged by the rebase).  The degree-bucketed pick kernels cumsum
+        unchanged by the rebase).  The degree-bucketed pick kernels prefix-sum
         over block-aligned windows whose float association is fixed by the
         within-block position, so preserving the offset is what makes a
         partition-local pick bit-identical to the full-graph pick

@@ -9,10 +9,10 @@ the cost model picks when a graph's tables are prebuilt and reused
 (DESIGN.md §13); the serving service amortizes construction across requests.
 
 Bit-parity contract: the kernel performs exactly the arithmetic of
-``core.select.alias_draw_flat`` with ``cap = max_seg`` (f32 one-hot gathers
-are exact — a single nonzero term — and vertex ids stay below 2^24), so
-reference and Pallas backends agree bit-for-bit, including the truncation
-semantics for oversized rows absorbed into the top bucket.
+``core.select.alias_draw_flat`` with ``cap = max_seg`` (its gathers are
+one-hot masked sums, exact for any value), so reference and Pallas backends
+agree bit-for-bit, including the truncation semantics for oversized rows
+absorbed into the top bucket.
 """
 from __future__ import annotations
 
@@ -21,45 +21,40 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.its_select import resolve_interpret
+from repro.kernels.common import pick_lane, tile_mask, tile_pick, tile_spec, to_tiles
+from repro.kernels.walk_step import _edge_specs, _rows, _store, _walker_call, _window
 
 
 def _alias_step_kernel(
     starts_ref,  # scalar-prefetch (W,)
     degs_ref,  # scalar-prefetch (W,)
-    rand_ref,  # (1,) this walker's uniform (same stream an ITS cohort uses)
-    p_lo_ref,  # (max_seg,) acceptance-threshold block containing `start`
-    p_hi_ref,  # (max_seg,) following block
-    a_lo_ref,  # (max_seg,) alias-offset blocks (row-local redirects)
+    rand_ref,  # (8, 128) walker tile of uniforms (the stream an ITS cohort uses)
+    p_lo_ref,  # (1, max_seg) acceptance-threshold block containing `start`
+    p_hi_ref,  # (1, max_seg) following block
+    a_lo_ref,  # (1, max_seg) alias-offset blocks (row-local redirects)
     a_hi_ref,
-    idx_lo_ref,  # (max_seg,) neighbor-id blocks
+    idx_lo_ref,  # (1, max_seg) neighbor-id blocks
     idx_hi_ref,
-    out_ref,  # (1,) next vertex
+    out_ref,  # (8, 128) walker tile of next vertices
     *,
     max_seg: int,
 ):
-    w = pl.program_id(0)
-    start = starts_ref[w]
-    deg = degs_ref[w]
+    i = pl.program_id(0)
+    m = tile_mask(i)
+    start = starts_ref[i]
+    deg = degs_ref[i]
     deg_eff = jnp.minimum(deg, max_seg)  # absorbed oversized rows truncate
+    top = jnp.maximum(deg_eff - 1, 0)
     local = start % max_seg  # offset inside the 2-block window
-    offs = jax.lax.broadcasted_iota(jnp.int32, (2 * max_seg,), 0)
-    u = rand_ref[0] * deg_eff.astype(jnp.float32)
-    slot = jnp.minimum(u.astype(jnp.int32), jnp.maximum(deg_eff - 1, 0))
+    u = tile_pick(rand_ref[...], m) * jnp.full((1, 1), deg_eff, jnp.int32).astype(jnp.float32)
+    slot = jnp.minimum(u.astype(jnp.int32), top)
     frac = u - slot.astype(jnp.float32)
-    oh = (offs == local + slot).astype(jnp.float32)
-    pval = jnp.sum(oh * jnp.concatenate([p_lo_ref[...], p_hi_ref[...]]))
-    aliases = jnp.concatenate([a_lo_ref[...], a_hi_ref[...]])
-    aval = jnp.sum(oh * aliases.astype(jnp.float32)).astype(jnp.int32)
-    chosen = jnp.where(frac < pval, slot, aval)
-    chosen = jnp.clip(chosen, 0, jnp.maximum(deg_eff - 1, 0))
-    ids = jnp.concatenate([idx_lo_ref[...], idx_hi_ref[...]])
-    oh2 = (offs == local + chosen).astype(jnp.float32)
-    nxt = jnp.sum(oh2 * ids.astype(jnp.float32)).astype(jnp.int32)
-    dead = (deg <= 0) | (aval < 0)  # zero-total rows carry alias = -1
-    out_ref[0] = jnp.where(dead, -1, nxt)
+    pval = pick_lane(_window(p_lo_ref, p_hi_ref), local + slot)
+    aval = pick_lane(_window(a_lo_ref, a_hi_ref), local + slot)
+    chosen = jnp.clip(jnp.where(frac < pval, slot, aval), 0, top)
+    nxt = pick_lane(_window(idx_lo_ref, idx_hi_ref), local + chosen)
+    _store(out_ref, m, (deg <= 0) | (aval < 0), nxt)  # zero-total rows carry alias = -1
 
 
 @functools.partial(jax.jit, static_argnames=("max_seg", "interpret"))
@@ -81,38 +76,13 @@ def alias_step_pallas(
     — pad values are never read for real rows); rand: (W,) uniforms.
     Returns next vertices (W,) int32 (-1 dead end).
     """
-    w = starts.shape[0]
     e = indices.shape[0]
     assert e % max_seg == 0, "pad CSR edge arrays with pad_csr_for_kernel"
     assert prob.shape[0] == e and alias.shape[0] == e, (prob.shape, alias.shape, e)
-
-    def lo_map(i, starts_ref, degs_ref):
-        return (starts_ref[i] // max_seg,)
-
-    def hi_map(i, starts_ref, degs_ref):
-        return (starts_ref[i] // max_seg + 1,)
-
-    def per_walker(i, starts_ref, degs_ref):
-        return (i,)
-
+    p, a, ind = _rows(prob), _rows(alias), _rows(indices)
     kernel = functools.partial(_alias_step_kernel, max_seg=max_seg)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(w,),
-        in_specs=[
-            pl.BlockSpec((1,), per_walker),
-            pl.BlockSpec((max_seg,), lo_map),
-            pl.BlockSpec((max_seg,), hi_map),
-            pl.BlockSpec((max_seg,), lo_map),
-            pl.BlockSpec((max_seg,), hi_map),
-            pl.BlockSpec((max_seg,), lo_map),
-            pl.BlockSpec((max_seg,), hi_map),
-        ],
-        out_specs=pl.BlockSpec((1,), per_walker),
+    in_specs = [tile_spec()] + _edge_specs(max_seg, 3)
+    return _walker_call(
+        kernel, starts.shape[0], in_specs, interpret, starts, degs,
+        to_tiles(rand), p, p, a, a, ind, ind,
     )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((w,), jnp.int32),
-        interpret=resolve_interpret(interpret),
-    )(starts, degs, rand, prob, prob, alias, alias, indices, indices)

@@ -4,17 +4,18 @@ TPU mapping of the paper's warp-centric SELECT (DESIGN.md §2, §6):
 
 - grid over *instance blocks* — each grid step owns ``(BLK_I, P)`` bias rows
   resident in VMEM (the paper's "one warp per instance" becomes "one tile of
-  instances per grid step"; the K draws of an instance occupy vector lanes).
+  instances per grid step").
 - prefix-sum + normalize + search + BRS retry are fused in one kernel: the
   CTPS never round-trips to HBM (the paper's key win over updated sampling).
-- all gathers are one-hot contractions (MXU) — no atomics, no irregular
-  addressing; within-round collisions resolve by lane priority (K×K conflict
-  matrix), replacing the strided atomic bitmap.
-- the retry budget is a static ``ITERS`` unroll of pre-generated randoms
+- gathers are one-hot masked lane sums — no atomics, no irregular
+  addressing.  The K draws of a round run in lane order, and a round mask of
+  the candidates earlier draws claimed resolves within-round collisions by
+  lane priority, replacing the strided atomic bitmap.
+- the retry budget is a static ``ITERS`` loop over pre-generated randoms
   (counted RNG outside the kernel keeps it deterministic and testable).
 
-VMEM budget: biases+CTPS+mask ≈ 3·BLK_I·P·4B; with BLK_I=8, P=2048 ≈ 200 KiB,
-comfortably inside ~16 MiB VMEM with room for double buffering.
+VMEM budget: biases+CTPS+masks ≈ 5·BLK_I·P·4B; with BLK_I=8, P=2048 ≈ 330 KiB,
+comfortably inside the scoped VMEM limit with room for double buffering.
 """
 from __future__ import annotations
 
@@ -24,89 +25,72 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-_EPS = 1e-12
+from repro.kernels.common import _EPS, its_ctps, pick_lane, resolve_interpret, shift_roll
 
 
 def _its_select_kernel(biases_ref, rands_ref, out_ref, stats_ref, *, iters: int, k: int):
     b = jnp.maximum(biases_ref[...].astype(jnp.float32), 0.0)  # (BLK_I, P)
     blk_i, p = b.shape
-    sums = jnp.cumsum(b, axis=-1)
-    total = jnp.maximum(sums[:, -1:], _EPS)
-    ctps = sums / total
-    lower = jnp.concatenate([jnp.zeros_like(ctps[:, :1]), ctps[:, :-1]], axis=-1)
-    navail = jnp.sum((b > 0).astype(jnp.int32), axis=-1)
-    want = jnp.minimum(navail, k)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (blk_i, k), 1)
-
-    done = lane >= want[:, None]
-    out = jnp.full((blk_i, k), -1, jnp.int32)
-    selmask = jnp.zeros((blk_i, p), jnp.float32)
-    it_acc = jnp.zeros((blk_i,), jnp.int32)
-    se_acc = jnp.zeros((blk_i,), jnp.int32)
-
-    def gather(table, idx):
-        oh = (idx[:, :, None] == jax.lax.broadcasted_iota(jnp.int32, (blk_i, k, p), 2)).astype(
-            table.dtype
-        )
-        return jnp.einsum("ikp,ip->ik", oh, table, preferred_element_type=jnp.float32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (blk_i, p), 1)
+    ctps = its_ctps(b, shift_roll)
+    lower = shift_roll(ctps, 1)
+    navail = jnp.sum((b > 0).astype(jnp.int32), axis=-1, keepdims=True)
+    want = jnp.minimum(navail, k)  # (BLK_I, 1)
+    rands = rands_ref[...]  # (BLK_I, ITERS*K): round t's draws at t*K ...
 
     def search(r):
-        idx = jnp.sum((ctps[:, None, :] <= r[:, :, None]).astype(jnp.int32), axis=-1)
+        idx = jnp.sum((ctps <= r).astype(jnp.int32), axis=-1, keepdims=True)
         return jnp.clip(idx, 0, p - 1)
 
     def body(it, carry):
         done, out, selmask, it_acc, se_acc = carry
-        pending = ~done
-        r1 = jax.lax.dynamic_slice_in_dim(rands_ref[...], it, 1, axis=1)[:, 0, :]
-        idx1 = search(r1)
-        hit1 = gather(selmask, idx1) > 0.5
+        # loop carries hold int32 flags: Mosaic cannot carry i1 vectors
+        done, out = [d > 0 for d in done], list(out)
+        pending = [~d for d in done]
+        any_pending = functools.reduce(jnp.logical_or, pending)
         # retry-loop accounting (paper Figs. 11/12), bit-identical to the
         # reference loop in core.select._select_its_loop
-        it_acc = it_acc + jnp.any(pending, axis=-1).astype(jnp.int32)
-        se_acc = se_acc + jnp.sum(pending.astype(jnp.int32), axis=-1)
-        se_acc = se_acc + jnp.sum((pending & hit1).astype(jnp.int32), axis=-1)
-        l = gather(lower, idx1)
-        h = gather(ctps, idx1)
-        delta = h - l
-        r2 = r1 * (1.0 - delta)
-        r2 = jnp.where(r2 < l, r2, r2 + delta)
-        r2 = jnp.clip(r2, 0.0, 1.0 - _EPS)
-        idx2 = search(r2)
-        hit2 = gather(selmask, idx2) > 0.5
-        cand = jnp.where(hit1, idx2, idx1)
-        ok = jnp.logical_and(~done, ~jnp.where(hit1, hit2, hit1))
-        ok = jnp.logical_and(ok, gather(b, cand) > 0)
-        # K×K conflict matrix: lowest lane wins (replaces atomic bitmap)
-        eq = cand[:, :, None] == cand[:, None, :]
-        both = ok[:, :, None] & ok[:, None, :]
-        tri = (
-            jax.lax.broadcasted_iota(jnp.int32, (k, k), 1)
-            < jax.lax.broadcasted_iota(jnp.int32, (k, k), 0)
-        )
-        beaten = jnp.any(eq & both & tri[None], axis=-1)
-        win = ok & ~beaten
-        out = jnp.where(win, cand, out)
-        oh = (
-            cand[:, :, None] == jax.lax.broadcasted_iota(jnp.int32, (blk_i, k, p), 2)
-        ) & win[:, :, None]
-        selmask = jnp.maximum(selmask, jnp.max(oh.astype(jnp.float32), axis=1))
-        done = done | win
-        got = jnp.sum(done.astype(jnp.int32), axis=-1)
-        done = done | ((got >= want)[:, None] & (lane >= want[:, None]))
-        return done, out, selmask, it_acc, se_acc
+        it_acc = it_acc + any_pending.astype(jnp.int32)
+        claimed = jnp.zeros((blk_i, p), jnp.float32)  # candidates of ok lanes this round
+        for j in range(k):
+            r1 = pick_lane(rands, it * k + j)
+            idx1 = search(r1)
+            hit1 = pick_lane(selmask, idx1) > 0.5
+            se_acc = se_acc + pending[j].astype(jnp.int32)
+            se_acc = se_acc + (pending[j] & hit1).astype(jnp.int32)
+            l = pick_lane(lower, idx1)
+            h = pick_lane(ctps, idx1)
+            delta = h - l
+            r2 = r1 * (1.0 - delta)
+            r2 = jnp.where(r2 < l, r2, r2 + delta)
+            r2 = jnp.clip(r2, 0.0, 1.0 - _EPS)
+            idx2 = search(r2)
+            hit2 = pick_lane(selmask, idx2) > 0.5
+            cand = jnp.where(hit1, idx2, idx1)
+            ok = ~done[j] & ~(hit1 & hit2)
+            ok = ok & (pick_lane(b, cand) > 0)
+            # lowest lane wins: an earlier ok lane already claimed `cand`
+            win = ok & ~(pick_lane(claimed, cand) > 0.5)
+            claimed = jnp.where((lane == cand) & ok, 1.0, claimed)
+            out[j] = jnp.where(win, cand, out[j])
+            done[j] = done[j] | win
+        # the set of won candidates is the set of ok candidates
+        selmask = jnp.maximum(selmask, claimed)
+        got = functools.reduce(jnp.add, [d.astype(jnp.int32) for d in done])
+        done = [d | ((got >= want) & (j >= want)) for j, d in enumerate(done)]
+        return tuple(d.astype(jnp.int32) for d in done), tuple(out), selmask, it_acc, se_acc
 
-    done, out, selmask, it_acc, se_acc = jax.lax.fori_loop(
-        0, iters, body, (done, out, selmask, it_acc, se_acc)
+    done = tuple((want <= j).astype(jnp.int32) for j in range(k))
+    out = tuple(jnp.full((blk_i, 1), -1, jnp.int32) for _ in range(k))
+    zero = jnp.zeros((blk_i, 1), jnp.int32)
+    carry = (done, out, jnp.zeros((blk_i, p), jnp.float32), zero, zero)
+    _, out, _, it_acc, se_acc = jax.lax.fori_loop(0, iters, body, carry)
+    kl = jax.lax.broadcasted_iota(jnp.int32, (blk_i, k), 1)
+    out_ref[...] = functools.reduce(
+        jnp.add, [jnp.where(kl == j, o, 0) for j, o in enumerate(out)]
     )
-    out_ref[...] = out
-    stats_ref[...] = jnp.stack([it_acc, se_acc], axis=-1)
-
-
-def resolve_interpret(interpret: bool | None) -> bool:
-    """``None`` → interpret off-TPU, compile through Mosaic on TPU."""
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
+    sl = jax.lax.broadcasted_iota(jnp.int32, (blk_i, 2), 1)
+    stats_ref[...] = jnp.where(sl == 0, it_acc, se_acc)
 
 
 @functools.partial(jax.jit, static_argnames=("blk_i", "interpret", "with_stats"))
@@ -145,7 +129,7 @@ def its_select_pallas(
         grid=(i_pad // blk_i,),
         in_specs=[
             pl.BlockSpec((blk_i, p), lambda i: (i, 0)),
-            pl.BlockSpec((blk_i, iters, k), lambda i: (i, 0, 0)),
+            pl.BlockSpec((blk_i, iters * k), lambda i: (i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((blk_i, k), lambda i: (i, 0)),
@@ -156,7 +140,7 @@ def its_select_pallas(
             jax.ShapeDtypeStruct((i_pad, 2), jnp.int32),
         ],
         interpret=resolve_interpret(interpret),
-    )(biases, rands)
+    )(biases, rands.reshape(i_pad, iters * k))
     if with_stats:
         return out[:i_dim], stats[:i_dim]
     return out[:i_dim]

@@ -1,22 +1,21 @@
 """Pure-jnp oracles for the Pallas kernels.
 
 Each oracle consumes the *same* pre-generated random budget as its kernel and
-performs bit-identical math, so tests can ``assert_allclose`` (exact for the
-integer outputs) across shapes and dtypes.
+runs the same floating-point formula (the prefix sum and ITS pick of
+``kernels.common``, called by both), so tests compare integer outputs
+exactly.  Gathers are direct indexing: the kernels' one-hot masked sums
+have a single nonzero term, so the values are the same.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-_EPS = 1e-12
+from repro.kernels.common import _EPS, its_ctps, shift_pad, window_pick
 
 
-def _onehot_gather(table: jax.Array, idx: jax.Array) -> jax.Array:
-    """Gather along the last axis via a one-hot contraction (MXU-friendly —
-    mirrors the kernel exactly, including its numerics)."""
-    oh = jax.nn.one_hot(idx, table.shape[-1], dtype=table.dtype)
-    return jnp.einsum("...kp,...p->...k", oh, table)
+def _gather(table: jax.Array, idx: jax.Array) -> jax.Array:
+    return jnp.take_along_axis(table, idx, axis=-1)
 
 
 def its_select_ref(biases: jax.Array, rands: jax.Array) -> jax.Array:
@@ -29,10 +28,8 @@ def its_select_ref(biases: jax.Array, rands: jax.Array) -> jax.Array:
     i_dim, p = biases.shape
     iters, k = rands.shape[1], rands.shape[2]
     b = jnp.maximum(biases.astype(jnp.float32), 0.0)
-    sums = jnp.cumsum(b, axis=-1)
-    total = jnp.maximum(sums[:, -1:], _EPS)
-    ctps = sums / total
-    lower = jnp.concatenate([jnp.zeros_like(ctps[:, :1]), ctps[:, :-1]], axis=-1)
+    ctps = its_ctps(b)
+    lower = shift_pad(ctps, 1)
     navail = jnp.sum(b > 0, axis=-1)
     want = jnp.minimum(navail, k)
 
@@ -44,18 +41,18 @@ def its_select_ref(biases: jax.Array, rands: jax.Array) -> jax.Array:
         done, out, selmask = carry
         r1 = rands[:, it, :]
         idx1 = search(r1)
-        hit1 = _onehot_gather(selmask.astype(jnp.float32), idx1) > 0.5
-        l = _onehot_gather(lower, idx1)
-        h = _onehot_gather(ctps, idx1)
+        hit1 = _gather(selmask.astype(jnp.float32), idx1) > 0.5
+        l = _gather(lower, idx1)
+        h = _gather(ctps, idx1)
         delta = h - l
         r2 = r1 * (1.0 - delta)
         r2 = jnp.where(r2 < l, r2, r2 + delta)
         r2 = jnp.clip(r2, 0.0, 1.0 - _EPS)
         idx2 = search(r2)
-        hit2 = _onehot_gather(selmask.astype(jnp.float32), idx2) > 0.5
+        hit2 = _gather(selmask.astype(jnp.float32), idx2) > 0.5
         cand = jnp.where(hit1, idx2, idx1)
         ok = ~done & ~jnp.where(hit1, hit2, hit1)
-        ok = ok & (_onehot_gather(b, cand) > 0)
+        ok = ok & (_gather(b, cand) > 0)
         eq = cand[:, :, None] == cand[:, None, :]
         both = ok[:, :, None] & ok[:, None, :]
         beaten = jnp.any(eq & both & jnp.tril(jnp.ones((k, k), bool), -1), axis=-1)
@@ -83,7 +80,7 @@ def _block_window(starts: jax.Array, degs: jax.Array, seg: int, width: int):
     and the row occupies offsets ``[local, local+deg)`` with
     ``local = start % seg``.  Returns ``(local, blk0, offs, mask)`` with
     ``offs`` of length ``width`` (``2*seg`` for the kernel's full window; a
-    truncated tail never changes any cumsum prefix)."""
+    truncated tail never changes any prefix sum up to the row's end)."""
     local = starts % seg
     blk0 = starts // seg * seg
     offs = jnp.arange(width, dtype=jnp.int32)
@@ -91,30 +88,13 @@ def _block_window(starts: jax.Array, degs: jax.Array, seg: int, width: int):
     return local, blk0, offs, mask
 
 
-def _window_pick(
-    local: jax.Array,
-    blk0: jax.Array,
-    degs: jax.Array,
-    mask: jax.Array,
-    wts: jax.Array,
-    rand: jax.Array,
-    inds_p: jax.Array,
-) -> jax.Array:
-    """Masked-cumsum ITS pick over block-aligned windows — the kernel's exact
-    arithmetic (DESIGN.md §6).  XLA's cumsum is position-indexed (prefix ``i``
-    combines elements in a tree fixed by ``i`` alone), so ``wts`` must sit at
-    the kernel's window offsets; then reference and Pallas agree bit-for-bit.
-    The selected id is gathered directly instead of through the kernel's
-    float32 one-hot reduction (identical for ids < 2^24, i.e. any graph this
-    repo can hold in f32 bias arrays)."""
-    cum = jnp.cumsum(wts, axis=-1)
-    total = cum[..., -1]
-    target = rand * total
-    pick = jnp.sum(((cum <= target[..., None]) & mask).astype(jnp.int32), axis=-1)
-    pick = jnp.minimum(local + pick, local + jnp.maximum(degs - 1, 0))
-    cand = inds_p[blk0 + pick]
-    dead = (degs <= 0) | (total <= _EPS)
-    return jnp.where(dead, -1, cand)
+def _window_pick(local, blk0, degs, wts, rand, inds_p) -> jax.Array:
+    """Masked ITS pick over block-aligned windows — the kernel's arithmetic
+    (``kernels.common.window_pick``, DESIGN.md §6).  Prefix ``i`` is summed
+    in a tree fixed by ``i`` alone, so ``wts`` must sit at the kernel's
+    window offsets; then reference and Pallas agree bit-for-bit."""
+    pos, dead = window_pick(wts, local[..., None], degs[..., None], rand[..., None])
+    return jnp.where(dead[..., 0], -1, inds_p[blk0 + pos[..., 0]])
 
 
 def walk_step_block_ref(
@@ -138,7 +118,7 @@ def walk_step_block_ref(
     local, blk0, _, mask = _block_window(starts, degs, seg, width)
     win = blk0[..., None] + jnp.arange(width, dtype=jnp.int32)
     wts = jnp.where(mask, bias_p[win], 0.0)
-    return _window_pick(local, blk0, degs, mask, wts, rand, inds_p)
+    return _window_pick(local, blk0, degs, wts, rand, inds_p)
 
 
 def walk_step_window_block_ref(
@@ -158,7 +138,7 @@ def walk_step_window_block_ref(
     parity reduces to the pick arithmetic, which this mirrors exactly)."""
     local, blk0, _, mask = _block_window(starts, degs, seg, bias_win.shape[-1])
     wts = jnp.where(mask, bias_win, 0.0)
-    return _window_pick(local, blk0, degs, mask, wts, rand, inds_p)
+    return _window_pick(local, blk0, degs, wts, rand, inds_p)
 
 
 def alias_step_block_ref(
@@ -175,8 +155,8 @@ def alias_step_block_ref(
 
     ``inds_p``/``prob_p``/``alias_p`` are the SAME padded flat arrays the
     kernel DMAs; one uniform splits into slot ``⌊u·deg⌋`` and coin, the coin
-    routes through the prebuilt redirect.  The kernel's f32 one-hot gathers
-    are exact (single nonzero term, values < 2^24), so a direct gather is
+    routes through the prebuilt redirect.  The kernel's one-hot masked
+    sums are exact (single nonzero term), so a direct gather is
     bit-identical."""
     deg_eff = jnp.minimum(degs, seg)  # absorbed oversized rows truncate
     local = starts % seg
@@ -234,30 +214,3 @@ def reject_step_block_ref(
     nxt = inds_p[blk0 + local + jnp.maximum(chosen, 0)]
     dead = (degs <= 0) | (row_max <= 0) | (chosen < 0)
     return jnp.where(dead, -1, nxt).astype(jnp.int32)
-
-
-def walk_step_ref(
-    starts: jax.Array,
-    degs: jax.Array,
-    indices: jax.Array,
-    weights: jax.Array,
-    rand: jax.Array,
-    max_seg: int,
-) -> jax.Array:
-    """One weighted ITS walk step per walker (oracle for walk_step kernel).
-
-    starts/degs: (W,) row start offsets and degrees (deg <= max_seg);
-    indices/weights: flat CSR arrays; rand: (W,) uniforms.
-    Returns next vertex (W,) int32, -1 for dead ends.
-    """
-    offs = jnp.arange(max_seg, dtype=jnp.int32)
-    idx = starts[:, None] + offs[None, :]
-    mask = offs[None, :] < degs[:, None]
-    w = jnp.where(mask, weights[jnp.where(mask, idx, 0)], 0.0)
-    cum = jnp.cumsum(w, axis=-1)
-    total = cum[:, -1]
-    target = rand * total
-    pick = jnp.sum((cum <= target[:, None]) & mask, axis=-1)
-    pick = jnp.minimum(pick, jnp.maximum(degs - 1, 0))
-    nxt = indices[jnp.clip(starts + pick, 0, indices.shape[0] - 1)]
-    return jnp.where((degs > 0) & (total > 0), nxt, -1).astype(jnp.int32)

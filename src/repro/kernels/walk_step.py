@@ -1,16 +1,22 @@
-"""Pallas TPU kernel: fused random-walk step (segment DMA + ITS draw).
+"""Pallas TPU kernels: fused random-walk steps (segment DMA + one draw).
 
 One grid step advances one walker: the walker's CSR neighbor segment is
 DMA'd into VMEM by BlockSpec index_maps driven by scalar-prefetched row
-starts (the TPU analogue of the paper's coalesced warp loads), then the
-weighted ITS draw happens entirely in VMEM.
+starts (the TPU analogue of the paper's coalesced warp loads), then the draw
+happens entirely in VMEM.
 
 Degree bucketing (DESIGN.md §6): segments must satisfy ``deg <= max_seg``;
 the engine routes larger rows through ``select.walk_transition_chunked``.
 A segment can straddle a ``max_seg`` block boundary, so the index_maps pull
 TWO consecutive blocks (same input bound twice with maps ``blk`` and
 ``blk+1``) and the kernel offsets into their concatenation.  Edge arrays must
-be padded with one extra trailing block so ``blk+1`` always exists.
+be padded with one extra trailing block so ``blk+1`` always exists; they
+enter the kernel as ``(1, E)`` rows so each window is a lane-dense
+``(1, max_seg)`` block (``max_seg`` a multiple of 128 on the chip).
+
+Per-walker scalars (uniforms, envelopes, the output) travel in ``(8, 128)``
+walker tiles (``kernels.common``); the draws use the arithmetic the oracles
+in ``kernels.ref`` share, so both backends agree bit-for-bit.
 """
 from __future__ import annotations
 
@@ -21,51 +27,63 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.its_select import resolve_interpret
+from repro.kernels.common import (
+    pick_lane,
+    resolve_interpret,
+    shift_roll,
+    tile_mask,
+    tile_pick,
+    tile_spec,
+    to_tiles,
+    window_pick,
+)
 
-_EPS = 1e-12
+
+def _window(lo_ref, hi_ref):
+    """The walker's 2-block window, ``(1, 2*max_seg)``."""
+    return jnp.concatenate([lo_ref[...], hi_ref[...]], axis=1)
+
+
+def _store(out_ref, m, dead, nxt):
+    out_ref[...] = jnp.where(m, jnp.where(dead, -1, nxt), out_ref[...])
+
+
+def _its_pick(out_ref, m, start, deg, wts, rand, ids, max_seg):
+    local = start % max_seg
+    lane = jax.lax.broadcasted_iota(jnp.int32, wts.shape, 1)
+    wts = jnp.where((lane >= local) & (lane < local + deg), wts, 0.0)
+    pos, dead = window_pick(wts, local, deg, rand, shift_roll)
+    _store(out_ref, m, dead, pick_lane(ids, pos))
 
 
 def _walk_step_kernel(
     starts_ref,  # scalar-prefetch (W,)
     degs_ref,  # scalar-prefetch (W,)
-    rand_ref,  # (1,) this walker's uniform
-    idx_lo_ref,  # (max_seg,) neighbor-id block containing `start`
-    idx_hi_ref,  # (max_seg,) following block
-    w_lo_ref,  # (max_seg,) weight blocks
+    rand_ref,  # (8, 128) walker tile of uniforms
+    idx_lo_ref,  # (1, max_seg) neighbor-id block containing `start`
+    idx_hi_ref,  # (1, max_seg) following block
+    w_lo_ref,  # (1, max_seg) weight blocks
     w_hi_ref,
-    out_ref,  # (1,) next vertex
+    out_ref,  # (8, 128) walker tile of next vertices
     *,
     max_seg: int,
 ):
-    w = pl.program_id(0)
-    start = starts_ref[w]
-    deg = degs_ref[w]
-    local = start % max_seg  # offset inside the 2-block window
-    offs = jax.lax.broadcasted_iota(jnp.int32, (2 * max_seg,), 0)
-    mask = (offs >= local) & (offs < local + deg)
-    wts = jnp.where(mask, jnp.concatenate([w_lo_ref[...], w_hi_ref[...]]), 0.0)
-    cum = jnp.cumsum(wts)
-    total = cum[-1]
-    target = rand_ref[0] * total
-    # index of the edge whose cumulative bias crosses target
-    pick = jnp.sum(((cum <= target) & mask).astype(jnp.int32))
-    pick = jnp.minimum(local + pick, local + jnp.maximum(deg - 1, 0))
-    ids = jnp.concatenate([idx_lo_ref[...], idx_hi_ref[...]])
-    oh = (offs == pick).astype(jnp.float32)
-    nxt = jnp.sum(oh * ids.astype(jnp.float32)).astype(jnp.int32)
-    dead = (deg <= 0) | (total <= _EPS)
-    out_ref[0] = jnp.where(dead, -1, nxt)
+    i = pl.program_id(0)
+    m = tile_mask(i)
+    _its_pick(
+        out_ref, m, starts_ref[i], degs_ref[i], _window(w_lo_ref, w_hi_ref),
+        tile_pick(rand_ref[...], m), _window(idx_lo_ref, idx_hi_ref), max_seg,
+    )
 
 
 def _walk_step_window_kernel(
     starts_ref,  # scalar-prefetch (W,)
     degs_ref,  # scalar-prefetch (W,)
-    rand_ref,  # (1,) this walker's uniform
-    bias_ref,  # (1, 2*max_seg) this walker's window-aligned bias row
-    idx_lo_ref,  # (max_seg,) neighbor-id block containing `start`
-    idx_hi_ref,  # (max_seg,) following block
-    out_ref,  # (1,) next vertex
+    rand_ref,  # (8, 128) walker tile of uniforms
+    bias_ref,  # (8, 2*max_seg) window-aligned bias rows of 8 walkers
+    idx_lo_ref,  # (1, max_seg) neighbor-id block containing `start`
+    idx_hi_ref,  # (1, max_seg) following block
+    out_ref,  # (8, 128) walker tile of next vertices
     *,
     max_seg: int,
 ):
@@ -74,35 +92,27 @@ def _walk_step_window_kernel(
     engine's dynamic edge-bias hook on this walker's gathered edge window —
     instead of a slice of a static flat CSR array.  Neighbor ids still
     arrive by segment DMA; the ITS pick is identical to the flat kernel."""
-    w = pl.program_id(0)
-    start = starts_ref[w]
-    deg = degs_ref[w]
-    local = start % max_seg  # offset inside the 2-block window
-    offs = jax.lax.broadcasted_iota(jnp.int32, (2 * max_seg,), 0)
-    mask = (offs >= local) & (offs < local + deg)
-    wts = jnp.where(mask, bias_ref[0, :], 0.0)
-    cum = jnp.cumsum(wts)
-    total = cum[-1]
-    target = rand_ref[0] * total
-    pick = jnp.sum(((cum <= target) & mask).astype(jnp.int32))
-    pick = jnp.minimum(local + pick, local + jnp.maximum(deg - 1, 0))
-    ids = jnp.concatenate([idx_lo_ref[...], idx_hi_ref[...]])
-    oh = (offs == pick).astype(jnp.float32)
-    nxt = jnp.sum(oh * ids.astype(jnp.float32)).astype(jnp.int32)
-    dead = (deg <= 0) | (total <= _EPS)
-    out_ref[0] = jnp.where(dead, -1, nxt)
+    i = pl.program_id(0)
+    m = tile_mask(i)
+    rows = bias_ref[...]
+    row = jax.lax.broadcasted_iota(jnp.int32, rows.shape, 0)
+    wts = jnp.sum(jnp.where(row == i % 8, rows, 0.0), axis=0, keepdims=True)
+    _its_pick(
+        out_ref, m, starts_ref[i], degs_ref[i], wts, tile_pick(rand_ref[...], m),
+        _window(idx_lo_ref, idx_hi_ref), max_seg,
+    )
 
 
 def _reject_step_kernel(
     starts_ref,  # scalar-prefetch (W,)
     degs_ref,  # scalar-prefetch (W,)
-    rej_ref,  # (1, 2*iters) this walker's [slot, accept] uniform rounds
-    rowmax_ref,  # (1,) this walker's rejection envelope (row max bias)
-    idx_lo_ref,  # (max_seg,) neighbor-id blocks
+    rej_ref,  # (2*iters, 8, 128) walker tiles of [slot, accept] uniforms
+    rowmax_ref,  # (8, 128) walker tile of rejection envelopes (row max bias)
+    idx_lo_ref,  # (1, max_seg) neighbor-id blocks
     idx_hi_ref,
-    w_lo_ref,  # (max_seg,) bias blocks
+    w_lo_ref,  # (1, max_seg) bias blocks
     w_hi_ref,
-    out_ref,  # (1,) next vertex
+    out_ref,  # (8, 128) walker tile of next vertices
     *,
     max_seg: int,
     iters: int,
@@ -113,34 +123,30 @@ def _reject_step_kernel(
     an exhausted budget falls back to the last candidate carrying mass.
     Static unroll; exactly ``core.select.rejection_draw_flat`` with
     ``cap = max_seg`` (bit-identical across backends)."""
-    w = pl.program_id(0)
-    start = starts_ref[w]
-    deg = degs_ref[w]
+    i = pl.program_id(0)
+    m = tile_mask(i)
+    start = starts_ref[i]
+    deg = degs_ref[i]
     deg_eff = jnp.minimum(deg, max_seg)
-    degf = deg_eff.astype(jnp.float32)
+    top = jnp.maximum(deg_eff - 1, 0)
     local = start % max_seg
-    offs = jax.lax.broadcasted_iota(jnp.int32, (2 * max_seg,), 0)
-    wts = jnp.concatenate([w_lo_ref[...], w_hi_ref[...]])
-    rm = rowmax_ref[0]
-    chosen = jnp.full((), -1, jnp.int32)
-    done = jnp.full((), False)
-    last = jnp.full((), 0, jnp.int32)
-    last_b = jnp.full((), 0.0, jnp.float32)
+    wts = _window(w_lo_ref, w_hi_ref)
+    rm = tile_pick(rowmax_ref[...], m)
+    degf = jnp.full((1, 1), deg_eff, jnp.int32).astype(jnp.float32)
+    chosen = jnp.full((1, 1), -1, jnp.int32)
+    done = jnp.zeros((1, 1), jnp.bool_)
+    last = jnp.zeros((1, 1), jnp.int32)
+    last_b = jnp.zeros((1, 1), jnp.float32)
     for t in range(iters):
-        slot = jnp.minimum(
-            (rej_ref[0, 2 * t] * degf).astype(jnp.int32), jnp.maximum(deg_eff - 1, 0)
-        )
-        bval = jnp.sum((offs == local + slot).astype(jnp.float32) * wts)
-        acc = rej_ref[0, 2 * t + 1] * rm < bval
+        slot = jnp.minimum((tile_pick(rej_ref[2 * t], m) * degf).astype(jnp.int32), top)
+        bval = pick_lane(wts, local + slot)
+        acc = tile_pick(rej_ref[2 * t + 1], m) * rm < bval
         chosen = jnp.where(~done & acc, slot, chosen)
         last, last_b = slot, bval
         done = done | acc
     chosen = jnp.where(done, chosen, jnp.where(last_b > 0, last, -1))
-    ids = jnp.concatenate([idx_lo_ref[...], idx_hi_ref[...]])
-    oh = (offs == local + jnp.maximum(chosen, 0)).astype(jnp.float32)
-    nxt = jnp.sum(oh * ids.astype(jnp.float32)).astype(jnp.int32)
-    dead = (deg <= 0) | (rm <= 0) | (chosen < 0)
-    out_ref[0] = jnp.where(dead, -1, nxt)
+    nxt = pick_lane(_window(idx_lo_ref, idx_hi_ref), local + jnp.maximum(chosen, 0))
+    _store(out_ref, m, (deg <= 0) | (rm <= 0) | (chosen < 0), nxt)
 
 
 def pad_csr_for_kernel(indices: jax.Array, weights: jax.Array, max_seg: int):
@@ -152,6 +158,41 @@ def pad_csr_for_kernel(indices: jax.Array, weights: jax.Array, max_seg: int):
         jnp.pad(indices, (0, pad), constant_values=0),
         jnp.pad(weights, (0, pad), constant_values=0.0),
     )
+
+
+def _edge_specs(max_seg: int, pairs: int) -> list:
+    """``pairs`` × (block containing the row start, following block)."""
+
+    def lo_map(i, starts_ref, degs_ref):
+        return (0, starts_ref[i] // max_seg)
+
+    def hi_map(i, starts_ref, degs_ref):
+        return (0, starts_ref[i] // max_seg + 1)
+
+    return [pl.BlockSpec((1, max_seg), f) for _ in range(pairs) for f in (lo_map, hi_map)]
+
+
+def _walker_call(kernel, w, in_specs, interpret, starts, degs, *args):
+    """Run a per-walker kernel over ``grid=(W,)``; returns ``(W,)`` int32."""
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(w,),
+        in_specs=in_specs,
+        out_specs=tile_spec(),
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(to_tiles(starts).shape, jnp.int32),
+        interpret=resolve_interpret(interpret),
+    )(starts, degs, *args)
+    return out.reshape(-1)[:w]
+
+
+def _rows(x: jax.Array) -> jax.Array:
+    """A flat padded edge array as one ``(1, E)`` lane-dense row."""
+    assert x.ndim == 1, x.shape
+    return x.reshape(1, -1)
 
 
 @functools.partial(jax.jit, static_argnames=("max_seg", "interpret"))
@@ -173,38 +214,14 @@ def walk_step_pallas(
     :func:`pad_csr_for_kernel`; rand: (W,) uniforms.  Returns next
     vertices (W,) int32 (-1 dead end).
     """
-    w = starts.shape[0]
-    e = indices.shape[0]
-    assert e % max_seg == 0, "pad CSR edge arrays with pad_csr_for_kernel"
-
-    def lo_map(i, starts_ref, degs_ref):
-        return (starts_ref[i] // max_seg,)
-
-    def hi_map(i, starts_ref, degs_ref):
-        return (starts_ref[i] // max_seg + 1,)
-
-    def per_walker(i, starts_ref, degs_ref):
-        return (i,)
-
+    assert indices.shape[0] % max_seg == 0, "pad CSR edge arrays with pad_csr_for_kernel"
+    ind, wts = _rows(indices), _rows(weights)
     kernel = functools.partial(_walk_step_kernel, max_seg=max_seg)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(w,),
-        in_specs=[
-            pl.BlockSpec((1,), per_walker),
-            pl.BlockSpec((max_seg,), lo_map),
-            pl.BlockSpec((max_seg,), hi_map),
-            pl.BlockSpec((max_seg,), lo_map),
-            pl.BlockSpec((max_seg,), hi_map),
-        ],
-        out_specs=pl.BlockSpec((1,), per_walker),
+    in_specs = [tile_spec()] + _edge_specs(max_seg, 2)
+    return _walker_call(
+        kernel, starts.shape[0], in_specs, interpret, starts, degs,
+        to_tiles(rand), ind, ind, wts, wts,
     )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((w,), jnp.int32),
-        interpret=resolve_interpret(interpret),
-    )(starts, degs, rand, indices, indices, weights, weights)
 
 
 @functools.partial(jax.jit, static_argnames=("max_seg", "interpret"))
@@ -222,45 +239,24 @@ def walk_step_window_pallas(
 
     Like :func:`walk_step_pallas` but the per-edge bias is ``bias_win``:
     ``(W, 2*max_seg)`` float32 rows, one per walker, aligned with the
-    kernel's 2-block edge window (the walker's neighbors sit at offsets
+    kernel's 2-block window (the walker's neighbors sit at offsets
     ``[start % max_seg, start % max_seg + deg)``).  ``indices`` is the
-    padded flat CSR id array (:func:`pad_csr_for_kernel`).
+    padded flat CSR id array (:func:`pad_csr_for_kernel`).  The bias rows
+    arrive eight walkers per block, so no block grows with ``W``.
     """
     w = starts.shape[0]
-    e = indices.shape[0]
-    assert e % max_seg == 0, "pad CSR edge arrays with pad_csr_for_kernel"
+    assert indices.shape[0] % max_seg == 0, "pad CSR edge arrays with pad_csr_for_kernel"
     assert bias_win.shape == (w, 2 * max_seg), bias_win.shape
-
-    def lo_map(i, starts_ref, degs_ref):
-        return (starts_ref[i] // max_seg,)
-
-    def hi_map(i, starts_ref, degs_ref):
-        return (starts_ref[i] // max_seg + 1,)
-
-    def per_walker(i, starts_ref, degs_ref):
-        return (i,)
-
-    def bias_row(i, starts_ref, degs_ref):
-        return (i, 0)
-
+    ind = _rows(indices)
+    bias = jnp.pad(bias_win, ((0, (-w) % 8), (0, 0)))
     kernel = functools.partial(_walk_step_window_kernel, max_seg=max_seg)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(w,),
-        in_specs=[
-            pl.BlockSpec((1,), per_walker),
-            pl.BlockSpec((1, 2 * max_seg), bias_row),
-            pl.BlockSpec((max_seg,), lo_map),
-            pl.BlockSpec((max_seg,), hi_map),
-        ],
-        out_specs=pl.BlockSpec((1,), per_walker),
+    in_specs = [
+        tile_spec(),
+        pl.BlockSpec((8, 2 * max_seg), lambda i, *_: (i // 8, 0)),
+    ] + _edge_specs(max_seg, 1)
+    return _walker_call(
+        kernel, w, in_specs, interpret, starts, degs, to_tiles(rand), bias, ind, ind,
     )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((w,), jnp.int32),
-        interpret=resolve_interpret(interpret),
-    )(starts, degs, rand, bias_win, indices, indices)
 
 
 @functools.partial(jax.jit, static_argnames=("max_seg", "interpret"))
@@ -285,41 +281,15 @@ def reject_step_pallas(
     (-1 dead end).
     """
     w = starts.shape[0]
-    e = indices.shape[0]
-    assert e % max_seg == 0, "pad CSR edge arrays with pad_csr_for_kernel"
+    assert indices.shape[0] % max_seg == 0, "pad CSR edge arrays with pad_csr_for_kernel"
     assert rej.ndim == 3 and rej.shape[0] == w and rej.shape[2] == 2, rej.shape
     iters = rej.shape[1]
-    rej2 = rej.reshape(w, 2 * iters)
-
-    def lo_map(i, starts_ref, degs_ref):
-        return (starts_ref[i] // max_seg,)
-
-    def hi_map(i, starts_ref, degs_ref):
-        return (starts_ref[i] // max_seg + 1,)
-
-    def per_walker(i, starts_ref, degs_ref):
-        return (i,)
-
-    def rej_row(i, starts_ref, degs_ref):
-        return (i, 0)
-
+    # round t's [slot, accept] uniforms → tiles 2t, 2t+1
+    rej_t = to_tiles(rej.transpose(1, 2, 0).reshape(2 * iters, w))
+    ind, wts = _rows(indices), _rows(weights)
     kernel = functools.partial(_reject_step_kernel, max_seg=max_seg, iters=iters)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(w,),
-        in_specs=[
-            pl.BlockSpec((1, 2 * iters), rej_row),
-            pl.BlockSpec((1,), per_walker),
-            pl.BlockSpec((max_seg,), lo_map),
-            pl.BlockSpec((max_seg,), hi_map),
-            pl.BlockSpec((max_seg,), lo_map),
-            pl.BlockSpec((max_seg,), hi_map),
-        ],
-        out_specs=pl.BlockSpec((1,), per_walker),
+    in_specs = [tile_spec((2 * iters,)), tile_spec()] + _edge_specs(max_seg, 2)
+    return _walker_call(
+        kernel, w, in_specs, interpret, starts, degs,
+        rej_t, to_tiles(row_max), ind, ind, wts, wts,
     )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((w,), jnp.int32),
-        interpret=resolve_interpret(interpret),
-    )(starts, degs, rej2, row_max, indices, indices, weights, weights)

@@ -50,18 +50,20 @@ source of divergence is pinned (DESIGN.md §12, §14):
   ``draw(key_of(depth))[instance]``.  Counted RNG is also what makes the
   sub-round restructure safe: a draw depends on (depth, instance), never on
   WHEN or WHERE the entry was popped.
-- *Selection arithmetic*: the pick kernels cumsum block-aligned CSR windows
+- *Selection arithmetic*: the pick kernels prefix-sum block-aligned CSR windows
   whose float association is fixed by within-window position, so partitions
   are materialized with ``edge_align = max(buckets)`` lead padding —
   every row (resident AND hub-replicated) keeps its global ``start % seg``
-  offset and the partition-local cumsum reproduces the full-graph bits.
+  offset and the partition-local prefix sum reproduces the full-graph bits.
 - *Flat biases*: evaluated ONCE on the full graph at partition time and
   sliced per shard (a neighbor-degree bias needs non-resident degrees, which
   a shard cannot see), so per-edge bias bits match by construction.
 - *Prev-dependent window biases* (node2vec): the previous vertex's neighbor
   row is CARRIED with the walker through the exchange (gathered at the
   source shard, which owns it), so ``is_prev_neighbor`` is exact without
-  any replicated adjacency.
+  any replicated adjacency beyond the hub region — a hub's row is on every
+  shard, so a hub ``prev`` is searched there and never carried, and the
+  carried width is the widest non-hub row.
 - *Non-resident degrees* (``needs_deg_u`` window biases, MH-accept): a
   replicated per-edge *target-degree lane* ``deg_tgt[e] = deg(indices[e])``
   — sliced/placed exactly like the flat bias — resolves ``deg(u)`` for any
@@ -89,8 +91,16 @@ from repro.core import backend as bk
 from repro.core import methods as mt
 from repro.core import select as sel
 from repro.core import transition as tp
-from repro.core.engine import WalkResult, _degree, _edge_ctx, flat_method_plan
-from repro.distributed.sharding import shard_map_compat
+from repro.core.engine import (
+    WalkResult,
+    _degree,
+    _edge_ctx,
+    _is_prev_neighbor_window,
+    _walker_rows,
+    flat_method_plan,
+    window_envelope,
+)
+from repro.distributed.sharding import shard_map_unchecked
 from repro.graph.csr import CSRGraph
 from repro.graph.partition import (
     PartitionMap,
@@ -131,43 +141,62 @@ def _per_entry(base_key, d, inst, valid, draw):
     return jax.lax.cond(same, cheap, general, None)
 
 
-def _carried_window_bias(graph, program, v, prev, d, curq, prow, deg_tgt):
+def _carried_window_bias(graph, program, v, prev, d, curq, prow, deg_tgt,
+                         ids, prev_row, prev_hub, steps):
     """The window-bias hook closed over carried walker state.
 
-    Mirrors ``engine._window_bias_fn`` except that prev-neighbor membership
-    is an exact compare against the CARRIED ``(B, prow_w)`` neighbor row of
-    ``prev`` (``-2``-padded, gathered at the source shard) instead of a
-    binary search over a resident CSR — identical booleans, no replicated
-    adjacency.  ``needs_deg_u`` hooks gather the replicated per-edge
-    target-degree lane at the window's edge positions (``eidx``) — the same
-    integers the engine's ``_degree(graph, u)`` row lookup produces, since
-    ``deg_tgt[e] = deg(indices[e])`` on the full graph by construction.
+    Mirrors ``engine._window_bias_fn`` except for where prev-neighbor
+    membership looks: a hub ``prev`` (``prev_hub``) has its full row on
+    every device, so the engine's binary search runs over that local row
+    (``prev_row`` in the hybrid row space, global ids ``ids``, ``steps``
+    halvings); any other ``prev`` is compared against the CARRIED ``(B,
+    prow_w)`` neighbor row (``-2``-padded, gathered at the source shard,
+    whose width is the widest NON-hub row).  Identical booleans either way,
+    no replicated adjacency beyond the hub region.  ``needs_deg_u`` hooks
+    gather the replicated per-edge target-degree lane at the window's edge
+    positions (``eidx``) — the same integers the engine's ``_degree(graph,
+    u)`` row lookup produces, since ``deg_tgt[e] = deg(indices[e])`` on the
+    full graph by construction.
     """
     wb = program.bias
     deg_v = _degree(graph, curq)
     e_hi = deg_tgt.shape[0] - 1
 
-    def bias_of(u, w, mask, eidx=None):
+    def bias_of(u, w, mask, eidx=None, rows=None):
         if wb.needs_deg_u:
             du = jnp.where(mask, deg_tgt[jnp.clip(eidx, 0, e_hi)], 0)
         else:  # declared unused — skip the window-wide lane gather
             du = jnp.zeros(u.shape, jnp.int32)
+        v_, prev_, d_, deg_v_ = _walker_rows(rows, v, prev, d, deg_v)
         ipn = None
         if wb.needs_prev_neighbors:
+            prow_, prev_row_, prev_hub_ = _walker_rows(rows, prow, prev_row, prev_hub)
+            carried = jnp.any(u[..., :, None] == prow_[..., None, :], axis=-1)
+            searched = _is_prev_neighbor_window(
+                graph.indptr, ids, prev_row_, prev_, u, mask, steps=steps
+            )
             ipn = (
-                jnp.any(u[..., :, None] == prow[..., None, :], axis=-1)
+                jnp.where(prev_hub_[..., None], searched, carried)
                 & mask
-                & (prev >= 0)[..., None]
+                & (prev_ >= 0)[..., None]
                 & (u >= 0)
             )
         ctx = EdgeCtx(
-            v=v, u=u, weight=w, deg_v=deg_v,
-            deg_u=du, prev=prev,
-            is_prev_neighbor=ipn, depth=d[..., None],
+            v=v_, u=u, weight=w, deg_v=deg_v_,
+            deg_u=du, prev=prev_,
+            is_prev_neighbor=ipn, depth=d_[..., None],
         )
         return wb.fn(ctx)
 
     return bias_of
+
+
+def _is_hub(x, hubs, num_hubs: int):
+    """Whether each global id in ``x`` is a replicated hub (sorted ``hubs``)."""
+    if not num_hubs:
+        return jnp.zeros(x.shape, bool)
+    pos = jnp.clip(jnp.searchsorted(hubs, x), 0, num_hubs - 1)
+    return (hubs[pos] == x) & (x >= 0)
 
 
 def _selected_deg(iglob, deg_tgt, st, dg, u, steps):
@@ -219,12 +248,12 @@ def _drain_block(
     mesh: Mesh, axis: str, *, spec: SamplingSpec, be: str, num_devices: int,
     num_inst: int, depth: int, cap: int, slots: int, prow_w: int,
     buckets: tuple, use_chunked: bool, rounds: int, range_size: int,
-    num_hubs: int, sub_rounds: int, mh_steps: int, methods: tuple = (),
+    num_hubs: int, sub_rounds: int, search_steps: int, methods: tuple = (),
 ):
     """Build (or fetch) the jitted shard_map drain for one static config."""
     cfg = (mesh, axis, spec, be, num_devices, num_inst, depth, cap, slots,
            prow_w, buckets, use_chunked, rounds, range_size, num_hubs,
-           sub_rounds, mh_steps, methods)
+           sub_rounds, search_steps, methods)
     if cfg in _DRAIN_CACHE:
         return _DRAIN_CACHE[cfg]
     while len(_DRAIN_CACHE) >= _DRAIN_CACHE_MAX:
@@ -243,7 +272,7 @@ def _drain_block(
 
     def body(indptr, iloc, iglob, wts, bias, vlo, prob, alias, rowmax,
              deg_tgt, hubs, qfields, qcount, qdropped, dfields, dcount,
-             walks, key, seeds, limits):
+             walks, key, seeds, limits, wmax):
         indptr, iloc, iglob, wts, bias, vlo0 = (
             indptr[0], iloc[0], iglob[0], wts[0], bias[0], vlo[0]
         )
@@ -287,28 +316,24 @@ def _drain_block(
 
             r0 = _per_entry(key, d, inst, valid, u_draw)
             tail = _per_entry(key, d, inst, valid, tail_draw) if use_chunked else None
+
+            def rej_col(c):  # fold_in(kstep,1) -> fold_in(·,2) -> c
+                def rej_draw(kd):
+                    return jax.random.uniform(
+                        jax.random.fold_in(jax.random.fold_in(
+                            jax.random.fold_in(kd, 1), 2), c),
+                        (num_inst,), dtype=jnp.float32)
+                return _per_entry(key, d, inst, valid, rej_draw)
+
+            def rej_budget(iters):  # the engine's rejection_randoms layout
+                cols = [rej_col(c) for c in range(2 * iters)]
+                return jnp.stack(cols, axis=-1).reshape(cols[0].shape + (iters, 2))
             if mode == "flat" and methods:
                 # adaptive selection (DESIGN.md §13): the plan was computed
                 # from the SAME full-graph bias as the in-memory engine, so
                 # supplying the engine's counted streams (instance-indexed)
                 # keeps the sharded walk bit-identical per method
-                rej = None
-                if use_rej:
-                    def rej_draw(c):
-                        def drawfn(kd):  # fold_in(kstep,1) -> fold_in(·,2) -> c
-                            return jax.random.uniform(
-                                jax.random.fold_in(jax.random.fold_in(
-                                    jax.random.fold_in(kd, 1), 2), c),
-                                (num_inst,), dtype=jnp.float32)
-                        return drawfn
-
-                    cols = [
-                        _per_entry(key, d, inst, valid, rej_draw(c))
-                        for c in range(2 * sel.REJECT_ITERS)
-                    ]
-                    rej = jnp.stack(cols, axis=-1).reshape(
-                        cols[0].shape + (sel.REJECT_ITERS, 2)
-                    )
+                rej = rej_budget(sel.REJECT_ITERS) if use_rej else None
                 u = bk.walk_step_adaptive(
                     key, indptr, iglob, bias, padded, curq,
                     buckets=buckets, use_chunked=use_chunked,
@@ -330,12 +355,18 @@ def _drain_block(
                     )
             else:
                 bias_of = _carried_window_bias(
-                    local, program, v, prev, d, curq, prow, deg_tgt0
+                    local, program, v, prev, d, curq, prow, deg_tgt0,
+                    iglob, rowid(prev), _is_hub(prev, hubs, num_hubs), search_steps,
                 )
+                # the engine's tail envelope, from the FULL graph's weights
+                envelope = tail_rej = None
+                if use_chunked and program.bias.max_ratio is not None:
+                    envelope = window_envelope(program.bias, wmax)
+                    tail_rej = rej_budget(bk.TAIL_REJECT_ITERS)
                 u = bk.walk_step_bucketed_window(
                     key, indptr, iglob, wts, padded, curq, bias_of,
                     buckets=buckets, use_chunked=use_chunked, backend=be,
-                    rand=r0, tail_rand=tail,
+                    rand=r0, tail_rand=tail, envelope=envelope, tail_rej=tail_rej,
                 )
 
             # -- epilogue (engine's fused post-select step, instance-keyed) --
@@ -370,7 +401,7 @@ def _drain_block(
 
                 st_mh = indptr[jnp.maximum(curq, 0)]
                 dg_mh = indptr[jnp.maximum(curq, 0) + 1] - st_mh
-                deg_u = _selected_deg(iglob, deg_tgt0, st_mh, dg_mh, u, mh_steps)
+                deg_u = _selected_deg(iglob, deg_tgt0, st_mh, dg_mh, u, search_steps)
                 acc = _per_entry(key, d, inst, valid, acc_draw)
                 stay = tp.mh_stay(acc, dg_mh, deg_u)
                 nxt = jnp.where(stay & (v >= 0) & (u >= 0), v, u)
@@ -389,10 +420,12 @@ def _drain_block(
             if needs_prev:
                 # the NEXT step's is_prev_neighbor needs N(v): gather v's
                 # row here, the one shard that holds it, and carry it along
+                # — unless v is a hub, whose row every shard holds
                 offs = jnp.arange(prow_w, dtype=jnp.int32)
                 st = indptr[jnp.maximum(curq, 0)]
                 dgv = _degree(local, curq)
-                rmask = (offs[None, :] < dgv[:, None]) & valid[:, None]
+                carry = valid & ~_is_hub(v, hubs, num_hubs)
+                rmask = (offs[None, :] < dgv[:, None]) & carry[:, None]
                 new_entry.append(
                     jnp.where(rmask, iglob[jnp.where(rmask, st[:, None] + offs, 0)], -2)
                 )
@@ -472,7 +505,7 @@ def _drain_block(
         dshard, rep,                                     # deg lane, hub ids
         (dshard,) * nfields, dshard, dshard,             # queue
         (dshard,) * nfields, dshard,                     # deferred
-        rep, rep, rep, rep,                              # walks, key, seeds, limits
+        rep, rep, rep, rep, rep,                # walks, key, seeds, limits, max weight
     )
     out_specs = (
         (dshard,) * nfields, dshard, dshard,
@@ -480,7 +513,7 @@ def _drain_block(
         rep, rep, dshard,
     )
     fn = jax.jit(
-        shard_map_compat(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+        shard_map_unchecked(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
     )
     _DRAIN_CACHE[cfg] = fn
     return fn
@@ -605,9 +638,11 @@ def sharded_random_walk(
     indptr_np = np.asarray(graph.indptr)
     indices_np = np.asarray(graph.indices)
     weights_np = np.asarray(graph.weights)
-    true_max_deg = int(np.diff(indptr_np).max()) if indptr_np.size > 1 else 0
-    prow_w = true_max_deg if needs_prev else 0
-    mh_steps = min(32, max(1, true_max_deg.bit_length())) if use_mh else 1
+    deg_np = np.diff(indptr_np)
+    true_max_deg = int(deg_np.max()) if indptr_np.size > 1 else 0
+    # halvings of a binary search over any row (MH's selected-degree lookup,
+    # the prev-membership search over hub rows)
+    search_steps = min(32, max(1, true_max_deg.bit_length()))
 
     # -- hub selection: replicate the hot top-degree rows on every device ---
     num_edges = int(indices_np.shape[0])
@@ -622,6 +657,13 @@ def sharded_random_walk(
         hb = 0  # single device: everything is already resident
     hubs_np = select_hubs(indptr_np, hb, seg_big)
     num_hubs = int(hubs_np.shape[0])
+    # prev rows travel with their walkers except hub rows, which every shard
+    # holds: the carried width is the widest NON-hub row
+    prow_w = 0
+    if needs_prev:
+        carried = np.ones(deg_np.shape, bool)
+        carried[hubs_np] = False
+        prow_w = max(int(deg_np[carried].max(initial=0)), 1)
 
     # -- materialize shards: common padded shape, global block alignment ----
     pad_v = pm.range_size
@@ -762,6 +804,10 @@ def sharded_random_walk(
     walks = jax.device_put(jnp.asarray(walks0), rep)
     seeds_d = jax.device_put(jnp.asarray(seeds_np), rep)
     limits_d = jax.device_put(jnp.asarray(limits_np), rep)
+    # the window tail's rejection envelope reads the full graph's max weight
+    wmax_d = jax.device_put(
+        jnp.asarray(weights_np.max() if weights_np.size else 0.0, jnp.float32), rep
+    )
     key = jax.device_put(key, rep)
 
     sub = max(int(sub_rounds), 1)
@@ -771,7 +817,7 @@ def sharded_random_walk(
         num_inst=num_inst, depth=depth, cap=cap, slots=slots, prow_w=prow_w,
         buckets=buckets, use_chunked=use_chunked, rounds=max(rounds, 1),
         range_size=pm.range_size, num_hubs=num_hubs, sub_rounds=sub,
-        mh_steps=mh_steps, methods=sel_methods,
+        search_steps=search_steps, methods=sel_methods,
     )
 
     blocks = 0
@@ -781,7 +827,7 @@ def sharded_random_walk(
             indptr_s, iloc_s, iglob_s, wts_s, bias_s, vlo_s,
             prob_s, alias_s, rowmax_s, deg_tgt_s, hubs_d,
             qfields, qcount, qdropped, dfields, dcount,
-            walks, key, seeds_d, limits_d,
+            walks, key, seeds_d, limits_d, wmax_d,
         )
         blocks += 1
         stats_acc += np.sum(
@@ -886,7 +932,7 @@ def replicated_psum_walk(
     hi = jnp.asarray(bounds[1:])
 
     @functools.partial(
-        shard_map_compat,
+        shard_map_unchecked,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis), P(axis), P(), P()),
         out_specs=P(),

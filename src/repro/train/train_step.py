@@ -153,7 +153,7 @@ def _podwise_compressed_grads(params, cfg: ModelConfig, batch, mesh: Mesh):
     infers per-pod shardings instead)."""
 
     @functools.partial(
-        shd.shard_map_compat,
+        shd.shard_map_unchecked,
         mesh=mesh,
         in_specs=(P(), {k: P("pod") for k in batch}),
         out_specs=(P(), P()),

@@ -6,6 +6,7 @@ here are exact array equality — not statistical.
 """
 import jax
 import jax.numpy as jnp
+from jax.extend import core as jex_core
 import numpy as np
 import pytest
 
@@ -154,6 +155,64 @@ class TestEngineBackends:
         assert (walks[:, 1] >= 1).all() and (walks[:, 1] <= hub_deg).all()
         assert (walks[:, 2] == 0).all()  # spokes all point back at the hub
 
+    @staticmethod
+    def _hub_tail_setup(num_walkers):
+        """Every walker on one hub above the top bucket, with a hook whose
+        bias is ``weight * (0.5, 1, 2)[u % 3]`` (so ``max_ratio`` is 2)."""
+        hub_deg = bk.WALK_BUCKETS[-1] + 88
+        nb = np.arange(1, hub_deg + 1)
+        wts = (0.1 + (nb % 7) / 7.0).astype(np.float32)
+        g = csr_from_edges(hub_deg + 1, np.zeros(hub_deg, int), nb, weights=wts)
+        factor = jnp.asarray([0.5, 1.0, 2.0], jnp.float32)
+
+        def bias_of(u, w, mask, eidx=None, rows=None):
+            return jnp.where(mask, w * factor[jnp.maximum(u, 0) % 3], 0.0)
+
+        cur = jnp.zeros((num_walkers,), jnp.int32)
+        start = jnp.zeros_like(cur)
+        deg = jnp.full_like(cur, hub_deg)
+        envelope = 2.0 * jnp.max(g.weights)
+        exact = np.asarray(g.weights) * np.asarray(factor)[np.asarray(g.indices) % 3]
+        return g, bias_of, cur, start, deg, envelope, exact / exact.sum()
+
+    def test_window_tail_rejection_matches_bias(self):
+        """The hub tail's rejection draw picks each neighbour in proportion
+        to its dynamic bias (grouped by the bias's 21 distinct values)."""
+        n = 40000
+        g, bias_of, cur, start, deg, envelope, prob = self._hub_tail_setup(n)
+        nxt = bk._window_tail(
+            KEY, g.indices, g.weights, start, deg, cur >= 0, bias_of,
+            jnp.full_like(cur, -1), envelope=envelope,
+        )
+        picks = np.asarray(nxt)
+        assert (picks >= 1).all()
+        nb = np.asarray(g.indices)
+        group = (nb % 3) * 7 + nb % 7
+        seen = np.bincount(group[picks - 1], minlength=21) / n
+        expect = np.bincount(group, weights=prob, minlength=21)
+        sigma = np.sqrt(expect * (1 - expect) / n)
+        assert np.all(np.abs(seen - expect) < 5 * sigma + 1e-9), (seen, expect)
+
+    def test_window_tail_exhausted_budget_takes_exact_scan(self):
+        """Walkers whose rejection budget runs out fall back to the chunked
+        scan, compacted into rounds: the picks equal the scan's own picks
+        for the same uniforms."""
+        n = 37
+        g, bias_of, cur, start, deg, envelope, _ = self._hub_tail_setup(n)
+        rand = jax.random.uniform(KEY, (n,))
+        # every trial proposes slot 0 (bias 1 * (0.1 + 1/7)) and draws an
+        # accept uniform of 0.99, far above bias / envelope: all rejected
+        rej = jnp.zeros((n, bk.TAIL_REJECT_ITERS, 2), jnp.float32).at[..., 1].set(0.99)
+        via_fallback = bk._window_tail(
+            KEY, g.indices, g.weights, start, deg, cur >= 0, bias_of,
+            jnp.full_like(cur, -1), envelope=envelope, rej=rej, rand=rand,
+        )
+        off = sel.walk_transition_chunked_window(
+            None, start, deg, g.indices, g.weights, bias_of, chunk=bk.CHUNK, rand=rand
+        )
+        expect = np.asarray(g.indices)[np.asarray(off)]
+        np.testing.assert_array_equal(np.asarray(via_fallback), expect)
+
     def test_walk_understated_max_degree_keeps_hub_walkers(self):
         """Regression: the bucket plan must not shrink its top segment on the
         caller's (possibly understated) max_degree — a deg-400 hub with
@@ -184,11 +243,13 @@ class TestEngineBackends:
 
 def _rank2_trailing_dims(jaxpr, dims):
     """Collect the trailing dim of every rank>=2 aval, recursing into nested
-    jaxprs (pjit/scan/cond/pallas_call bodies)."""
+    jaxprs (pjit/scan/cond/pallas_call bodies).  A flat array viewed as one
+    ``(1, E)`` row (how the walk kernels take CSR edge arrays) is still a
+    flat array, like the rank-1 ones, and is skipped."""
     for eqn in jaxpr.eqns:
         for v in list(eqn.invars) + list(eqn.outvars):
             shape = getattr(getattr(v, "aval", None), "shape", ())
-            if len(shape) >= 2:
+            if len(shape) >= 2 and any(d != 1 for d in shape[:-1]):
                 dims.append(int(shape[-1]))
         for val in eqn.params.values():
             for sub in _subjaxprs(val):
@@ -197,9 +258,9 @@ def _rank2_trailing_dims(jaxpr, dims):
 
 
 def _subjaxprs(val):
-    if isinstance(val, jax.core.ClosedJaxpr):
+    if isinstance(val, jex_core.ClosedJaxpr):
         return [val.jaxpr]
-    if isinstance(val, jax.core.Jaxpr):
+    if isinstance(val, jex_core.Jaxpr):
         return [val]
     if isinstance(val, (list, tuple)):
         return [j for v in val for j in _subjaxprs(v)]

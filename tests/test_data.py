@@ -1,5 +1,6 @@
 """Data plane: walk corpus (C-SAW as the LM data pipeline) + graph substrate."""
 import jax
+from jax.sharding import AxisType
 import numpy as np
 import pytest
 
@@ -92,7 +93,7 @@ class TestWalkCorpus:
         corpus = build_walk_corpus(g, num_walks=8, walk_length=16, seed=2, vocab_size=256)
         cfg = get_smoke_config("xlstm_350m")  # vocab 256
         pipe = TokenPipeline(cfg.vocab_size, 8, 16, corpus=corpus)
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         ocfg = OptConfig(kind="adamw", lr=3e-3, warmup_steps=2)
         params = init_params(jax.random.PRNGKey(0), cfg)
         opt_state = opt_init(ocfg, params)

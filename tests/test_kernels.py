@@ -11,7 +11,8 @@ import pytest
 from repro.graph import powerlaw_graph
 from repro.kernels import ops
 from repro.kernels.its_select import its_select_pallas
-from repro.kernels.ref import its_select_ref, walk_step_ref
+from repro.kernels.common import pick_lane, prefix_sum
+from repro.kernels.ref import its_select_ref, walk_step_block_ref
 from repro.kernels.walk_step import pad_csr_for_kernel, walk_step_pallas
 
 
@@ -75,7 +76,7 @@ class TestWalkStepKernel:
         inds, wts = pad_csr_for_kernel(g.indices, g.weights, max_seg)
         rand = jax.random.uniform(jax.random.fold_in(key, 1), (64,))
         out_k = walk_step_pallas(starts, degs, inds, wts, rand, max_seg=max_seg)
-        out_r = walk_step_ref(starts, degs, inds, wts, rand, max_seg)
+        out_r = walk_step_block_ref(starts, degs, inds, wts, rand, seg=max_seg)
         np.testing.assert_array_equal(np.asarray(out_k), np.asarray(out_r))
 
     @pytest.mark.parametrize("wdtype", [jnp.float32, jnp.bfloat16])
@@ -88,7 +89,7 @@ class TestWalkStepKernel:
         inds, wts = pad_csr_for_kernel(g.indices, g.weights.astype(wdtype), 64)
         rand = jax.random.uniform(jax.random.fold_in(key, 1), (32,))
         out_k = walk_step_pallas(starts, degs, inds, wts.astype(jnp.float32), rand, max_seg=64)
-        out_r = walk_step_ref(starts, degs, inds, wts.astype(jnp.float32), rand, 64)
+        out_r = walk_step_block_ref(starts, degs, inds, wts.astype(jnp.float32), rand, seg=64)
         np.testing.assert_array_equal(np.asarray(out_k), np.asarray(out_r))
 
     def test_next_vertices_are_neighbors(self):
@@ -109,3 +110,34 @@ class TestWalkStepKernel:
         key = jax.random.PRNGKey(12)
         nxt = ops.walk_step(key, g, jnp.array([0, 1], jnp.int32), max_seg=64)
         assert int(nxt[0]) == -1 and int(nxt[1]) >= 0
+
+    def test_oracle_follows_kernel_sum_order(self):
+        """Regression: the oracle must sum in the kernel's order, not
+        ``jnp.cumsum``'s.  One heavy edge followed by 127 edges each below
+        half an ulp of it: a running sum drops every light edge, the
+        log-step tree keeps their pairwise sums, so the two orders pick
+        different neighbours for the same uniform."""
+        seg = 128
+        wts = jnp.concatenate([jnp.ones(1), jnp.full(seg - 1, 4e-8)]).astype(jnp.float32)
+        inds = jnp.arange(seg, dtype=jnp.int32) + 1000
+        inds_p, wts_p = pad_csr_for_kernel(inds, wts, seg)
+
+        def old_pick(r):
+            cum = jnp.cumsum(wts)
+            return int(jnp.sum(cum <= r * cum[-1]))
+
+        def new_pick(r):
+            cum = prefix_sum(wts)
+            return int(jnp.sum(cum <= r * pick_lane(cum, seg - 1)[0]))
+
+        rands = jnp.linspace(0.999990, 0.9999999, 64, dtype=jnp.float32)
+        split = [r for r in rands if old_pick(r) != new_pick(r)]
+        assert split, "constructed row no longer separates the two orders"
+        rand = jnp.full((8,), split[0], jnp.float32)
+        starts = jnp.zeros((8,), jnp.int32)
+        degs = jnp.full((8,), seg, jnp.int32)
+        out_k = walk_step_pallas(starts, degs, inds_p, wts_p, rand, max_seg=seg)
+        out_r = walk_step_block_ref(starts, degs, inds_p, wts_p, rand, seg=seg)
+        np.testing.assert_array_equal(np.asarray(out_k), np.asarray(out_r))
+        assert int(out_k[0]) == 1000 + min(new_pick(split[0]), seg - 1)
+        assert int(out_k[0]) != 1000 + min(old_pick(split[0]), seg - 1)

@@ -114,6 +114,14 @@ class TestAliasBuild:
         pmf = _reconstruct_pmf(indptr, bias, prob, alias)
         np.testing.assert_allclose(pmf[2:], [0.0, 1.0, 0.0], atol=1e-7)
 
+    def test_subnormal_row_total(self):
+        """Regression: a row whose total is subnormal (``deg / total``
+        overflows float64) still builds a finite, exact table."""
+        rows = [[1e-310, 2e-310, 0.0], [1e-320, 3e-320, 2e-320], [2.0, 6.0]]
+        prob, _ = sel.build_alias(*_csr_from_rows(rows))
+        assert np.isfinite(prob).all()
+        _check_rows(rows)
+
     def test_reconstruction_hypothesis(self):
         pytest.importorskip("hypothesis")
         from hypothesis import given, settings, strategies as st
@@ -138,10 +146,17 @@ class TestAliasBuild:
         def prop(rows):
             indptr, bias = _csr_from_rows(rows)
             rm = sel.build_row_max(indptr, bias)
-            expect = [max(r) if r else 0.0 for r in rows]
-            np.testing.assert_allclose(rm, expect)
+            # the envelope is float32 by contract: compare with the f32 max
+            expect = np.asarray([max(r) if r else 0.0 for r in rows], np.float32)
+            np.testing.assert_array_equal(rm, expect)
 
         prop()
+
+    def test_row_max_before_trailing_empty_row(self):
+        """Regression: the row before a trailing empty row reads its own whole
+        segment (a clamped start used to cut it to one edge)."""
+        indptr, bias = _csr_from_rows([[1.0], [2.0, 5.0], []])
+        np.testing.assert_array_equal(sel.build_row_max(indptr, bias), [1.0, 5.0, 0.0])
 
 
 class TestCostModel:

@@ -444,6 +444,37 @@ print(json.dumps(out))
 
 
 @pytest.mark.slow
+def test_sharded_node2vec_hub_prev_membership():
+    """A hub ``prev`` is not carried: its membership is searched in the
+    replicated hub row on whichever shard the walker is on.  Hub 0 is joined
+    to every spoke and the spokes form a ring, so after 0 -> i both ring
+    neighbours of i are neighbours of prev — a wrong search changes their
+    bias, and the walks must still equal single-device ones."""
+    d = run_child(HEADER + """
+from repro.graph import csr_from_edges
+from repro.core import algorithms as alg
+from repro.core.engine import random_walk
+from repro.shard import sharded_random_walk
+V = 1200
+spokes = np.arange(1, V)
+src = np.concatenate([np.zeros(V - 1, int), spokes])
+dst = np.concatenate([spokes, np.roll(spokes, 1)])
+g = csr_from_edges(V, src, dst, symmetrize=True)
+md = g.max_degree()
+seeds = jnp.zeros((64,), jnp.int32)
+key = jax.random.PRNGKey(5)
+mesh = jax.make_mesh((8,), ("data",))
+spec = alg.node2vec()
+ref = random_walk(g, seeds, key, depth=8, spec=spec, max_degree=md, backend="reference")
+res = sharded_random_walk(mesh, g, seeds, key, depth=8, spec=spec, max_degree=md,
+                          backend="reference", hub_bytes=10**6)
+print(json.dumps({"hubs": int(res.stats["num_hubs"]),
+                  "equal": bool(jnp.array_equal(ref.walks, res.walks))}))
+""")
+    assert d["hubs"] >= 1 and d["equal"], d
+
+
+@pytest.mark.slow
 def test_sharded_service_cohorts():
     """placement="sharded": heterogeneous request cohorts drain through the
     mesh, return exact per-request geometry, walk real edges, and are

@@ -1,17 +1,16 @@
 """Sharding rules: divisibility fallback, cache specs, mesh construction."""
 import jax
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
-from repro.distributed.sharding import abstract_mesh, cache_spec, default_rules, spec_for
+from repro.distributed.sharding import cache_spec, default_rules, spec_for
 from repro.train.fault import largest_mesh_shape
 
 
 @pytest.fixture(scope="module")
 def mesh():
     # abstract 16x16 mesh over 1 real device is fine for spec logic tests
-    # (abstract_mesh absorbs the AbstractMesh API drift across JAX versions)
-    return abstract_mesh(("data", "model"), (16, 16))
+    return AbstractMesh(axis_sizes=(16, 16), axis_names=("data", "model"))
 
 
 class TestSpecFor:

@@ -2,6 +2,7 @@
 import os
 
 import jax
+from jax.sharding import AxisType
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -55,7 +56,7 @@ class TestOptimizers:
 class TestTrainingLoop:
     def test_loss_decreases_on_learnable_data(self):
         cfg = get_smoke_config("internlm2-1.8b")
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         ocfg = OptConfig(kind="adamw", lr=3e-3, warmup_steps=2)
         params = init_params(KEY, cfg)
         opt_state = opt_init(ocfg, params)
