@@ -27,6 +27,7 @@ from typing import Literal, Mapping
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import select as sel
 from repro.kernels import ref
 from repro.kernels.alias_select import alias_step_pallas
@@ -245,51 +246,53 @@ def walk_step_bucketed(
     DESIGN.md §12); the default draws stay ``fold_in(key, 0)`` /
     ``fold_in(key, 1)``.
     """
-    safe = jnp.maximum(cur, 0)
-    starts = indptr[safe]
-    deg = jnp.where(cur >= 0, indptr[safe + 1] - starts, 0)
-    if rand is None:
-        rand = jax.random.uniform(jax.random.fold_in(key, 0), cur.shape, dtype=jnp.float32)
-    r = rand
+    with obs.scope("walk.select"):
+        safe = jnp.maximum(cur, 0)
+        starts = indptr[safe]
+        deg = jnp.where(cur >= 0, indptr[safe + 1] - starts, 0)
+        if rand is None:
+            rand = jax.random.uniform(jax.random.fold_in(key, 0), cur.shape, dtype=jnp.float32)
+        r = rand
 
-    nxt = jnp.full_like(cur, -1)
-    lo = 0
-    for i, seg in enumerate(buckets):
-        inds_p, bias_p = padded[seg]
-        # understated max_degree degrades to NEIGHBORHOOD TRUNCATION (the
-        # dense-gather contract), never silent walker death: without a
-        # chunked tail the top cohort absorbs any larger degree, capped at
-        # its window (same policy as the window scheduler below)
-        absorb = i == len(buckets) - 1 and not use_chunked
-        inb = (deg > lo) & ((deg <= seg) | absorb)
-        cand = walk_step_pallas(
-            jnp.where(inb, starts, 0),
-            jnp.where(inb, jnp.minimum(deg, seg), 0),
-            inds_p,
-            bias_p,
-            r,
-            max_seg=seg,
-            interpret=interpret,
-        )
-        nxt = jnp.where(inb, cand, nxt)
-        lo = seg
+        nxt = jnp.full_like(cur, -1)
+        lo = 0
+        for i, seg in enumerate(buckets):
+            inds_p, bias_p = padded[seg]
+            # understated max_degree degrades to NEIGHBORHOOD TRUNCATION (the
+            # dense-gather contract), never silent walker death: without a
+            # chunked tail the top cohort absorbs any larger degree, capped at
+            # its window (same policy as the window scheduler below)
+            absorb = i == len(buckets) - 1 and not use_chunked
+            inb = (deg > lo) & ((deg <= seg) | absorb)
+            cand = walk_step_pallas(
+                jnp.where(inb, starts, 0),
+                jnp.where(inb, jnp.minimum(deg, seg), 0),
+                inds_p,
+                bias_p,
+                r,
+                max_seg=seg,
+                interpret=interpret,
+            )
+            nxt = jnp.where(inb, cand, nxt)
+            lo = seg
 
-    if use_chunked:
-        nxt = _chunked_tail(
-            jax.random.fold_in(key, 1), indptr, indices, flat_bias, safe, deg, buckets[-1], nxt,
-            rand=tail_rand,
-        )
-    return nxt
+        if use_chunked:
+            nxt = _chunked_tail(
+                jax.random.fold_in(key, 1), indptr, indices, flat_bias, safe, deg, buckets[-1], nxt,
+                rand=tail_rand,
+            )
+        return nxt
 
 
 def _chunked_tail(key, indptr, indices, flat_bias, safe, deg, seg_hi, nxt, rand=None):
     """Route walkers with ``deg > seg_hi`` through the two-pass chunked scan."""
-    huge = deg > seg_hi
-    safe_cur = jnp.where(huge, safe, 0)
-    off = sel.walk_transition_chunked(key, indptr, flat_bias, safe_cur, chunk=CHUNK, rand=rand)
-    eidx = jnp.clip(indptr[safe_cur] + jnp.maximum(off, 0), 0, indices.shape[0] - 1)
-    cand = jnp.where(off >= 0, indices[eidx], -1)
-    return jnp.where(huge, cand, nxt)
+    with obs.scope("walk.hub_tail"):
+        huge = deg > seg_hi
+        safe_cur = jnp.where(huge, safe, 0)
+        off = sel.walk_transition_chunked(key, indptr, flat_bias, safe_cur, chunk=CHUNK, rand=rand)
+        eidx = jnp.clip(indptr[safe_cur] + jnp.maximum(off, 0), 0, indices.shape[0] - 1)
+        cand = jnp.where(off >= 0, indices[eidx], -1)
+        return jnp.where(huge, cand, nxt)
 
 
 def walk_step_flat_reference(
@@ -320,35 +323,36 @@ def walk_step_flat_reference(
     window shrinks from ``2*seg`` to ``seg + min(seg, max_degree)`` without
     changing any prefix up to the row's end.
     """
-    safe = jnp.maximum(cur, 0)
-    starts = indptr[safe]
-    deg = jnp.where(cur >= 0, indptr[safe + 1] - starts, 0)
-    if rand is None:
-        rand = jax.random.uniform(jax.random.fold_in(key, 0), cur.shape, dtype=jnp.float32)
-    r = rand
+    with obs.scope("walk.select"):
+        safe = jnp.maximum(cur, 0)
+        starts = indptr[safe]
+        deg = jnp.where(cur >= 0, indptr[safe + 1] - starts, 0)
+        if rand is None:
+            rand = jax.random.uniform(jax.random.fold_in(key, 0), cur.shape, dtype=jnp.float32)
+        r = rand
 
-    nxt = jnp.full_like(cur, -1)
-    lo = 0
-    for i, seg in enumerate(buckets):
-        inds_p, bias_p = padded[seg]
-        # same truncation-absorb policy as walk_step_bucketed — the two must
-        # mirror each other bit-for-bit
-        absorb = i == len(buckets) - 1 and not use_chunked
-        inb = (deg > lo) & ((deg <= seg) | absorb)
-        width = 2 * seg if max_degree is None else seg + min(seg, max_degree)
-        cand = ref.walk_step_block_ref(
-            jnp.where(inb, starts, 0), jnp.where(inb, jnp.minimum(deg, seg), 0),
-            inds_p, bias_p, r, seg=seg, width=width,
-        )
-        nxt = jnp.where(inb, cand, nxt)
-        lo = seg
+        nxt = jnp.full_like(cur, -1)
+        lo = 0
+        for i, seg in enumerate(buckets):
+            inds_p, bias_p = padded[seg]
+            # same truncation-absorb policy as walk_step_bucketed — the two must
+            # mirror each other bit-for-bit
+            absorb = i == len(buckets) - 1 and not use_chunked
+            inb = (deg > lo) & ((deg <= seg) | absorb)
+            width = 2 * seg if max_degree is None else seg + min(seg, max_degree)
+            cand = ref.walk_step_block_ref(
+                jnp.where(inb, starts, 0), jnp.where(inb, jnp.minimum(deg, seg), 0),
+                inds_p, bias_p, r, seg=seg, width=width,
+            )
+            nxt = jnp.where(inb, cand, nxt)
+            lo = seg
 
-    if use_chunked:
-        nxt = _chunked_tail(
-            jax.random.fold_in(key, 1), indptr, indices, flat_bias, safe, deg, buckets[-1], nxt,
-            rand=tail_rand,
-        )
-    return nxt
+        if use_chunked:
+            nxt = _chunked_tail(
+                jax.random.fold_in(key, 1), indptr, indices, flat_bias, safe, deg, buckets[-1], nxt,
+                rand=tail_rand,
+            )
+        return nxt
 
 
 # ---------------------------------------------------------------------------
@@ -403,94 +407,95 @@ def walk_step_adaptive(
     removing the two-pass chunked scan from hub vertices entirely; only an
     ITS tail still scans.
     """
-    safe = jnp.maximum(cur, 0)
-    starts = indptr[safe]
-    deg = jnp.where(cur >= 0, indptr[safe + 1] - starts, 0)
-    if rand is None:
-        rand = jax.random.uniform(jax.random.fold_in(key, 0), cur.shape, dtype=jnp.float32)
-    r = rand
-    if any(m == "rejection" for m in methods) and rej_rand is None:
-        rej_rand = sel.rejection_randoms(jax.random.fold_in(key, 2), cur.shape)
-    rmv = None
-    if tables.row_max is not None:
-        rmv = jnp.where(cur >= 0, tables.row_max[safe], 0.0)
-    pal = backend == "pallas"
-    tables_p = None
-    if pal and any(m == "alias" for m in methods):
-        # one padding to the largest segment serves every alias cohort (the
-        # same geometry argument as pad_walk_csr); pad values are never read
-        # for real rows
-        a_pad, p_pad = pad_csr_for_kernel(tables.alias, tables.prob, max(buckets))
-        tables_p = (p_pad, a_pad)
+    with obs.scope("walk.select"):
+        safe = jnp.maximum(cur, 0)
+        starts = indptr[safe]
+        deg = jnp.where(cur >= 0, indptr[safe + 1] - starts, 0)
+        if rand is None:
+            rand = jax.random.uniform(jax.random.fold_in(key, 0), cur.shape, dtype=jnp.float32)
+        r = rand
+        if any(m == "rejection" for m in methods) and rej_rand is None:
+            rej_rand = sel.rejection_randoms(jax.random.fold_in(key, 2), cur.shape)
+        rmv = None
+        if tables.row_max is not None:
+            rmv = jnp.where(cur >= 0, tables.row_max[safe], 0.0)
+        pal = backend == "pallas"
+        tables_p = None
+        if pal and any(m == "alias" for m in methods):
+            # one padding to the largest segment serves every alias cohort (the
+            # same geometry argument as pad_walk_csr); pad values are never read
+            # for real rows
+            a_pad, p_pad = pad_csr_for_kernel(tables.alias, tables.prob, max(buckets))
+            tables_p = (p_pad, a_pad)
 
-    nxt = jnp.full_like(cur, -1)
-    lo = 0
-    for i, seg in enumerate(buckets):
-        inds_p, bias_p = padded[seg]
-        # same truncation-absorb policy as walk_step_bucketed: an understated
-        # max_degree degrades to neighborhood truncation (cap = seg inside
-        # each draw), never silent walker death
-        absorb = i == len(buckets) - 1 and not use_chunked
-        inb = (deg > lo) & ((deg <= seg) | absorb)
-        st = jnp.where(inb, starts, 0)
-        dg = jnp.where(inb, deg, 0)
-        m = methods[i]
-        if m == "alias":
-            if pal:
-                cand = alias_step_pallas(
-                    st, dg, inds_p, tables_p[0], tables_p[1], r,
+        nxt = jnp.full_like(cur, -1)
+        lo = 0
+        for i, seg in enumerate(buckets):
+            inds_p, bias_p = padded[seg]
+            # same truncation-absorb policy as walk_step_bucketed: an understated
+            # max_degree degrades to neighborhood truncation (cap = seg inside
+            # each draw), never silent walker death
+            absorb = i == len(buckets) - 1 and not use_chunked
+            inb = (deg > lo) & ((deg <= seg) | absorb)
+            st = jnp.where(inb, starts, 0)
+            dg = jnp.where(inb, deg, 0)
+            m = methods[i]
+            if m == "alias":
+                if pal:
+                    cand = alias_step_pallas(
+                        st, dg, inds_p, tables_p[0], tables_p[1], r,
+                        max_seg=seg, interpret=interpret,
+                    )
+                else:
+                    cand = sel.alias_draw_flat(
+                        st, dg, tables.prob, tables.alias, indices, r, cap=seg
+                    )
+            elif m == "rejection":
+                if pal:
+                    cand = reject_step_pallas(
+                        st, dg, inds_p, bias_p, rmv, rej_rand,
+                        max_seg=seg, interpret=interpret,
+                    )
+                else:
+                    cand = sel.rejection_draw_flat(
+                        st, dg, flat_bias, rmv, indices, rej_rand, cap=seg
+                    )
+            elif pal:
+                cand = walk_step_pallas(
+                    st, jnp.minimum(dg, seg), inds_p, bias_p, r,
                     max_seg=seg, interpret=interpret,
                 )
             else:
-                cand = sel.alias_draw_flat(
-                    st, dg, tables.prob, tables.alias, indices, r, cap=seg
+                width = 2 * seg if max_degree is None else seg + min(seg, max_degree)
+                cand = ref.walk_step_block_ref(
+                    st, jnp.minimum(dg, seg), inds_p, bias_p, r, seg=seg, width=width
                 )
-        elif m == "rejection":
-            if pal:
-                cand = reject_step_pallas(
-                    st, dg, inds_p, bias_p, rmv, rej_rand,
-                    max_seg=seg, interpret=interpret,
-                )
-            else:
-                cand = sel.rejection_draw_flat(
-                    st, dg, flat_bias, rmv, indices, rej_rand, cap=seg
-                )
-        elif pal:
-            cand = walk_step_pallas(
-                st, jnp.minimum(dg, seg), inds_p, bias_p, r,
-                max_seg=seg, interpret=interpret,
-            )
-        else:
-            width = 2 * seg if max_degree is None else seg + min(seg, max_degree)
-            cand = ref.walk_step_block_ref(
-                st, jnp.minimum(dg, seg), inds_p, bias_p, r, seg=seg, width=width
-            )
-        nxt = jnp.where(inb, cand, nxt)
-        lo = seg
+            nxt = jnp.where(inb, cand, nxt)
+            lo = seg
 
-    if use_chunked:
-        huge = deg > buckets[-1]
-        st = jnp.where(huge, starts, 0)
-        dg = jnp.where(huge, deg, 0)
-        mt = methods[len(buckets)]
-        if mt == "alias":
-            if tail_rand is None:
-                tail_rand = jax.random.uniform(
-                    jax.random.fold_in(key, 1), cur.shape, dtype=jnp.float32
-                )
-            cand = sel.alias_draw_flat(
-                st, dg, tables.prob, tables.alias, indices, tail_rand
-            )
-            nxt = jnp.where(huge, cand, nxt)
-        elif mt == "rejection":
-            cand = sel.rejection_draw_flat(st, dg, flat_bias, rmv, indices, rej_rand)
-            nxt = jnp.where(huge, cand, nxt)
-        else:
+        mt = methods[len(buckets)] if use_chunked else None
+        if mt in ("alias", "rejection"):
+            with obs.scope("walk.hub_tail"):
+                huge = deg > buckets[-1]
+                st = jnp.where(huge, starts, 0)
+                dg = jnp.where(huge, deg, 0)
+                if mt == "alias":
+                    if tail_rand is None:
+                        tail_rand = jax.random.uniform(
+                            jax.random.fold_in(key, 1), cur.shape, dtype=jnp.float32
+                        )
+                    cand = sel.alias_draw_flat(
+                        st, dg, tables.prob, tables.alias, indices, tail_rand
+                    )
+                else:
+                    cand = sel.rejection_draw_flat(st, dg, flat_bias, rmv, indices, rej_rand)
+                nxt = jnp.where(huge, cand, nxt)
+        elif use_chunked:
             nxt = _chunked_tail(
                 jax.random.fold_in(key, 1), indptr, indices, flat_bias, safe, deg,
                 buckets[-1], nxt, rand=tail_rand,
             )
-    return nxt
+        return nxt
 
 
 # ---------------------------------------------------------------------------
@@ -566,86 +571,87 @@ def walk_step_bucketed_window(
     None).  Returns next vertices (W,) int32; -1 for finished walkers and
     dead ends.
     """
-    safe = jnp.maximum(cur, 0)
-    starts = indptr[safe]
-    deg = jnp.where(cur >= 0, indptr[safe + 1] - starts, 0)
-    if rand is None:
-        rand = jax.random.uniform(jax.random.fold_in(key, 0), cur.shape, dtype=jnp.float32)
-    r = rand
+    with obs.scope("walk.select"):
+        safe = jnp.maximum(cur, 0)
+        starts = indptr[safe]
+        deg = jnp.where(cur >= 0, indptr[safe + 1] - starts, 0)
+        if rand is None:
+            rand = jax.random.uniform(jax.random.fold_in(key, 0), cur.shape, dtype=jnp.float32)
+        r = rand
 
-    # each walker's cohort: its bucket, or len(buckets) for the tail and for
-    # finished walkers; an understated max_degree (possible in-memory, where
-    # the caller's bound is trusted for the exact bucket plan) degrades to
-    # NEIGHBORHOOD TRUNCATION — the dense-gather path's contract — never
-    # silent walker death: without a chunked tail the top cohort absorbs any
-    # larger degree, capped at its window
-    cohort = jnp.full(cur.shape, len(buckets), jnp.int32)
-    lo = 0
-    for i, seg in enumerate(buckets):
-        absorb = i == len(buckets) - 1 and not use_chunked
-        cohort = jnp.where((deg > lo) & ((deg <= seg) | absorb), i, cohort)
-        lo = seg
-    # cohorts are evaluated compacted, WINDOW_TILE walkers per round, so a
-    # walker pays only for its own cohort's window width
-    w = cur.shape[-1]
-    k = min(WINDOW_TILE, w)
-    order = jnp.argsort(cohort, stable=True)
-    nxt = jnp.full_like(cur, -1)
-    begin = jnp.zeros((), jnp.int32)
-    for i, seg in enumerate(buckets):
-        inds_p, wts_p = padded[seg]
-        n = jnp.sum((cohort == i).astype(jnp.int32))
+        # each walker's cohort: its bucket, or len(buckets) for the tail and for
+        # finished walkers; an understated max_degree (possible in-memory, where
+        # the caller's bound is trusted for the exact bucket plan) degrades to
+        # NEIGHBORHOOD TRUNCATION — the dense-gather path's contract — never
+        # silent walker death: without a chunked tail the top cohort absorbs any
+        # larger degree, capped at its window
+        cohort = jnp.full(cur.shape, len(buckets), jnp.int32)
+        lo = 0
+        for i, seg in enumerate(buckets):
+            absorb = i == len(buckets) - 1 and not use_chunked
+            cohort = jnp.where((deg > lo) & ((deg <= seg) | absorb), i, cohort)
+            lo = seg
+        # cohorts are evaluated compacted, WINDOW_TILE walkers per round, so a
+        # walker pays only for its own cohort's window width
+        w = cur.shape[-1]
+        k = min(WINDOW_TILE, w)
+        order = jnp.argsort(cohort, stable=True)
+        nxt = jnp.full_like(cur, -1)
+        begin = jnp.zeros((), jnp.int32)
+        for i, seg in enumerate(buckets):
+            inds_p, wts_p = padded[seg]
+            n = jnp.sum((cohort == i).astype(jnp.int32))
 
-        def tile(carry, seg=seg, inds_p=inds_p, wts_p=wts_p, begin=begin, n=n):
-            j, nxt = carry
-            pos = j * k + jnp.arange(k, dtype=jnp.int32)
-            live = pos < n
-            rows = order[jnp.minimum(begin + pos, w - 1)]
-            st = jnp.where(live, starts[rows], 0)
-            dg = jnp.where(live, jnp.minimum(deg[rows], seg), 0)
-            # compact row-aligned windows for the hook (row fits: dg <= seg,
-            # and the padded arrays keep a spare trailing block, so st+seg is
-            # safe)
-            offs_c = jnp.arange(seg, dtype=jnp.int32)
-            cmask = offs_c < dg[..., None]
-            ceidx = st[..., None] + offs_c
-            u_c = jnp.where(cmask, inds_p[ceidx], -1)
-            w_c = jnp.where(cmask, wts_p[ceidx], 0.0)
-            # the hook also receives the window's edge positions (``ceidx``)
-            # so per-edge side lanes (the sharded drain's replicated degree
-            # lane) can be gathered without row lookups; in-memory hooks
-            # ignore it
-            bias_c = jnp.where(
-                cmask, jnp.maximum(bias_of(u_c, w_c, cmask, ceidx, rows=rows), 0.0), 0.0
+            def tile(carry, seg=seg, inds_p=inds_p, wts_p=wts_p, begin=begin, n=n):
+                j, nxt = carry
+                pos = j * k + jnp.arange(k, dtype=jnp.int32)
+                live = pos < n
+                rows = order[jnp.minimum(begin + pos, w - 1)]
+                st = jnp.where(live, starts[rows], 0)
+                dg = jnp.where(live, jnp.minimum(deg[rows], seg), 0)
+                # compact row-aligned windows for the hook (row fits: dg <= seg,
+                # and the padded arrays keep a spare trailing block, so st+seg is
+                # safe)
+                offs_c = jnp.arange(seg, dtype=jnp.int32)
+                cmask = offs_c < dg[..., None]
+                ceidx = st[..., None] + offs_c
+                u_c = jnp.where(cmask, inds_p[ceidx], -1)
+                w_c = jnp.where(cmask, wts_p[ceidx], 0.0)
+                # the hook also receives the window's edge positions (``ceidx``)
+                # so per-edge side lanes (the sharded drain's replicated degree
+                # lane) can be gathered without row lookups; in-memory hooks
+                # ignore it
+                bias_c = jnp.where(
+                    cmask, jnp.maximum(bias_of(u_c, w_c, cmask, ceidx, rows=rows), 0.0), 0.0
+                )
+                # re-align to the kernel's 2-block window at offset start % seg
+                # (same geometry the reference pick uses — shared helper keeps
+                # the bit-parity contract in one place)
+                local, _, offs, mask = ref._block_window(st, dg, seg, 2 * seg)
+                src = jnp.clip(offs - local[..., None], 0, seg - 1)
+                bias_win = jnp.where(mask, jnp.take_along_axis(bias_c, src, axis=-1), 0.0)
+                if backend == "pallas":
+                    cand = walk_step_window_pallas(
+                        st, dg, inds_p, bias_win, r[rows], max_seg=seg, interpret=interpret
+                    )
+                else:
+                    cand = ref.walk_step_window_block_ref(
+                        st, dg, inds_p, bias_win, r[rows], seg=seg
+                    )
+                # out-of-range index for idle slots: a clipped row may repeat
+                return j + 1, nxt.at[jnp.where(live, rows, w)].set(cand, mode="drop")
+
+            _, nxt = jax.lax.while_loop(
+                lambda c, n=n: c[0] * k < n, tile, (jnp.zeros((), jnp.int32), nxt)
             )
-            # re-align to the kernel's 2-block window at offset start % seg
-            # (same geometry the reference pick uses — shared helper keeps
-            # the bit-parity contract in one place)
-            local, _, offs, mask = ref._block_window(st, dg, seg, 2 * seg)
-            src = jnp.clip(offs - local[..., None], 0, seg - 1)
-            bias_win = jnp.where(mask, jnp.take_along_axis(bias_c, src, axis=-1), 0.0)
-            if backend == "pallas":
-                cand = walk_step_window_pallas(
-                    st, dg, inds_p, bias_win, r[rows], max_seg=seg, interpret=interpret
-                )
-            else:
-                cand = ref.walk_step_window_block_ref(
-                    st, dg, inds_p, bias_win, r[rows], seg=seg
-                )
-            # out-of-range index for idle slots: a clipped row may repeat
-            return j + 1, nxt.at[jnp.where(live, rows, w)].set(cand, mode="drop")
+            begin = begin + n
 
-        _, nxt = jax.lax.while_loop(
-            lambda c, n=n: c[0] * k < n, tile, (jnp.zeros((), jnp.int32), nxt)
-        )
-        begin = begin + n
-
-    if use_chunked:
-        nxt = _window_tail(
-            key, indices, weights, starts, deg, deg > buckets[-1], bias_of, nxt,
-            envelope=envelope, rand=tail_rand, rej=tail_rej,
-        )
-    return nxt
+        if use_chunked:
+            nxt = _window_tail(
+                key, indices, weights, starts, deg, deg > buckets[-1], bias_of, nxt,
+                envelope=envelope, rand=tail_rand, rej=tail_rej,
+            )
+        return nxt
 
 
 def _window_tail(key, indices, weights, starts, deg, huge, bias_of, nxt, *,
@@ -661,44 +667,45 @@ def _window_tail(key, indices, weights, starts, deg, huge, bias_of, nxt, *,
     walkers per round after a rejection stage and all of them in one round
     otherwise.  Each round loops only to the widest row among its walkers.
     """
-    pending = huge
-    if envelope is not None:
-        if rej is None:
-            rej = sel.rejection_randoms(
-                jax.random.fold_in(key, 2), huge.shape, iters=TAIL_REJECT_ITERS
+    with obs.scope("walk.hub_tail"):
+        pending = huge
+        if envelope is not None:
+            if rej is None:
+                rej = sel.rejection_randoms(
+                    jax.random.fold_in(key, 2), huge.shape, iters=TAIL_REJECT_ITERS
+                )
+            cand, acc = sel.window_rejection_draw(
+                jnp.where(huge, starts, 0), jnp.where(huge, deg, 0), indices, weights,
+                bias_of, envelope, rej,
             )
-        cand, acc = sel.window_rejection_draw(
-            jnp.where(huge, starts, 0), jnp.where(huge, deg, 0), indices, weights,
-            bias_of, envelope, rej,
-        )
-        nxt = jnp.where(huge & acc, cand, nxt)
-        pending = huge & ~acc
-    if rand is None:
-        rand = jax.random.uniform(jax.random.fold_in(key, 1), huge.shape, dtype=jnp.float32)
-    w = huge.shape[-1]
-    k = min(TAIL_SCAN_ROUND, w) if envelope is not None else w
-    order = jnp.argsort(jnp.where(pending, -deg, 1))  # pending first, widest first
-    npend = jnp.sum(pending.astype(jnp.int32))
+            nxt = jnp.where(huge & acc, cand, nxt)
+            pending = huge & ~acc
+        if rand is None:
+            rand = jax.random.uniform(jax.random.fold_in(key, 1), huge.shape, dtype=jnp.float32)
+        w = huge.shape[-1]
+        k = min(TAIL_SCAN_ROUND, w) if envelope is not None else w
+        order = jnp.argsort(jnp.where(pending, -deg, 1))  # pending first, widest first
+        npend = jnp.sum(pending.astype(jnp.int32))
 
-    def scan_round(carry):
-        i, nxt = carry
-        pos = i * k + jnp.arange(k, dtype=jnp.int32)
-        live = pos < npend
-        rows = order[jnp.minimum(pos, w - 1)]
-        st = jnp.where(live, starts[rows], 0)
-        dg = jnp.where(live, deg[rows], 0)
-        off = sel.walk_transition_chunked_window(
-            None, st, dg, indices, weights,
-            lambda u, wt, m, e: bias_of(u, wt, m, e, rows=rows),
-            chunk=CHUNK, rand=rand[rows],
-        )
-        eidx = jnp.clip(st + jnp.maximum(off, 0), 0, indices.shape[0] - 1)
-        cand = jnp.where(off >= 0, indices[eidx], -1)
-        # out-of-range index for idle slots: a clipped row may repeat
-        nxt = nxt.at[jnp.where(live, rows, w)].set(cand, mode="drop")
-        return i + 1, nxt
+        def scan_round(carry):
+            i, nxt = carry
+            pos = i * k + jnp.arange(k, dtype=jnp.int32)
+            live = pos < npend
+            rows = order[jnp.minimum(pos, w - 1)]
+            st = jnp.where(live, starts[rows], 0)
+            dg = jnp.where(live, deg[rows], 0)
+            off = sel.walk_transition_chunked_window(
+                None, st, dg, indices, weights,
+                lambda u, wt, m, e: bias_of(u, wt, m, e, rows=rows),
+                chunk=CHUNK, rand=rand[rows],
+            )
+            eidx = jnp.clip(st + jnp.maximum(off, 0), 0, indices.shape[0] - 1)
+            cand = jnp.where(off >= 0, indices[eidx], -1)
+            # out-of-range index for idle slots: a clipped row may repeat
+            nxt = nxt.at[jnp.where(live, rows, w)].set(cand, mode="drop")
+            return i + 1, nxt
 
-    _, nxt = jax.lax.while_loop(
-        lambda c: c[0] * k < npend, scan_round, (jnp.zeros((), jnp.int32), nxt)
-    )
-    return nxt
+        _, nxt = jax.lax.while_loop(
+            lambda c: c[0] * k < npend, scan_round, (jnp.zeros((), jnp.int32), nxt)
+        )
+        return nxt

@@ -26,6 +26,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.api import EdgeCtx, SamplingSpec, VertexCtx
 from repro.core import backend as bk
 from repro.core import methods as mt
@@ -226,23 +227,24 @@ def _window_bias_fn(graph: CSRGraph, program: tp.TransitionProgram,
     bs_steps = min(32, max(1, bound.bit_length()))
 
     def bias_of(u, w, mask, eidx=None, rows=None):
-        del eidx  # in-memory/OOM: degrees come from row lookups below
-        if wb.needs_deg_u:
-            uq = u if row_of is None else row_of(u)
-            deg_u = jnp.where(mask, _degree(graph, uq), 0)
-        else:  # declared unused — skip two window-wide indptr gathers
-            deg_u = jnp.zeros(u.shape, jnp.int32)
-        v_, prev_, pq_, deg_v_, depth_ = _walker_rows(rows, v, prev, pq, deg_v, depth)
-        ipn = None
-        if wb.needs_prev_neighbors:
-            ipn = _is_prev_neighbor_window(
-                graph.indptr, ids_sorted, pq_, prev_, u, mask, steps=bs_steps
+        with obs.scope("walk.window_hook"):
+            del eidx  # in-memory/OOM: degrees come from row lookups below
+            if wb.needs_deg_u:
+                uq = u if row_of is None else row_of(u)
+                deg_u = jnp.where(mask, _degree(graph, uq), 0)
+            else:  # declared unused — skip two window-wide indptr gathers
+                deg_u = jnp.zeros(u.shape, jnp.int32)
+            v_, prev_, pq_, deg_v_, depth_ = _walker_rows(rows, v, prev, pq, deg_v, depth)
+            ipn = None
+            if wb.needs_prev_neighbors:
+                ipn = _is_prev_neighbor_window(
+                    graph.indptr, ids_sorted, pq_, prev_, u, mask, steps=bs_steps
+                )
+            ctx = EdgeCtx(
+                v=v_, u=u, weight=w, deg_v=deg_v_, deg_u=deg_u, prev=prev_,
+                is_prev_neighbor=ipn, depth=depth_,
             )
-        ctx = EdgeCtx(
-            v=v_, u=u, weight=w, deg_v=deg_v_, deg_u=deg_u, prev=prev_,
-            is_prev_neighbor=ipn, depth=depth_,
-        )
-        return wb.fn(ctx)
+            return wb.fn(ctx)
 
     return bias_of
 
@@ -389,12 +391,14 @@ def random_walk(
     >>> bool(jnp.all(res.lengths == 4))  # no dead ends on a cycle
     True
     """
-    sel_methods, tables = flat_method_plan(graph, tp.lower(spec), max_degree)
-    return _random_walk_impl(
-        graph, seeds, key, tables, depth=depth, spec=spec,
-        max_degree=max_degree, method=method, backend=backend,
-        sel_methods=sel_methods,
-    )
+    with obs.span("walk.plan"):
+        sel_methods, tables = flat_method_plan(graph, tp.lower(spec), max_degree)
+    with obs.span("walk.dispatch"):
+        return _random_walk_impl(
+            graph, seeds, key, tables, depth=depth, spec=spec,
+            max_degree=max_degree, method=method, backend=backend,
+            sel_methods=sel_methods,
+        )
 
 
 @functools.partial(
@@ -421,15 +425,16 @@ def _random_walk_impl(
     be = bk.resolve_backend(backend)
     program = tp.lower(spec)
     mode = program.mode
-    if mode == "flat":
-        flat_bias = program.bias.fn(graph)
-        buckets, use_chunked = bk.walk_bucket_plan(max_degree)
-        padded = bk.pad_walk_csr(graph.indices, flat_bias, buckets)
-    elif mode == "window":
-        # the window path treats max_degree as the TRUE max row degree
-        # (exact bucket plan; chunked tail above the top segment)
-        buckets, use_chunked = bk.walk_bucket_plan_window(max_degree)
-        padded = bk.pad_walk_csr(graph.indices, graph.weights, buckets)
+    with obs.scope("walk.graph_prep"):
+        if mode == "flat":
+            flat_bias = program.bias.fn(graph)
+            buckets, use_chunked = bk.walk_bucket_plan(max_degree)
+            padded = bk.pad_walk_csr(graph.indices, flat_bias, buckets)
+        elif mode == "window":
+            # the window path treats max_degree as the TRUE max row degree
+            # (exact bucket plan; chunked tail above the top segment)
+            buckets, use_chunked = bk.walk_bucket_plan_window(max_degree)
+            padded = bk.pad_walk_csr(graph.indices, graph.weights, buckets)
     home = seeds.astype(jnp.int32) if program.carries_home else None
 
     def step(carry, it):
@@ -505,11 +510,13 @@ def random_walk_segments(
     >>> bool(jnp.array_equal(fused.walks[1], solo.walks))
     True
     """
-    sel_methods, tables = flat_method_plan(graph, tp.lower(spec), max_degree)
-    return _random_walk_segments(
-        graph, seeds, keys, tables, depth=depth, spec=spec, max_degree=max_degree,
-        method=method, backend=backend, sel_methods=sel_methods,
-    )
+    with obs.span("walk.plan"):
+        sel_methods, tables = flat_method_plan(graph, tp.lower(spec), max_degree)
+    with obs.span("walk.dispatch"):
+        return _random_walk_segments(
+            graph, seeds, keys, tables, depth=depth, spec=spec, max_degree=max_degree,
+            method=method, backend=backend, sel_methods=sel_methods,
+        )
 
 
 @functools.partial(

@@ -54,6 +54,7 @@ from typing import Callable, Literal, Optional, Union
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.api import EdgeCtx, SamplingSpec, identity_update
 
 # ---------------------------------------------------------------------------
@@ -258,30 +259,31 @@ def apply_epilogue(
     vertex array, required iff ``program.carries_home``.  RNG: exactly one
     ``key`` per step, consumed identically on every backend.
     """
-    epi = program.epilogue
-    if isinstance(epi, IdentityEpilogue):
-        return u
-    if isinstance(epi, MHAcceptEpilogue):
-        deg_u = _selected_deg_u(ctx, u)
-        stay = mh_stay(jax.random.uniform(key, u.shape), ctx.deg_v, deg_u)
-        return jnp.where(stay & (ctx.v >= 0) & (u >= 0), ctx.v, u)
-    if isinstance(epi, TeleportEpilogue):
-        kj, kv = jax.random.split(key)
-        teleport = jax.random.uniform(kj, u.shape) < epi.prob
-        if epi.target == "uniform":
-            tgt = jax.random.randint(kv, u.shape, 0, epi.num_vertices)
-        elif epi.target == "fixed":
-            tgt = jnp.full_like(u, epi.vertex)
-        else:  # "home"
-            if home is None:
-                raise ValueError(
-                    "TeleportEpilogue(target='home') needs the per-instance "
-                    "home array; this engine does not carry one"
-                )
-            tgt = jnp.broadcast_to(jnp.expand_dims(home, tuple(range(home.ndim, u.ndim))), u.shape)
-        return jnp.where(teleport & (u >= 0), tgt, u)
-    # OpaqueEpilogue — full generality through the user hook
-    return spec.update(key, ctx, u)
+    with obs.scope("walk.epilogue"):
+        epi = program.epilogue
+        if isinstance(epi, IdentityEpilogue):
+            return u
+        if isinstance(epi, MHAcceptEpilogue):
+            deg_u = _selected_deg_u(ctx, u)
+            stay = mh_stay(jax.random.uniform(key, u.shape), ctx.deg_v, deg_u)
+            return jnp.where(stay & (ctx.v >= 0) & (u >= 0), ctx.v, u)
+        if isinstance(epi, TeleportEpilogue):
+            kj, kv = jax.random.split(key)
+            teleport = jax.random.uniform(kj, u.shape) < epi.prob
+            if epi.target == "uniform":
+                tgt = jax.random.randint(kv, u.shape, 0, epi.num_vertices)
+            elif epi.target == "fixed":
+                tgt = jnp.full_like(u, epi.vertex)
+            else:  # "home"
+                if home is None:
+                    raise ValueError(
+                        "TeleportEpilogue(target='home') needs the per-instance "
+                        "home array; this engine does not carry one"
+                    )
+                tgt = jnp.broadcast_to(jnp.expand_dims(home, tuple(range(home.ndim, u.ndim))), u.shape)
+            return jnp.where(teleport & (u >= 0), tgt, u)
+        # OpaqueEpilogue — full generality through the user hook
+        return spec.update(key, ctx, u)
 
 
 def mh_stay(r: jax.Array, deg_v: jax.Array, deg_u: jax.Array) -> jax.Array:

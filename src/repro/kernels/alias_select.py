@@ -83,6 +83,6 @@ def alias_step_pallas(
     kernel = functools.partial(_alias_step_kernel, max_seg=max_seg)
     in_specs = [tile_spec()] + _edge_specs(max_seg, 3)
     return _walker_call(
-        kernel, starts.shape[0], in_specs, interpret, starts, degs,
+        kernel, "csaw_alias_step", starts.shape[0], in_specs, interpret, starts, degs,
         to_tiles(rand), p, p, a, a, ind, ind,
     )

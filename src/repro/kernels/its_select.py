@@ -140,6 +140,7 @@ def its_select_pallas(
             jax.ShapeDtypeStruct((i_pad, 2), jnp.int32),
         ],
         interpret=resolve_interpret(interpret),
+        name="csaw_its_select",
     )(biases, rands.reshape(i_pad, iters * k))
     if with_stats:
         return out[:i_dim], stats[:i_dim]

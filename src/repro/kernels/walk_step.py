@@ -172,8 +172,9 @@ def _edge_specs(max_seg: int, pairs: int) -> list:
     return [pl.BlockSpec((1, max_seg), f) for _ in range(pairs) for f in (lo_map, hi_map)]
 
 
-def _walker_call(kernel, w, in_specs, interpret, starts, degs, *args):
-    """Run a per-walker kernel over ``grid=(W,)``; returns ``(W,)`` int32."""
+def _walker_call(kernel, name, w, in_specs, interpret, starts, degs, *args):
+    """Run a per-walker kernel over ``grid=(W,)``; returns ``(W,)`` int32.
+    ``name`` is the kernel's name in compiled programs and device traces."""
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(w,),
@@ -185,6 +186,7 @@ def _walker_call(kernel, w, in_specs, interpret, starts, degs, *args):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(to_tiles(starts).shape, jnp.int32),
         interpret=resolve_interpret(interpret),
+        name=name,
     )(starts, degs, *args)
     return out.reshape(-1)[:w]
 
@@ -219,7 +221,7 @@ def walk_step_pallas(
     kernel = functools.partial(_walk_step_kernel, max_seg=max_seg)
     in_specs = [tile_spec()] + _edge_specs(max_seg, 2)
     return _walker_call(
-        kernel, starts.shape[0], in_specs, interpret, starts, degs,
+        kernel, "csaw_walk_step", starts.shape[0], in_specs, interpret, starts, degs,
         to_tiles(rand), ind, ind, wts, wts,
     )
 
@@ -255,7 +257,8 @@ def walk_step_window_pallas(
         pl.BlockSpec((8, 2 * max_seg), lambda i, *_: (i // 8, 0)),
     ] + _edge_specs(max_seg, 1)
     return _walker_call(
-        kernel, w, in_specs, interpret, starts, degs, to_tiles(rand), bias, ind, ind,
+        kernel, "csaw_walk_step_window", w, in_specs, interpret, starts, degs,
+        to_tiles(rand), bias, ind, ind,
     )
 
 
@@ -290,6 +293,6 @@ def reject_step_pallas(
     kernel = functools.partial(_reject_step_kernel, max_seg=max_seg, iters=iters)
     in_specs = [tile_spec((2 * iters,)), tile_spec()] + _edge_specs(max_seg, 2)
     return _walker_call(
-        kernel, w, in_specs, interpret, starts, degs,
+        kernel, "csaw_reject_step", w, in_specs, interpret, starts, degs,
         rej_t, to_tiles(row_max), ind, ind, wts, wts,
     )

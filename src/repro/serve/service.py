@@ -42,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
+from repro import obs
 from repro.core.api import SamplingSpec
 from repro.core import backend as bk
 from repro.core import transition as tp
@@ -86,8 +87,12 @@ class RequestResult(NamedTuple):
 class RequestLatency(NamedTuple):
     """One streamed request's life-cycle timing (``serve.stream``).
 
-    ``queue_ms`` is submission → launch start (the batching-window cost),
-    ``launch_ms`` the request's cohort launch wall time, ``total_ms``
+    ``queue_ms`` is submission → launch start: the policy's own wait (until
+    the cohort was due: its batching window, its deadline slack, or the
+    moment it filled; none for a request that joins a cohort already due)
+    plus ``blocked_ms``, the time from then to the launch start, spent
+    behind the launch in flight.
+    ``launch_ms`` is the request's cohort launch wall time, ``total_ms``
     submission → result delivery.  ``deadline_met`` is ``None`` for
     requests submitted without a deadline.
     """
@@ -99,6 +104,7 @@ class RequestLatency(NamedTuple):
     total_ms: float
     reason: str  # what launched the cohort: fill / slack / window / flush / immediate
     deadline_met: Optional[bool]
+    blocked_ms: float = 0.0
 
 
 @dataclasses.dataclass
@@ -348,6 +354,8 @@ class SamplingService:
         terminate before the OOM/sharded drain bodies ever compile); the
         key is a constant, and no service stats/counters move, so the warm
         launch is invisible to serving semantics and benchmarks alike.
+        Under a running profiler it records the engine's ``csaw.walk.*``
+        spans like any launch, and no ``csaw.serve.*`` span.
         """
         cfg = self.config
         depth_b = _pow2_bucket(int(depth), cfg.min_depth_bucket)
@@ -446,15 +454,19 @@ class SamplingService:
         return jnp.asarray(seeds), keys, r_pad
 
     def _run_fused(self, cohort: Cohort, out: Dict[int, RequestResult]) -> None:
-        seeds, keys, r_pad = self._pack(cohort)
-        res = random_walk_segments(
-            self.graph, seeds, keys, depth=cohort.depth,
-            spec=cohort.requests[0].spec, max_degree=self.max_degree,
-            method=self.method, backend=self.backend,
-        )
-        walks = np.asarray(res.walks)
-        for i, req in enumerate(cohort.requests):
-            out[req.request_id] = _slice_result(req, walks[i])
+        with obs.span("serve.pack"):
+            seeds, keys, r_pad = self._pack(cohort)
+        with obs.span("serve.dispatch"):
+            res = random_walk_segments(
+                self.graph, seeds, keys, depth=cohort.depth,
+                spec=cohort.requests[0].spec, max_degree=self.max_degree,
+                method=self.method, backend=self.backend,
+            )
+        with obs.span("serve.fetch"):
+            walks = np.asarray(res.walks)
+        with obs.span("serve.slice"):
+            for i, req in enumerate(cohort.requests):
+                out[req.request_id] = _slice_result(req, walks[i])
         self.stats.launches += 1
         self.stats.padded_walker_slots += r_pad * cohort.width - cohort.num_walkers
 
@@ -462,14 +474,19 @@ class SamplingService:
         """One launch per request, same padded geometry as the fused path —
         the bit-identical baseline the benchmark compares against."""
         for req in cohort.requests:
-            row = np.full((cohort.width,), -1, np.int32)
-            row[: req.num_walkers] = req.seeds
-            res = random_walk(
-                self.graph, jnp.asarray(row), req.key, depth=cohort.depth,
-                spec=req.spec, max_degree=self.max_degree,
-                method=self.method, backend=self.backend,
-            )
-            out[req.request_id] = _slice_result(req, np.asarray(res.walks))
+            with obs.span("serve.pack"):
+                row = np.full((cohort.width,), -1, np.int32)
+                row[: req.num_walkers] = req.seeds
+            with obs.span("serve.dispatch"):
+                res = random_walk(
+                    self.graph, jnp.asarray(row), req.key, depth=cohort.depth,
+                    spec=req.spec, max_degree=self.max_degree,
+                    method=self.method, backend=self.backend,
+                )
+            with obs.span("serve.fetch"):
+                walks = np.asarray(res.walks)
+            with obs.span("serve.slice"):
+                out[req.request_id] = _slice_result(req, walks)
             self.stats.launches += 1
             self.stats.padded_walker_slots += cohort.width - req.num_walkers
 
@@ -506,14 +523,18 @@ class SamplingService:
         requests merge into one flat instance axis (per-instance
         ``depth_limits`` let mixed walk lengths share the partition
         schedule)."""
-        seeds, limits, spans, key, ghost = self._pack_flat(cohort)
-        walks, _stats = oom_random_walk(
-            self.partitions, self.num_vertices, seeds, key,
-            depth=cohort.depth, spec=cohort.requests[0].spec,
-            max_degree=self.max_degree, backend=self.backend,
-            depth_limits=limits, **self._oom_kwargs,
-        )
-        self._unpack_flat(spans, walks, out)
+        with obs.span("serve.pack"):
+            seeds, limits, spans, key, ghost = self._pack_flat(cohort)
+        # the drain returns host arrays: its dispatch includes the fetch
+        with obs.span("serve.dispatch"):
+            walks, _stats = oom_random_walk(
+                self.partitions, self.num_vertices, seeds, key,
+                depth=cohort.depth, spec=cohort.requests[0].spec,
+                max_degree=self.max_degree, backend=self.backend,
+                depth_limits=limits, **self._oom_kwargs,
+            )
+        with obs.span("serve.slice"):
+            self._unpack_flat(spans, walks, out)
         self.stats.oom_launches += 1
         self.stats.padded_walker_slots += ghost
 
@@ -521,13 +542,18 @@ class SamplingService:
         """Route one cohort through the owner-routed mesh drain
         (``repro.shard``, DESIGN.md §12): same flat-instance-axis packing
         and launch-key contract as the OOM path."""
-        seeds, limits, spans, key, ghost = self._pack_flat(cohort)
-        res = sharded_random_walk(
-            self.mesh, self.graph, seeds, key,
-            depth=cohort.depth, spec=cohort.requests[0].spec,
-            max_degree=self.max_degree, axis=self.shard_axis,
-            backend=self.backend, depth_limits=limits,
-        )
-        self._unpack_flat(spans, np.asarray(res.walks), out)
+        with obs.span("serve.pack"):
+            seeds, limits, spans, key, ghost = self._pack_flat(cohort)
+        with obs.span("serve.dispatch"):
+            res = sharded_random_walk(
+                self.mesh, self.graph, seeds, key,
+                depth=cohort.depth, spec=cohort.requests[0].spec,
+                max_degree=self.max_degree, axis=self.shard_axis,
+                backend=self.backend, depth_limits=limits,
+            )
+        with obs.span("serve.fetch"):
+            walks = np.asarray(res.walks)
+        with obs.span("serve.slice"):
+            self._unpack_flat(spans, walks, out)
         self.stats.sharded_launches += 1
         self.stats.padded_walker_slots += ghost

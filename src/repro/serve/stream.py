@@ -62,6 +62,7 @@ from typing import Callable, Dict, List, Mapping, Optional
 import jax
 import numpy as np
 
+from repro import obs
 from repro.core.api import SamplingSpec
 from repro.serve.queue import (
     AdmissionError,
@@ -494,15 +495,32 @@ class StreamingSamplingService:
         return False, "", launch_at, sort_key
 
     def _pick(self, now: float, due_only: bool = True):
-        """Best launchable cohort under EDF (+priority, +FIFO), or None."""
+        """Best launchable cohort under EDF (+priority, +FIFO), or None:
+        ``(sort_key, group key, reason, due_at)``."""
         best = None
         for gk, members in self._forming.items():
-            due, reason, _launch_at, sort_key = self._evaluate(gk, members, now)
+            due, reason, launch_at, sort_key = self._evaluate(gk, members, now)
             if due_only and not due:
                 continue
             if best is None or sort_key < best[0]:
-                best = (sort_key, gk, reason if due else "flush")
-        return best
+                best = (sort_key, gk, reason if due else "flush", members, launch_at)
+        if best is None:
+            return None
+        sort_key, gk, reason, members, launch_at = best
+        return sort_key, gk, reason, self._due_at(members, reason, launch_at, now)
+
+    def _due_at(self, members: List[_Pending], reason: str, launch_at: float,
+                now: float) -> float:
+        """When a picked cohort became due: its launch point, the arrival
+        that filled it if that came first, or ``now`` for a flush."""
+        if reason == "flush":
+            return now
+        if reason == "immediate":
+            return members[0].submitted_at
+        if reason == "fill":
+            cap = self._svc.config.max_requests_per_launch
+            return min(launch_at, members[cap - 1].submitted_at)
+        return launch_at
 
     def _next_launch_at(self, now: float) -> Optional[float]:
         ats = [
@@ -527,11 +545,12 @@ class StreamingSamplingService:
 
     # -- execution ---------------------------------------------------------
 
-    def _execute(self, cohort: Cohort, members: List[_Pending], reason: str) -> None:
+    def _execute(self, cohort: Cohort, members: List[_Pending], reason: str,
+                 due_at: float) -> None:
         """One cohort launch + per-request delivery and accounting."""
         out: Dict[int, RequestResult] = {}
         error: Optional[Exception] = None
-        with self._launch_lock:
+        with self._launch_lock, obs.span("serve.launch"):
             t0 = self._clock()
             try:
                 self._svc._run_cohort(cohort, out)
@@ -569,6 +588,7 @@ class StreamingSamplingService:
                     launch_ms=launch_ms,
                     total_ms=(t1 - p.submitted_at) * 1e3,
                     reason=reason, deadline_met=met,
+                    blocked_ms=(t0 - max(due_at, p.submitted_at)) * 1e3,
                 )
                 stats.stream_latencies.append(lat)
                 exc = None
@@ -583,17 +603,18 @@ class StreamingSamplingService:
                     )
                     exc.__cause__ = error
                 deliveries.append((p.future, result, exc, lat))
-        for fut, result, exc, lat in deliveries:
-            fut._finish(result, exc, lat)
+        with obs.span("serve.deliver"):
+            for fut, result, exc, lat in deliveries:
+                fut._finish(result, exc, lat)
 
     def _launch_next(self, due_only: bool = True) -> bool:
         with self._lock:
             pick = self._pick(self._clock(), due_only=due_only)
             if pick is None:
                 return False
-            _, gk, reason = pick
+            _, gk, reason, due_at = pick
             cohort, members, reason = self._pop(gk, reason)
-        self._execute(cohort, members, reason)
+        self._execute(cohort, members, reason, due_at)
         return True
 
     def poll(self) -> int:
@@ -621,17 +642,18 @@ class StreamingSamplingService:
                     if pick is not None:
                         break
                     nxt = self._next_launch_at(now)
-                    if nxt is None:
-                        self._wake.wait()
-                    else:
-                        # cap the sleep: launch-cost EMAs can move the due
-                        # time earlier while we sleep
-                        self._wake.wait(min(max(nxt - now, 1e-4), 0.05))
+                    with obs.span("serve.idle"):
+                        if nxt is None:
+                            self._wake.wait()
+                        else:
+                            # cap the sleep: launch-cost EMAs can move the
+                            # due time earlier while we sleep
+                            self._wake.wait(min(max(nxt - now, 1e-4), 0.05))
                 if self._closed:
                     return  # close() flushes the backlog synchronously
-                _, gk, reason = pick
+                _, gk, reason, due_at = pick
                 cohort, members, reason = self._pop(gk, reason)
-            self._execute(cohort, members, reason)
+            self._execute(cohort, members, reason, due_at)
 
 
 def percentile(samples, q: float) -> float:

@@ -6,6 +6,8 @@ raise on the chip (tiling, VMEM, unsupported primitives).  ``interpret``
 is pinned to ``False`` so the CPU-side interpret fallback cannot hide a
 kernel that would not lower.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -54,9 +56,8 @@ I32, F32 = jnp.int32, jnp.float32
 WALKERS = [((W,), I32), ((W,), I32)]
 
 
-@pytest.mark.parametrize("seg", [128, 512])
-@pytest.mark.parametrize("kernel", ["walk", "window", "reject", "alias"])
-def test_walk_kernels_compile(one_chip, kernel, seg):
+def _walk_kernel(kernel: str, seg: int) -> tuple:
+    """(function, argument shapes) of one per-walker kernel at ``seg``."""
     if kernel == "walk":
         fn = lambda s, d, i, w, r: walk_step_pallas(s, d, i, w, r, max_seg=seg, interpret=False)
         shapes = WALKERS + [((E,), I32), ((E,), F32), ((W,), F32)]
@@ -72,6 +73,13 @@ def test_walk_kernels_compile(one_chip, kernel, seg):
         fn = lambda s, d, i, p, a, r: alias_step_pallas(
             s, d, i, p, a, r, max_seg=seg, interpret=False)
         shapes = WALKERS + [((E,), I32), ((E,), F32), ((E,), I32), ((W,), F32)]
+    return fn, shapes
+
+
+@pytest.mark.parametrize("seg", [128, 512])
+@pytest.mark.parametrize("kernel", ["walk", "window", "reject", "alias"])
+def test_walk_kernels_compile(one_chip, kernel, seg):
+    fn, shapes = _walk_kernel(kernel, seg)
     assert "tpu_custom_call" in _compile(fn, *shapes, one_chip=one_chip)
 
 
@@ -90,3 +98,20 @@ def test_its_select_compiles(one_chip, pool, k):
     fn = lambda b, r: its_select_pallas(b, r, interpret=False, with_stats=True)
     shapes = [((W, pool), F32), ((W, 32, k), F32)]
     assert "tpu_custom_call" in _compile(fn, *shapes, one_chip=one_chip)
+
+
+@pytest.mark.parametrize("kernel,name", [
+    ("walk", "csaw_walk_step"), ("window", "csaw_walk_step_window"),
+    ("reject", "csaw_reject_step"), ("alias", "csaw_alias_step"), ("its", "csaw_its_select"),
+])
+def test_kernels_carry_stable_names(one_chip, kernel, name):
+    """Each kernel's custom call is named by the kernel, not by the Python
+    function around it, so a device trace finds it after a refactor."""
+    if kernel == "its":
+        fn = lambda b, r: its_select_pallas(b, r, interpret=False)
+        shapes = [((W, 128), F32), ((W, 32, 1), F32)]
+    else:
+        fn, shapes = _walk_kernel(kernel, 128)
+    calls = [line for line in _compile(fn, *shapes, one_chip=one_chip).splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls and all(re.match(rf"\s*(ROOT )?%{name}(\.\d+)? = ", c) for c in calls), calls

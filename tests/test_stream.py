@@ -394,6 +394,58 @@ class TestLatencyAccounting:
         assert stream.stats.stream_launches == 1
         assert stream.stats.stream_latencies == [lat]
 
+    def test_blocked_behind_the_launch_in_flight(self, graph, monkeypatch):
+        """A cohort that falls due while another launch holds the device
+        counts the wait from its due time to its launch start as
+        ``blocked_ms``; ``queue_ms - blocked_ms`` is its batching window."""
+        stream, clk = make_stream(graph, StreamConfig(max_batch_window_ms=20))
+        svc = stream._svc
+        real = svc._run_cohort
+
+        restart = alg.random_walk_with_restart(0.5)
+        late = []
+
+        def slow(cohort, out):
+            clk.t += 0.050
+            if not late:  # arrives mid-launch, joining b's cohort, already due
+                late.append(stream.submit([3], depth=4, spec=restart))
+            clk.t += 0.050  # every launch takes 100 ms
+            return real(cohort, out)
+
+        monkeypatch.setattr(svc, "_run_cohort", slow)
+        fa = stream.submit([0, 1], depth=4, spec=alg.deepwalk())  # due at 20 ms
+        clk.t = 0.010
+        fb = stream.submit([2], depth=4, spec=restart)  # due at 30 ms
+        clk.t = 0.020
+        assert stream.poll() == 2  # a launches at 20 ms and holds to 120 ms, then b
+        a, b, c = fa.latency, fb.latency, late[0].latency
+        assert (a.reason, b.reason, c.reason) == ("window", "window", "window")
+        assert a.queue_ms == pytest.approx(20.0) and a.blocked_ms == pytest.approx(0.0)
+        assert b.queue_ms == pytest.approx(110.0)
+        assert b.blocked_ms == pytest.approx(90.0)
+        assert b.queue_ms - b.blocked_ms == pytest.approx(20.0)
+        # submitted at 70 ms into a cohort due since 30 ms: all its wait is blocked
+        assert c.queue_ms == pytest.approx(50.0) and c.blocked_ms == pytest.approx(50.0)
+
+    def test_blocked_counts_from_the_fill(self, graph):
+        """A cohort is due the moment it fills: what it waits after that is
+        blocked time, what its first member waited before it is the
+        policy's."""
+        stream, clk = make_stream(
+            graph, StreamConfig(max_batch_window_ms=1000),
+            svc_config=ServiceConfig(max_requests_per_launch=2),
+        )
+        f1 = stream.submit([0], depth=4, spec=alg.deepwalk())
+        clk.t = 0.005
+        f2 = stream.submit([1], depth=4, spec=alg.deepwalk())  # fills: due now
+        clk.t = 0.030
+        assert stream.poll() == 1
+        assert f1.latency.reason == "fill"
+        assert f1.latency.blocked_ms == pytest.approx(25.0)
+        assert f1.latency.queue_ms - f1.latency.blocked_ms == pytest.approx(5.0)
+        assert f2.latency.blocked_ms == pytest.approx(25.0)
+        assert f2.latency.queue_ms - f2.latency.blocked_ms == pytest.approx(0.0)
+
     def test_deadline_miss_counted(self, graph):
         stream, clk = make_stream(graph)
         f = stream.submit([0], depth=4, spec=alg.deepwalk(), deadline_ms=10)
