@@ -21,6 +21,7 @@ routes through this module, which owns the plumbing the kernels need:
 """
 from __future__ import annotations
 
+import contextlib
 import logging
 from typing import Literal, Mapping
 
@@ -53,16 +54,17 @@ WALK_BUCKETS = (128, 512)
 #: chunk width of the two-pass huge-degree scan
 CHUNK = 512
 
-#: walkers per round of a window-bias cohort (one (8, 128) walker tile of the
-#: kernels)
+#: walkers per round of a window-bias cohort when every walker draws by ITS
+#: (one (8, 128) walker tile of the kernels)
 WINDOW_TILE = 1024
 
-#: rejection budget of a window-bias walker on a row wider than the top
-#: bucket (hooks that declare ``WindowBias.max_ratio``)
+#: rejection budget of every live window-bias walker, whatever its degree
+#: (hooks that declare ``WindowBias.max_ratio``)
 TAIL_REJECT_ITERS = 16
 
-#: walkers per round of the exact scan after a rejection stage: only walkers
-#: whose budget ran out are left, so rounds are narrow
+#: walkers per round of the exact draws after a rejection stage (cohort
+#: windows and the hub scan; one sublane of the kernels' walker tile): only
+#: walkers whose budget ran out are left, so rounds are narrow
 TAIL_SCAN_ROUND = 8
 
 
@@ -537,153 +539,177 @@ def walk_step_bucketed_window(
     rand: jax.Array | None = None,
     tail_rand: jax.Array | None = None,
     envelope: jax.Array | None = None,
-    tail_rej: jax.Array | None = None,
+    rej_rand: jax.Array | None = None,
 ) -> jax.Array:
     """One dynamic-bias transition for all walkers, scheduled by degree.
 
     The ``WindowBias`` analogue of :func:`walk_step_bucketed` /
     :func:`walk_step_flat_reference` — ONE function serves both backends
     because the expensive, semantics-bearing part (evaluating the dynamic
-    edge-bias hook) runs in shared jnp either way:
+    edge-bias hook) runs in shared jnp either way.
 
-    per bucket, the bucket's walkers — taken ``WINDOW_TILE`` at a time in
-    degree-cohort order, so a walker pays only for its own cohort's width —
-    gather their *compact* ``(tile, seg)`` row windows from the padded CSR
-    arrays (``padded[seg] = (ids, weights)``,
-    :func:`pad_walk_csr` over edge WEIGHTS, not a flat bias) and
-    ``bias_of(u, w, mask, eidx) -> biases`` is evaluated on it — the narrowest
-    arrays the hook (and its prev-membership search) can see.  The computed
-    bias is then re-aligned into the kernel's block-aligned ``(tile, 2·seg)``
-    window (one cheap row-local gather; per-edge bias values are unchanged)
-    and the ITS pick runs
-    :func:`~repro.kernels.walk_step.walk_step_window_pallas` under
-    ``backend="pallas"`` or the bit-identical
+    With an ``envelope`` (an upper bound of every bias the hook returns),
+    every live walker, whatever its degree, first makes the counted
+    rejection draw (:func:`window_rejection_stage`): ``TAIL_REJECT_ITERS``
+    proposals a walker, evaluated as one window.  Only the walkers left
+    without an accepted proposal go on, ``TAIL_SCAN_ROUND`` at a time.
+    Without an envelope every live walker goes on, ``WINDOW_TILE`` at a
+    time.  Those walkers take the exact draw of their cohort:
+
+    per bucket, the bucket's walkers — taken in rounds, in degree-cohort
+    order, so a walker pays only for its own cohort's width and a cohort
+    with no walker runs no round — gather their *compact* ``(round, seg)``
+    row windows from the padded CSR arrays (``padded[seg] = (ids,
+    weights)``, :func:`pad_walk_csr` over edge WEIGHTS, not a flat bias) and
+    ``bias_of(u, w, mask, eidx) -> biases`` is evaluated on it — the
+    narrowest arrays the hook (and its prev-membership search) can see.  The
+    computed bias is then re-aligned into the kernel's block-aligned
+    ``(round, 2·seg)`` window (one cheap row-local gather; per-edge bias
+    values are unchanged) and the ITS pick, on the walker's own uniform
+    ``rand``, runs :func:`~repro.kernels.walk_step.walk_step_window_pallas`
+    under ``backend="pallas"`` or the bit-identical
     :func:`~repro.kernels.ref.walk_step_window_block_ref` mirror under
-    ``"reference"`` — same bias rows, same uniforms, same picks.
+    ``"reference"`` — same bias rows, same uniforms, same picks.  Degrees
+    above the last bucket take the exact two-pass chunked scan
+    (:func:`_window_tail`), compacted into rounds so no other walker pays for
+    a hub row — no ``(W, max_degree)`` tensor exists on any path.
 
-    Degrees above the last bucket take the huge-degree tail
-    (:func:`_window_tail`): a counted rejection draw when ``envelope`` (an
-    upper bound of every bias the hook returns) is given, then the exact
-    two-pass chunked scan for the walkers still without a pick, compacted
-    into rounds so no other walker pays for a hub row — no ``(W,
-    max_degree)`` tensor exists on any path.  ``bias_of(u, w, mask, eidx,
+    Accepted proposals follow the bias exactly, and so does every exact
+    draw, so the step is exact either way.  ``bias_of(u, w, mask, eidx,
     rows=None)`` evaluates the hook for the walkers ``rows`` (all when
-    None).  Returns next vertices (W,) int32; -1 for finished walkers and
-    dead ends.
+    None).  ``rand`` / ``tail_rand`` / ``rej_rand`` override the cohort,
+    tail and rejection streams (defaults ``fold_in(key, 0)``,
+    ``fold_in(key, 1)``, ``rejection_randoms(fold_in(key, 2))``).  Returns
+    next vertices (W,) int32; -1 for finished walkers and dead ends.
     """
     with obs.scope("walk.select"):
         safe = jnp.maximum(cur, 0)
         starts = indptr[safe]
         deg = jnp.where(cur >= 0, indptr[safe + 1] - starts, 0)
+        if not use_chunked:
+            # an understated max_degree (possible in-memory, where the caller's
+            # bound is trusted for the exact bucket plan) degrades to
+            # NEIGHBORHOOD TRUNCATION — the dense-gather path's contract — never
+            # silent walker death: without a chunked tail the top cohort absorbs
+            # any larger degree, capped at its window, on every draw
+            deg = jnp.minimum(deg, buckets[-1])
         if rand is None:
             rand = jax.random.uniform(jax.random.fold_in(key, 0), cur.shape, dtype=jnp.float32)
         r = rand
 
-        # each walker's cohort: its bucket, or len(buckets) for the tail and for
-        # finished walkers; an understated max_degree (possible in-memory, where
-        # the caller's bound is trusted for the exact bucket plan) degrades to
-        # NEIGHBORHOOD TRUNCATION — the dense-gather path's contract — never
-        # silent walker death: without a chunked tail the top cohort absorbs any
-        # larger degree, capped at its window
+        w = cur.shape[-1]
+        nxt = jnp.full_like(cur, -1)
+        pending = deg > 0
+        k = min(WINDOW_TILE, w)
+        rounds = contextlib.nullcontext()
+        if envelope is not None:
+            nxt, pending = window_rejection_stage(
+                key, indices, weights, starts, deg, bias_of, envelope, rej_rand
+            )
+            k = min(TAIL_SCAN_ROUND, w)
+            rounds = obs.scope("walk.window_fallback")
+
+        # each pending walker's cohort: its bucket, or len(buckets) for the
+        # tail; everyone else (picked, finished, dead end) is in no cohort
         cohort = jnp.full(cur.shape, len(buckets), jnp.int32)
         lo = 0
         for i, seg in enumerate(buckets):
-            absorb = i == len(buckets) - 1 and not use_chunked
-            cohort = jnp.where((deg > lo) & ((deg <= seg) | absorb), i, cohort)
+            cohort = jnp.where(pending & (deg > lo) & (deg <= seg), i, cohort)
             lo = seg
-        # cohorts are evaluated compacted, WINDOW_TILE walkers per round, so a
-        # walker pays only for its own cohort's window width
-        w = cur.shape[-1]
-        k = min(WINDOW_TILE, w)
-        order = jnp.argsort(cohort, stable=True)
-        nxt = jnp.full_like(cur, -1)
-        begin = jnp.zeros((), jnp.int32)
-        for i, seg in enumerate(buckets):
-            inds_p, wts_p = padded[seg]
-            n = jnp.sum((cohort == i).astype(jnp.int32))
+        with rounds:
+            order = jnp.argsort(cohort, stable=True)
+            begin = jnp.zeros((), jnp.int32)
+            for i, seg in enumerate(buckets):
+                inds_p, wts_p = padded[seg]
+                n = jnp.sum((cohort == i).astype(jnp.int32))
 
-            def tile(carry, seg=seg, inds_p=inds_p, wts_p=wts_p, begin=begin, n=n):
-                j, nxt = carry
-                pos = j * k + jnp.arange(k, dtype=jnp.int32)
-                live = pos < n
-                rows = order[jnp.minimum(begin + pos, w - 1)]
-                st = jnp.where(live, starts[rows], 0)
-                dg = jnp.where(live, jnp.minimum(deg[rows], seg), 0)
-                # compact row-aligned windows for the hook (row fits: dg <= seg,
-                # and the padded arrays keep a spare trailing block, so st+seg is
-                # safe)
-                offs_c = jnp.arange(seg, dtype=jnp.int32)
-                cmask = offs_c < dg[..., None]
-                ceidx = st[..., None] + offs_c
-                u_c = jnp.where(cmask, inds_p[ceidx], -1)
-                w_c = jnp.where(cmask, wts_p[ceidx], 0.0)
-                # the hook also receives the window's edge positions (``ceidx``)
-                # so per-edge side lanes (the sharded drain's replicated degree
-                # lane) can be gathered without row lookups; in-memory hooks
-                # ignore it
-                bias_c = jnp.where(
-                    cmask, jnp.maximum(bias_of(u_c, w_c, cmask, ceidx, rows=rows), 0.0), 0.0
+                def tile(carry, seg=seg, inds_p=inds_p, wts_p=wts_p, begin=begin, n=n):
+                    j, nxt = carry
+                    pos = j * k + jnp.arange(k, dtype=jnp.int32)
+                    live = pos < n
+                    rows = order[jnp.minimum(begin + pos, w - 1)]
+                    st = jnp.where(live, starts[rows], 0)
+                    dg = jnp.where(live, deg[rows], 0)
+                    # compact row-aligned windows for the hook (row fits: dg <=
+                    # seg, and the padded arrays keep a spare trailing block, so
+                    # st+seg is safe)
+                    offs_c = jnp.arange(seg, dtype=jnp.int32)
+                    cmask = offs_c < dg[..., None]
+                    ceidx = st[..., None] + offs_c
+                    u_c = jnp.where(cmask, inds_p[ceidx], -1)
+                    w_c = jnp.where(cmask, wts_p[ceidx], 0.0)
+                    # the hook also receives the window's edge positions
+                    # (``ceidx``) so per-edge side lanes (the sharded drain's
+                    # replicated degree lane) can be gathered without row
+                    # lookups; in-memory hooks ignore it
+                    bias_c = jnp.where(
+                        cmask, jnp.maximum(bias_of(u_c, w_c, cmask, ceidx, rows=rows), 0.0), 0.0
+                    )
+                    # re-align to the kernel's 2-block window at offset start %
+                    # seg (same geometry the reference pick uses — shared helper
+                    # keeps the bit-parity contract in one place)
+                    local, _, offs, mask = ref._block_window(st, dg, seg, 2 * seg)
+                    src = jnp.clip(offs - local[..., None], 0, seg - 1)
+                    bias_win = jnp.where(mask, jnp.take_along_axis(bias_c, src, axis=-1), 0.0)
+                    if backend == "pallas":
+                        cand = walk_step_window_pallas(
+                            st, dg, inds_p, bias_win, r[rows], max_seg=seg, interpret=interpret
+                        )
+                    else:
+                        cand = ref.walk_step_window_block_ref(
+                            st, dg, inds_p, bias_win, r[rows], seg=seg
+                        )
+                    # out-of-range index for idle slots: a clipped row may repeat
+                    return j + 1, nxt.at[jnp.where(live, rows, w)].set(cand, mode="drop")
+
+                _, nxt = jax.lax.while_loop(
+                    lambda c, n=n: c[0] * k < n, tile, (jnp.zeros((), jnp.int32), nxt)
                 )
-                # re-align to the kernel's 2-block window at offset start % seg
-                # (same geometry the reference pick uses — shared helper keeps
-                # the bit-parity contract in one place)
-                local, _, offs, mask = ref._block_window(st, dg, seg, 2 * seg)
-                src = jnp.clip(offs - local[..., None], 0, seg - 1)
-                bias_win = jnp.where(mask, jnp.take_along_axis(bias_c, src, axis=-1), 0.0)
-                if backend == "pallas":
-                    cand = walk_step_window_pallas(
-                        st, dg, inds_p, bias_win, r[rows], max_seg=seg, interpret=interpret
-                    )
-                else:
-                    cand = ref.walk_step_window_block_ref(
-                        st, dg, inds_p, bias_win, r[rows], seg=seg
-                    )
-                # out-of-range index for idle slots: a clipped row may repeat
-                return j + 1, nxt.at[jnp.where(live, rows, w)].set(cand, mode="drop")
-
-            _, nxt = jax.lax.while_loop(
-                lambda c, n=n: c[0] * k < n, tile, (jnp.zeros((), jnp.int32), nxt)
-            )
-            begin = begin + n
+                begin = begin + n
 
         if use_chunked:
             nxt = _window_tail(
-                key, indices, weights, starts, deg, deg > buckets[-1], bias_of, nxt,
-                envelope=envelope, rand=tail_rand, rej=tail_rej,
+                key, indices, weights, starts, deg, pending & (deg > buckets[-1]), bias_of, nxt,
+                narrow=envelope is not None, rand=tail_rand,
             )
         return nxt
 
 
-def _window_tail(key, indices, weights, starts, deg, huge, bias_of, nxt, *,
-                 envelope=None, rand=None, rej=None):
-    """Next vertices of the walkers in ``huge`` (rows above the top bucket).
+def window_rejection_stage(key, indices, weights, starts, deg, bias_of, envelope, rej_rand=None):
+    """The counted rejection draw of every live walker (``deg > 0``) of a
+    window-bias step (:func:`~repro.core.select.window_rejection_draw`).
 
-    With an ``envelope``, every such walker first makes the counted
-    rejection draw (:func:`~repro.core.select.window_rejection_draw`,
-    budget ``rejection_randoms(fold_in(key, 2))`` unless ``rej`` overrides
-    it).  The walkers left without a pick — all of ``huge`` when there is no
-    envelope — take the exact chunked scan (uniform ``fold_in(key, 1)``
-    unless ``rand`` overrides it), widest rows first, ``TAIL_SCAN_ROUND``
-    walkers per round after a rejection stage and all of them in one round
-    otherwise.  Each round loops only to the widest row among its walkers.
+    ``envelope`` bounds every bias the hook returns; the budget is
+    ``rejection_randoms(fold_in(key, 2))`` with ``TAIL_REJECT_ITERS``
+    proposals a walker unless ``rej_rand`` overrides it.  Returns ``(nxt,
+    pending)``: the accepted picks (-1 elsewhere), and the live walkers
+    without one, which must draw exactly another way.
+    """
+    with obs.scope("walk.window_reject"):
+        if rej_rand is None:
+            rej_rand = sel.rejection_randoms(
+                jax.random.fold_in(key, 2), deg.shape, iters=TAIL_REJECT_ITERS
+            )
+        cand, acc = sel.window_rejection_draw(
+            starts, deg, indices, weights, bias_of, envelope, rej_rand
+        )
+        return jnp.where(acc, cand, -1), (deg > 0) & ~acc
+
+
+def _window_tail(key, indices, weights, starts, deg, pending, bias_of, nxt, *,
+                 narrow=False, rand=None):
+    """Next vertices of the walkers in ``pending`` (rows above the top
+    bucket) by the exact chunked scan (uniform ``fold_in(key, 1)`` unless
+    ``rand`` overrides it), widest rows first: ``TAIL_SCAN_ROUND`` walkers
+    per round when ``narrow`` (after a rejection stage, which leaves few) and
+    all of them in one round otherwise.  Each round loops only to the widest
+    row among its walkers.
     """
     with obs.scope("walk.hub_tail"):
-        pending = huge
-        if envelope is not None:
-            if rej is None:
-                rej = sel.rejection_randoms(
-                    jax.random.fold_in(key, 2), huge.shape, iters=TAIL_REJECT_ITERS
-                )
-            cand, acc = sel.window_rejection_draw(
-                jnp.where(huge, starts, 0), jnp.where(huge, deg, 0), indices, weights,
-                bias_of, envelope, rej,
-            )
-            nxt = jnp.where(huge & acc, cand, nxt)
-            pending = huge & ~acc
         if rand is None:
-            rand = jax.random.uniform(jax.random.fold_in(key, 1), huge.shape, dtype=jnp.float32)
-        w = huge.shape[-1]
-        k = min(TAIL_SCAN_ROUND, w) if envelope is not None else w
+            rand = jax.random.uniform(jax.random.fold_in(key, 1), pending.shape, dtype=jnp.float32)
+        w = pending.shape[-1]
+        k = min(TAIL_SCAN_ROUND, w) if narrow else w
         order = jnp.argsort(jnp.where(pending, -deg, 1))  # pending first, widest first
         npend = jnp.sum(pending.astype(jnp.int32))
 

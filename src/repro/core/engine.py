@@ -251,7 +251,7 @@ def _window_bias_fn(graph: CSRGraph, program: tp.TransitionProgram,
 
 def window_envelope(bias: tp.WindowBias, weights) -> jax.Array:
     """Upper bound of every bias a ``max_ratio`` hook returns on ``weights``
-    (the huge-degree tail's rejection envelope)."""
+    (the rejection envelope of every walker of a window-bias step)."""
     return jnp.float32(bias.max_ratio) * jnp.maximum(jnp.max(weights), 0.0)
 
 
@@ -265,15 +265,17 @@ def walk_window_transition(key: jax.Array, graph: CSRGraph, indices_out: jax.Arr
     transition-program path that puts node2vec-class specs on the
     degree-bucketed scheduler (shared by the in-memory engine and the §V
     out-of-memory drain loop).  ``padded`` maps bucket segments to padded
-    (ids, WEIGHTS) arrays; the dynamic hook is evaluated per bucket on the
-    kernel's gathered windows, chunk-wise on the huge-degree tail."""
+    (ids, WEIGHTS) arrays.  A hook that declares ``max_ratio`` draws by
+    counted rejection first; the dynamic hook is evaluated per bucket on the
+    kernel's gathered windows, chunk-wise on the huge-degree tail, for the
+    walkers still without a pick."""
     vq = v if row_of is None else row_of(v)
     kf = jax.random.fold_in(key, 1)
     bias_of = _window_bias_fn(
         graph, program, v, prev, depth, row_of, indices_out, max_degree
     )
     envelope = None
-    if use_chunked and program.bias.max_ratio is not None:
+    if program.bias.max_ratio is not None:
         envelope = window_envelope(program.bias, graph.weights)
     u = bk.walk_step_bucketed_window(
         kf, graph.indptr, indices_out, graph.weights, padded, vq, bias_of,

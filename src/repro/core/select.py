@@ -501,11 +501,14 @@ def rejection_randoms(key: jax.Array, batch_shape: tuple, iters: int = REJECT_IT
     """
     if iters < 1:
         raise ValueError(f"rejection budget needs at least one round, got iters={iters}")
-    rs = [
-        jax.random.uniform(jax.random.fold_in(key, t), tuple(batch_shape), dtype=jnp.float32)
-        for t in range(2 * iters)
-    ]
-    return jnp.stack(rs, axis=-1).reshape(tuple(batch_shape) + (iters, 2))
+    # one batched draw over the rounds, not 2·iters separate ones: the same
+    # bits, in a program a fraction of the size
+    rs = jax.vmap(
+        lambda t: jax.random.uniform(
+            jax.random.fold_in(key, t), tuple(batch_shape), dtype=jnp.float32
+        )
+    )(jnp.arange(2 * iters))
+    return jnp.moveaxis(rs, 0, -1).reshape(tuple(batch_shape) + (iters, 2))
 
 
 def alias_draw_flat(

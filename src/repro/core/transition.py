@@ -95,10 +95,11 @@ class WindowBias:
     reads as zeros).
 
     ``max_ratio`` declares ``fn(ctx) <= max_ratio * ctx.weight`` on every
-    edge.  With it, a walker on a row wider than the top bucket draws by
+    edge.  With it, every walker, whatever its row's degree, draws by
     rejection against ``max_ratio * max(weight)`` — a few hook evaluations
-    instead of a scan of the whole row — and only walkers whose counted
-    budget runs out take the exact two-pass scan, so the draw stays exact.
+    instead of the whole row window — and only walkers whose counted budget
+    runs out take the exact draw of their cohort (the row window, or the
+    two-pass scan of a hub row), so the draw stays exact.
     """
 
     fn: Callable[[EdgeCtx], jax.Array]

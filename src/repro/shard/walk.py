@@ -358,15 +358,15 @@ def _drain_block(
                     local, program, v, prev, d, curq, prow, deg_tgt0,
                     iglob, rowid(prev), _is_hub(prev, hubs, num_hubs), search_steps,
                 )
-                # the engine's tail envelope, from the FULL graph's weights
-                envelope = tail_rej = None
-                if use_chunked and program.bias.max_ratio is not None:
+                # the engine's rejection envelope, from the FULL graph's weights
+                envelope = rej = None
+                if program.bias.max_ratio is not None:
                     envelope = window_envelope(program.bias, wmax)
-                    tail_rej = rej_budget(bk.TAIL_REJECT_ITERS)
+                    rej = rej_budget(bk.TAIL_REJECT_ITERS)
                 u = bk.walk_step_bucketed_window(
                     key, indptr, iglob, wts, padded, curq, bias_of,
                     buckets=buckets, use_chunked=use_chunked, backend=be,
-                    rand=r0, tail_rand=tail, envelope=envelope, tail_rej=tail_rej,
+                    rand=r0, tail_rand=tail, envelope=envelope, rej_rand=rej,
                 )
 
             # -- epilogue (engine's fused post-select step, instance-keyed) --
@@ -804,7 +804,7 @@ def sharded_random_walk(
     walks = jax.device_put(jnp.asarray(walks0), rep)
     seeds_d = jax.device_put(jnp.asarray(seeds_np), rep)
     limits_d = jax.device_put(jnp.asarray(limits_np), rep)
-    # the window tail's rejection envelope reads the full graph's max weight
+    # the window path's rejection envelope reads the full graph's max weight
     wmax_d = jax.device_put(
         jnp.asarray(weights_np.max() if weights_np.size else 0.0, jnp.float32), rep
     )

@@ -175,15 +175,23 @@ class TestEngineBackends:
         exact = np.asarray(g.weights) * np.asarray(factor)[np.asarray(g.indices) % 3]
         return g, bias_of, cur, start, deg, envelope, exact / exact.sum()
 
+    @staticmethod
+    def _window_step(g, cur, bias_of, backend="reference", **kw):
+        """``walk_step_bucketed_window`` on ``g`` with its exact window plan."""
+        buckets, use_chunked = bk.walk_bucket_plan_window(g.max_degree())
+        padded = bk.pad_walk_csr(g.indices, g.weights, buckets)
+        return bk.walk_step_bucketed_window(
+            KEY, g.indptr, g.indices, g.weights, padded, cur, bias_of,
+            buckets=buckets, use_chunked=use_chunked, backend=backend, **kw,
+        )
+
     def test_window_tail_rejection_matches_bias(self):
-        """The hub tail's rejection draw picks each neighbour in proportion
-        to its dynamic bias (grouped by the bias's 21 distinct values)."""
+        """Walkers on a hub row draw by rejection, then the exact scan: each
+        neighbour is picked in proportion to its dynamic bias (grouped by
+        the bias's 21 distinct values)."""
         n = 40000
         g, bias_of, cur, start, deg, envelope, prob = self._hub_tail_setup(n)
-        nxt = bk._window_tail(
-            KEY, g.indices, g.weights, start, deg, cur >= 0, bias_of,
-            jnp.full_like(cur, -1), envelope=envelope,
-        )
+        nxt = self._window_step(g, cur, bias_of, envelope=envelope)
         picks = np.asarray(nxt)
         assert (picks >= 1).all()
         nb = np.asarray(g.indices)
@@ -194,24 +202,121 @@ class TestEngineBackends:
         assert np.all(np.abs(seen - expect) < 5 * sigma + 1e-9), (seen, expect)
 
     def test_window_tail_exhausted_budget_takes_exact_scan(self):
-        """Walkers whose rejection budget runs out fall back to the chunked
-        scan, compacted into rounds: the picks equal the scan's own picks
-        for the same uniforms."""
+        """Hub walkers whose rejection budget runs out fall back to the
+        chunked scan, compacted into rounds: the picks equal the scan's own
+        picks for the same uniforms."""
         n = 37
         g, bias_of, cur, start, deg, envelope, _ = self._hub_tail_setup(n)
         rand = jax.random.uniform(KEY, (n,))
         # every trial proposes slot 0 (bias 1 * (0.1 + 1/7)) and draws an
         # accept uniform of 0.99, far above bias / envelope: all rejected
         rej = jnp.zeros((n, bk.TAIL_REJECT_ITERS, 2), jnp.float32).at[..., 1].set(0.99)
-        via_fallback = bk._window_tail(
-            KEY, g.indices, g.weights, start, deg, cur >= 0, bias_of,
-            jnp.full_like(cur, -1), envelope=envelope, rej=rej, rand=rand,
+        via_fallback = self._window_step(
+            g, cur, bias_of, envelope=envelope, rej_rand=rej, tail_rand=rand
         )
         off = sel.walk_transition_chunked_window(
             None, start, deg, g.indices, g.weights, bias_of, chunk=bk.CHUNK, rand=rand
         )
         expect = np.asarray(g.indices)[np.asarray(off)]
         np.testing.assert_array_equal(np.asarray(via_fallback), expect)
+
+    @staticmethod
+    def _cohort_setup(per_state):
+        """Three rows, one per route — degree 100 (the 128 cohort), 300 (the
+        512 cohort) and 600 (the hub tail) — over shared leaves, with
+        ``per_state`` walkers on each row in each of three walker states
+        ``s``, and a hook whose bias ``weight * (0.5, 1, 2)[(u + s) % 3]``
+        reads the walker's state (so ``max_ratio`` is 2).  Also returns the
+        walkers' rows and states, and the bias of ``(row, state, neighbour)``."""
+        degs = (100, 300, 600)
+        leaves = np.arange(len(degs), len(degs) + max(degs))
+        src = np.concatenate([np.full(d, c) for c, d in enumerate(degs)])
+        dst = np.concatenate([leaves[:d] for d in degs])
+        wts = (0.1 + (dst % 7) / 7.0).astype(np.float32)
+        g = csr_from_edges(len(degs) + max(degs), src, dst, weights=wts)
+        factor = np.asarray([0.5, 1.0, 2.0], np.float32)
+        row, state = np.meshgrid(np.arange(3), np.arange(3), indexing="ij")
+        row = np.repeat(row.ravel(), per_state)
+        state = np.repeat(state.ravel(), per_state)
+        s = jnp.asarray(state, jnp.int32)
+        fj = jnp.asarray(factor)
+
+        def bias_of(u, w, mask, eidx=None, rows=None):
+            sw = s if rows is None else s[rows]
+            return jnp.where(mask, w * fj[(jnp.maximum(u, 0) + sw[..., None]) % 3], 0.0)
+
+        def exact(c, st):
+            ip = np.asarray(g.indptr)
+            nb = np.asarray(g.indices)[ip[c]: ip[c + 1]]
+            return nb, np.asarray(g.weights)[ip[c]: ip[c + 1]] * factor[(nb + st) % 3]
+
+        envelope = 2.0 * jnp.max(g.weights)
+        return g, bias_of, jnp.asarray(row, jnp.int32), row, state, envelope, exact
+
+    @pytest.mark.parametrize("backend", ["reference", "pallas"])
+    def test_window_rejection_route_matches_bias(self, backend):
+        """Every cohort draws by rejection first, then the exact draw of its
+        route: from each (row, state), the picks follow the exact normalised
+        bias (chi-square over the bias's 21 distinct values a state)."""
+        per = 3000
+        g, bias_of, cur, row, state, envelope, exact = self._cohort_setup(per)
+        buckets, use_chunked = bk.walk_bucket_plan_window(g.max_degree())
+        assert buckets == (128, 512) and use_chunked  # one row per route
+        picks = np.asarray(self._window_step(g, cur, bias_of, backend, envelope=envelope))
+        chi2, df = 0.0, 0
+        for c in range(3):
+            for st in range(3):
+                nb, b = exact(c, st)
+                got = picks[(row == c) & (state == st)]
+                assert np.isin(got, nb).all()
+                group = ((nb + st) % 3) * 7 + nb % 7
+                expect = per * np.bincount(group, weights=b / b.sum(), minlength=21)
+                seen = np.bincount(group[np.searchsorted(nb, got)], minlength=21)
+                chi2 += float(np.sum((seen - expect) ** 2 / expect))
+                df += 20
+        # Wilson-Hilferty quantile of chi2(df) at z = 5 (about 3e-7)
+        bound = df * (1 - 2 / (9 * df) + 5 * np.sqrt(2 / (9 * df))) ** 3
+        assert chi2 < bound, (chi2, bound)
+
+    @pytest.mark.parametrize("backend", ["reference", "pallas"])
+    def test_window_rejection_exhausted_budget_is_exact_route(self, backend):
+        """A budget that accepts nothing (accept uniform 1) leaves every
+        live walker pending, and the step then returns exactly what the
+        route without a rejection stage returns, in every cohort."""
+        g, bias_of, cur, *_, envelope, _ = self._cohort_setup(5)
+        cur = cur.at[::7].set(-1)  # finished walkers stay finished
+        n = cur.shape[0]
+        rej = jnp.ones((n, bk.TAIL_REJECT_ITERS, 2), jnp.float32)
+        live = np.asarray(cur) >= 0
+        starts = g.indptr[jnp.maximum(cur, 0)]
+        deg = jnp.where(cur >= 0, g.indptr[jnp.maximum(cur, 0) + 1] - starts, 0)
+        _, pending = bk.window_rejection_stage(
+            KEY, g.indices, g.weights, starts, deg, bias_of, envelope, rej
+        )
+        np.testing.assert_array_equal(np.asarray(pending), live)
+        via_fallback = self._window_step(g, cur, bias_of, backend, envelope=envelope, rej_rand=rej)
+        exact = self._window_step(g, cur, bias_of, backend)
+        np.testing.assert_array_equal(np.asarray(via_fallback), np.asarray(exact))
+        assert (np.asarray(exact)[live] >= 0).all()
+
+    def test_window_rejection_stage_accepting_budget_leaves_none_pending(self):
+        """A budget that accepts every proposal (accept uniform 0, every
+        bias positive) leaves no walker pending, each pick is the neighbour
+        its first proposal names, and the step keeps those picks: no
+        cohort round or hub scan runs over them."""
+        g, bias_of, cur, *_, envelope, _ = self._cohort_setup(5)
+        n = cur.shape[0]
+        rej = jnp.zeros((n, bk.TAIL_REJECT_ITERS, 2), jnp.float32).at[:, 0, 0].set(0.5)
+        starts = g.indptr[cur]
+        deg = g.indptr[cur + 1] - starts
+        nxt, pending = bk.window_rejection_stage(
+            KEY, g.indices, g.weights, starts, deg, bias_of, envelope, rej
+        )
+        assert not np.asarray(pending).any()
+        expect = np.asarray(g.indices)[np.asarray(starts + deg // 2)]
+        np.testing.assert_array_equal(np.asarray(nxt), expect)
+        step = self._window_step(g, cur, bias_of, envelope=envelope, rej_rand=rej)
+        np.testing.assert_array_equal(np.asarray(step), expect)
 
     def test_walk_understated_max_degree_keeps_hub_walkers(self):
         """Regression: the bucket plan must not shrink its top segment on the

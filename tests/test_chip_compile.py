@@ -115,3 +115,26 @@ def test_kernels_carry_stable_names(one_chip, kernel, name):
     calls = [line for line in _compile(fn, *shapes, one_chip=one_chip).splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     assert calls and all(re.match(rf"\s*(ROOT )?%{name}(\.\d+)? = ", c) for c in calls), calls
+
+
+@pytest.mark.parametrize("rejection", [False, True])
+def test_window_step_compiles(one_chip, rejection):
+    """The window-bias step as the node2vec cell runs it: with a rejection
+    stage its cohort rounds are ``TAIL_SCAN_ROUND`` walkers wide, without
+    one ``WINDOW_TILE`` wide; both lower, kernel included."""
+    from repro.core import backend as bk
+
+    w, v = 2 * W, 1 << 16
+    buckets = bk.WALK_BUCKETS
+
+    def step(indptr, indices, weights, cur, key):
+        padded = bk.pad_walk_csr(indices, weights, buckets)
+        return bk.walk_step_bucketed_window(
+            key, indptr, indices, weights, padded, cur,
+            lambda u, wt, m, e=None, rows=None: 2.0 * wt,
+            buckets=buckets, use_chunked=True, backend="pallas", interpret=False,
+            envelope=2.0 * jnp.max(weights) if rejection else None,
+        )
+
+    shapes = [((v + 1,), I32), ((E,), I32), ((E,), F32), ((w,), I32), ((2,), jnp.uint32)]
+    assert "tpu_custom_call" in _compile(step, *shapes, one_chip=one_chip)

@@ -2,11 +2,13 @@
 
 - every ``csaw.walk.*`` device scope lands in the ``op_name`` metadata of
   the compiled walk wherever its path runs: graph preparation, selection,
-  the node2vec window hook, the hub tail (a row wider than the top bucket)
-  and the epilogue (restart walks);
+  the node2vec window hook, its rejection draw and the exact fallback
+  rounds after it (only for a hook that declares ``max_ratio``), the hub
+  tail (a row wider than the top bucket) and the epilogue (restart walks);
 - the streaming service records its ``csaw.serve.*`` host spans, and the
   engine its ``csaw.walk.*`` ones, in a profiler trace.
 """
+import dataclasses
 import re
 
 import jax
@@ -45,11 +47,18 @@ def _scopes_in_compiled_walk(graph, spec) -> set:
 
 
 WALK = {"csaw.walk.graph_prep", "csaw.walk.select", "csaw.walk.hub_tail"}
+WINDOW_REJECT = {"csaw.walk.window_hook", "csaw.walk.window_reject", "csaw.walk.window_fallback"}
+N2V = alg.node2vec()
+# node2vec's own hook, without the bound that lets it draw by rejection
+NO_RATIO = dataclasses.replace(
+    N2V.transition, bias=dataclasses.replace(N2V.transition.bias, max_ratio=None))
 
 
 @pytest.mark.parametrize("name,spec,expected", [
     ("deepwalk", alg.deepwalk(), WALK),
-    ("node2vec", alg.node2vec(), WALK | {"csaw.walk.window_hook"}),
+    ("node2vec", alg.node2vec(), WALK | WINDOW_REJECT),
+    ("node2vec_no_ratio", dataclasses.replace(N2V, transition=NO_RATIO),
+     WALK | {"csaw.walk.window_hook"}),
     ("restart", alg.random_walk_with_restart(0.15), WALK | {"csaw.walk.epilogue"}),
 ])
 def test_walk_scopes_reach_the_op_name_metadata(hub_graph, name, spec, expected):

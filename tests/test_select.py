@@ -200,3 +200,17 @@ class TestChunkedTransition:
         )
         counts = np.bincount(np.asarray(off), minlength=4)
         assert chi2_stat(counts, np.array([0.1, 0.2, 0.3, 0.4])) < 16.3
+
+
+@pytest.mark.parametrize("batch_shape,iters", [((37,), 16), ((3, 64), 8), ((5,), 1)])
+def test_rejection_randoms_keep_the_counted_layout(batch_shape, iters):
+    """Round ``t`` reads ``uniform(fold_in(key, 2t))`` for its slot and
+    ``uniform(fold_in(key, 2t + 1))`` for its accept test, bit for bit: the
+    layout the sharded drain rebuilds one column at a time."""
+    key = jax.random.PRNGKey(11)
+    rej = np.asarray(sel.rejection_randoms(key, batch_shape, iters=iters))
+    assert rej.shape == batch_shape + (iters, 2)
+    for t in range(iters):
+        for j in range(2):
+            col = jax.random.uniform(jax.random.fold_in(key, 2 * t + j), batch_shape)
+            np.testing.assert_array_equal(rej[..., t, j], np.asarray(col))
