@@ -25,7 +25,6 @@ import time
 import numpy as np
 
 import run as R  # bench/ is this script's own directory
-import graphgen
 import load
 import reference
 
@@ -57,14 +56,16 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     bench, cell, config, mix = R.load_spec(args.workload)
     seconds = args.seconds or float(bench["run_seconds"])
-    R.check_device(int(cell["chips"]), allow_cpu=False)
+    placement = R.placement_of(config, mix)
+    R.import_program()
+    devices = R.check_device(int(cell["chips"]), allow_cpu=False)
     R.enable_compile_cache(R.ROOT)
     law = reference.Law.of(mix["program"], broken=mix["control"])
     for seed in args.seed:
         t = time.perf_counter()
-        g, order, info = R.build_graph(config, R.run_key(seed))
-        host = reference.HostGraph(*graphgen.host_csr(g))
-        del g
+        placed, order, _ = placement.build(config, R.run_key(seed), devices)
+        host = placement.host_graph(placed, None)
+        del placed
         walks, starts = control_walks(mix, order, host, law, seed, seconds)
         numbers = reference.compare(host, law, walks, starts,
                                     np.random.default_rng([seed, 11]))

@@ -38,31 +38,73 @@ HUB_TRUNCATION = 512
 
 
 class HostGraph:
-    """CSR on the host with an int64 key per entry (src * n + dst), sorted."""
+    """CSR rows on the host, each entry keyed ``src * n + dst`` (int64), keys
+    sorted.
 
-    def __init__(self, indptr: np.ndarray, indices: np.ndarray, weights: np.ndarray):
-        self.indptr = np.asarray(indptr, np.int64)
+    ``HostGraph(indptr, indices, weights)`` holds every row.  With ``held``
+    (vertices, sorted and distinct) it holds only theirs: ``indices`` and
+    ``weights`` are those rows, one after another, while ``indptr`` is still
+    the whole graph's offsets and ``max_weight`` its largest weight.  For
+    walks whose vertices are all held (``row_vertices``), ``find``,
+    ``compare`` and the laws' draws then give the same numbers as on the
+    whole graph.  Positions (``find``'s, ``row_start``) index the held
+    entries.
+    """
+
+    def __init__(self, indptr: np.ndarray, indices: np.ndarray, weights: np.ndarray, *,
+                 held: np.ndarray | None = None, max_weight: float | None = None):
+        indptr = np.asarray(indptr, np.int64)
         self.indices = np.asarray(indices, np.int32)
         self.weights = np.asarray(weights, np.float32)
-        self.n = self.indptr.shape[0] - 1
-        self.deg = np.diff(self.indptr)
-        self.keys = np.repeat(np.arange(self.n, dtype=np.int64) * self.n, self.deg)
+        self.n = indptr.shape[0] - 1
+        self.deg = np.diff(indptr)
+        #: the held vertices, sorted (None: every vertex)
+        self.held = held
+        if held is None:
+            rows, self.start = np.arange(self.n, dtype=np.int64), indptr
+        else:
+            rows = self.held = np.asarray(held, np.int64)
+            if rows.size and not (np.all(rows[1:] > rows[:-1])
+                                  and rows[0] >= 0 and rows[-1] < self.n):
+                raise ValueError("held vertices must be sorted, distinct and in range")
+            self.start = np.concatenate([[0], np.cumsum(self.deg[rows])])
+        if self.start[-1] != self.indices.size or self.weights.size != self.indices.size:
+            raise ValueError("the held rows' degrees do not add up to their entries")
+        self.keys = np.repeat(rows * self.n, self.deg[rows])
         self.keys += self.indices
         if self.keys.size and not (
             np.all(self.keys[1:] > self.keys[:-1])
             and self.indices.min() >= 0 and self.indices.max() < self.n
         ):
             raise ValueError("not a CSR with sorted, distinct, in-range rows")
-        self.max_weight = float(self.weights.max()) if self.weights.size else 1.0
+        if max_weight is None:
+            max_weight = float(self.weights.max()) if self.weights.size else 1.0
+        self.max_weight = float(max_weight)
+
+    def row_start(self, v: np.ndarray) -> np.ndarray:
+        """Where each row ``v`` starts among the held entries."""
+        if self.held is None:
+            return self.start[v]
+        slot = np.minimum(np.searchsorted(self.held, v), max(self.held.size - 1, 0))
+        if not np.all(self.held[slot] == v):
+            raise KeyError("a row the comparison reads is not held")
+        return self.start[slot]
 
     def find(self, v: np.ndarray, x: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-        """(x ∈ N(v), CSR position of the entry where it is)."""
+        """(x ∈ N(v), position of the entry where it is)."""
         v = np.asarray(v, np.int64)
         x = np.asarray(x, np.int64)
         q = v * self.n + x
         pos = np.minimum(np.searchsorted(self.keys, q), max(self.keys.size - 1, 0))
         hit = (v >= 0) & (x >= 0) & (self.keys[pos] == q)
         return hit, pos
+
+
+def row_vertices(walks: np.ndarray, n: int) -> np.ndarray:
+    """The vertices whose rows ``compare`` reads for ``walks``: every vertex
+    in them, sorted, once."""
+    w = np.asarray(walks, np.int64).ravel()
+    return np.unique(w[(w >= 0) & (w < n)])
 
 
 @dataclasses.dataclass(frozen=True)
@@ -91,7 +133,7 @@ class Law:
         """One next vertex per walker from state (t, v, home); v must be live."""
         v = np.asarray(v, np.int64)
         deg = g.deg[v]
-        start = g.indptr[v]
+        start = g.row_start(v)
         if self.name == "restart":
             width = deg
             nxt = g.indices[start + rng.integers(0, width)].astype(np.int64)
@@ -139,7 +181,7 @@ def _features(g: HostGraph, t, v, home, x) -> np.ndarray:
     """(len(FEATURES), hops) features of next vertex x from state (t, v)."""
     on_edge, pos = g.find(v, x)
     deg_v = np.maximum(g.deg[v], 1)
-    position = np.where(on_edge, (pos - g.indptr[v] + 0.5) / deg_v, 0.5)
+    position = np.where(on_edge, (pos - g.row_start(v) + 0.5) / deg_v, 0.5)
     weight = np.where(on_edge, g.weights[pos], 0.0)
     back = (x == t).astype(np.float64)
     near = g.find(t, x)[0].astype(np.float64)

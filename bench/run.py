@@ -14,19 +14,36 @@ output one JSON object: ``correct``, ``attempted``, ``failed``, ``metrics``
 The compared numbers are also the last lines on standard error.  With no TPU,
 or fewer chips than the cell asks for, it exits 3 and prints no result.
 
-Everything that belongs to one configuration, traffic mix or per-layer metric
-is a file of its own, found by the name ``BENCHMARK.json`` gives it; a later
+Everything that belongs to one configuration, placement, graph kind, traffic
+mix or per-layer metric is a file of its own, found by the name that
+``BENCHMARK.json`` or the configuration gives it (``parts.py``); a later
 change adds one by adding a file and an entry, and edits no file here.
 
-``bench/configs/<config>.json`` — a deployment:
-    ``graph``: ``{"kind": "rmat", "scale", "edge_factor", "a", "b", "c",
-    "structure_seed"}`` (the only kind so far; ``graphgen.py``);
-    ``weights_dtype`` (``"float32"``); ``placement`` (``"memory"``);
-    ``backend`` (``"pallas"``); for open mixes ``stream``, the keyword
-    arguments of the program's ``StreamConfig``, and ``service_limit_ms``,
-    the p99 limit ``sweep.py`` finds the knee against; ``guarantees``;
-    ``source``; ``assumed``; ``reduced``.  Closed mixes drive
-    ``repro.core.random_walk``; open ones ``StreamingSamplingService``.
+``bench/configs/<config>.json`` — a deployment, named by a ``configs`` entry:
+    ``graph`` (``kind`` names a graph kind, the other keys are that kind's);
+    ``weights_dtype``; ``placement`` (names a placement); ``backend``
+    (``"pallas"``); for open mixes ``stream``, the keyword arguments of the
+    program's ``StreamConfig``, and ``service_limit_ms``, the p99 limit
+    ``sweep.py`` finds the knee against; ``guarantees``; ``source``;
+    ``assumed``; ``reduced``.
+``bench/placements/<placement>.py`` — where the graph lives and how the
+    program is driven on it (``memory``: one CSR in one chip's HBM).  It
+    defines ``build(config, key, devices)``, which returns ``(placed, order,
+    info)``: the graph on ``devices`` as the program takes it, the
+    non-isolated vertices in a seeded order (numpy) and the ``graph:`` line's
+    summary, ``bytes_per_device`` among it; ``launch(placed, starts, key, *,
+    depth, spec, backend)``, one launch of a closed mix, which returns
+    ``(walks, sampled_edges)`` for the window to wait on; ``service(placed,
+    key, *, spec, depth, width, backend)``, the program's ``SamplingService``
+    with the mix's shapes compiled, for open mixes (a placement without it
+    takes closed mixes only); and ``host_graph(placed, walks)``, a
+    ``reference.HostGraph`` that holds at least the rows ``compare`` reads
+    for ``walks`` (``reference.row_vertices``), or every row where ``walks``
+    is None (``control.py``).
+``bench/graphs/<kind>.py`` — a graph kind, called by the placements that
+    build it: ``rmat`` defines ``build(spec, key)``, which returns the graph
+    as a ``graphgen.DeviceCSR`` on the default device and the seconds each
+    step took.
 ``bench/traffic/<mix>.json`` — a mix for the one generator (``load.py``):
     ``kind`` (``"closed"`` or ``"open"``), ``program`` (``{"name":
     "deepwalk"}``, ``{"name": "node2vec", "p", "q"}`` or ``{"name":
@@ -43,7 +60,7 @@ change adds one by adding a file and an entry, and edits no file here.
     (this chip's row of ``peaks.json``).
 
 A new program name needs its constructor in ``PROGRAMS`` below and its law in
-``reference.Law``; a new graph kind needs a generator in ``graphgen.py``.
+``reference.Law``.
 """
 from __future__ import annotations
 
@@ -52,7 +69,6 @@ import time
 T0 = time.perf_counter()
 
 import argparse  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
@@ -66,8 +82,8 @@ BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 sys.path.insert(0, str(BENCH))
 
-import graphgen  # noqa: E402
 import load  # noqa: E402
+import parts  # noqa: E402
 import reference  # noqa: E402
 import tracefile  # noqa: E402
 
@@ -167,39 +183,34 @@ def make_spec(program: dict):
     return PROGRAMS[program["name"]](alg, program)
 
 
-def build_graph(config: dict, key):
-    """The configuration's graph on the device and its summary."""
+def placement_of(config: dict, mix: dict):
+    """The configuration's placement (a module), once its file and its graph
+    kind's are found: a missing one, or an open mix on a placement with no
+    service, fails here, before any device work."""
+    placement = parts.load("placements", config["placement"])
+    parts.load("graphs", config["graph"]["kind"])
+    if mix["kind"] == "open" and not hasattr(placement, "service"):
+        raise ValueError(f"placement {config['placement']!r} "
+                         f"({parts.path('placements', config['placement'])}) defines no "
+                         "service, so it cannot serve an open mix")
+    return placement
+
+
+def context(placement, config: dict, mix: dict, seed: int, devices) -> SimpleNamespace:
+    """The graph placed on ``devices`` from ``seed``, and what the windows and
+    the comparison read besides."""
     import jax
 
-    spec = config["graph"]
-    if spec["kind"] != "rmat":
-        raise ValueError(f"unknown graph kind {spec['kind']!r}")
-    if config["placement"] != "memory" or config["weights_dtype"] != "float32":
-        raise ValueError("only the in-HBM placement with float32 weights is built so far")
+    key = run_key(seed)
     t = time.perf_counter()
-    draws = graphgen.rmat_draws(
-        jax.random.PRNGKey(int(spec["structure_seed"])), jax.random.fold_in(key, 1),
-        scale=int(spec["scale"]), edge_factor=int(spec["edge_factor"]),
-        a=float(spec["a"]), b=float(spec["b"]), c=float(spec["c"]),
+    placed, order, info = placement.build(config, key, devices)
+    info["build_s"] = time.perf_counter() - t
+    info["start_s"] = t - T0
+    return SimpleNamespace(
+        seed=seed, key=jax.random.fold_in(key, 3), config=config, mix=mix,
+        placement=placement, placed=placed, order=order, info=info,
+        spec=make_spec(mix["program"]), law=reference.Law.of(mix["program"]),
     )
-    jax.block_until_ready(draws)
-    t_draws = time.perf_counter()
-    g = graphgen.csr_from_draws(*draws, 1 << int(spec["scale"]))
-    del draws
-    jax.block_until_ready(g)
-    t_csr = time.perf_counter()
-    max_deg, live = (int(x) for x in graphgen.degree_summary(g.indptr))
-    order = graphgen.live_permutation(jax.random.fold_in(key, 2), g.indptr, count=live)
-    order = np.asarray(order)
-    info = {
-        "draws_s": t_draws - t, "csr_s": t_csr - t_draws,
-        "order_s": time.perf_counter() - t_csr,
-        "vertices": int(g.indptr.shape[0] - 1), "entries": int(g.indices.shape[0]),
-        "max_degree": max_deg, "non_isolated": live,
-        "hubs_over_512": int(np.sum(np.diff(np.asarray(g.indptr)) > 512)),
-        "bytes": int(g.indptr.nbytes + g.indices.nbytes + g.weights.nbytes),
-    }
-    return g, order, info
 
 
 # ---------------------------------------------------------------------------
@@ -253,22 +264,19 @@ def closed_window(ctx, seconds: float, tracer: Tracer) -> dict:
     """Launch after launch, each blocked on, until ``seconds`` have passed."""
     import jax
 
-    from repro.core import random_walk
-
-    mix, graph = ctx.mix, ctx.graph
+    mix = ctx.mix
     depth, walkers = int(mix["depth"]), int(mix["walkers"])
     base = ctx.key
 
     def launch(i: int, starts: np.ndarray):
         with span("launch"):
-            res = random_walk(
-                graph, jax.device_put(starts.astype(np.int32)),
-                jax.random.fold_in(base, 1000 + i), depth=depth, spec=ctx.spec,
-                max_degree=ctx.info["max_degree"], backend=ctx.config["backend"],
+            walks, n = ctx.placement.launch(
+                ctx.placed, starts, jax.random.fold_in(base, 1000 + i), depth=depth,
+                spec=ctx.spec, backend=ctx.config["backend"],
             )
         with span("block"):
-            res.walks.block_until_ready()
-            return res.walks, int(res.sampled_edges)
+            walks.block_until_ready()
+            return walks, int(n)
 
     # warm-up: the window's own shapes, with a key the window never uses
     t = time.perf_counter()
@@ -305,22 +313,13 @@ def closed_window(ctx, seconds: float, tracer: Tracer) -> dict:
 
 def open_window(ctx, seconds: float, tracer: Tracer) -> dict:
     """Queries on a fixed schedule; each timed from its due time to its answer."""
-    from repro.serve import AdmissionError, SamplingService
+    from repro.serve import AdmissionError
     from repro.serve.stream import StreamConfig, StreamingSamplingService
 
     mix = ctx.mix
     depth, walkers = int(mix["depth"]), int(mix["walkers"])
-    svc = SamplingService(
-        ctx.graph, max_degree=ctx.info["max_degree"], backend=ctx.config["backend"],
-        key=ctx.key,
-    )
-    cap = svc.config.max_requests_per_launch
-    r = 1
-    while r <= cap:
-        t = time.perf_counter()
-        svc.prewarm(ctx.spec, depth=depth, width=walkers, requests=r)
-        print(f"prewarm requests={r}: {time.perf_counter() - t:.3f} s", file=sys.stderr)
-        r *= 2
+    svc = ctx.placement.service(ctx.placed, ctx.key, spec=ctx.spec, depth=depth,
+                                width=walkers, backend=ctx.config["backend"])
     stream = StreamingSamplingService(svc, StreamConfig(**ctx.config.get("stream", {})))
     rng = np.random.default_rng([ctx.seed, 7])
     due, vertices = load.open_arrivals(mix, seconds, ctx.order, rng)
@@ -388,8 +387,9 @@ def open_window(ctx, seconds: float, tracer: Tracer) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def closed_compare(ctx, out: dict, host: reference.HostGraph) -> dict:
-    """A seeded sample of the window's walks against the reference."""
+def closed_sample(ctx, out: dict):
+    """A seeded sample of the window's walks: ``(walks, starts, rng, rows
+    missing)``."""
     rng = np.random.default_rng([ctx.seed, 11])
     depth = int(ctx.mix["depth"])
     budget = int(ctx.mix["compare"]["hops"])
@@ -404,12 +404,14 @@ def closed_compare(ctx, out: dict, host: reference.HostGraph) -> dict:
             got = np.full((rows.size, depth + 1), -1)
         walks.append(got)
         starts.append(launch_starts[rows])
-    return reference.compare(host, ctx.law, np.concatenate(walks), np.concatenate(starts), rng)
+    return np.concatenate(walks), np.concatenate(starts), rng, 0
 
 
-def open_compare(ctx, out: dict, host: reference.HostGraph) -> dict:
-    """A seeded sample of the answered queries, each whole, against the
-    reference; an unanswered query counts its rows as bad."""
+def open_sample(ctx, out: dict):
+    """A seeded sample of the answered queries, each whole: ``(walks, starts,
+    rng, rows missing)``, where every row of a query that was admitted and
+    never answered, or answered in the wrong shape, is missing (walks None:
+    none to compare)."""
     rng = np.random.default_rng([ctx.seed, 13])
     depth, walkers = int(ctx.mix["depth"]), int(ctx.mix["walkers"])
     n = int(ctx.mix["compare"]["queries"])
@@ -425,9 +427,22 @@ def open_compare(ctx, out: dict, host: reference.HostGraph) -> dict:
         walks.append(res.walks)
         starts.append(np.full((walkers,), out["vertices"][i]))
     if not walks:
-        return {"bad_rows": bad + walkers, "bad_hops": 0, "max_abs_z": 0.0, "hops": 0, "z": {}}
-    numbers = reference.compare(host, ctx.law, np.concatenate(walks), np.concatenate(starts), rng)
-    numbers["bad_rows"] += bad + walkers * (out["queries"] - out["refused"] - out["answered"])
+        return None, None, rng, bad + walkers
+    unanswered = out["queries"] - out["refused"] - out["answered"]
+    return np.concatenate(walks), np.concatenate(starts), rng, bad + walkers * unanswered
+
+
+def judge(ctx, out: dict) -> dict:
+    """The sample against the reference, on the rows the placement reads back;
+    the program's graph is let go before the reference runs."""
+    walks, starts, rng, missing = (
+        closed_sample if ctx.mix["kind"] == "closed" else open_sample)(ctx, out)
+    if walks is None:
+        return {"bad_rows": missing, "bad_hops": 0, "max_abs_z": 0.0, "hops": 0, "z": {}}
+    host = ctx.placement.host_graph(ctx.placed, walks)
+    ctx.placed = None
+    numbers = reference.compare(host, ctx.law, walks, starts, rng)
+    numbers["bad_rows"] += missing
     return numbers
 
 
@@ -467,11 +482,7 @@ def per_layer(cell: dict, bench: dict, run: SimpleNamespace) -> dict:
     for m in bench["per_layer"]:
         if "workloads" in m and cell["name"] not in m["workloads"]:
             continue
-        path = BENCH / "metrics" / f"{m['name']}.py"
-        spec = importlib.util.spec_from_file_location(f"bench_metric_{len(values)}", path)
-        module = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(module)
-        values[m["name"]] = module.read(run)
+        values[m["name"]] = parts.load("metrics", m["name"]).read(run)
     return _pick(bench["per_layer"], cell["name"], values)
 
 
@@ -511,25 +522,13 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     if overrides:
         config = {**config, **overrides.get("config", {})}
         mix = {**mix, **overrides.get("mix", {})}
+    placement = placement_of(config, mix)
     import_program()
     devices = check_device(int(cell["chips"]), allow_cpu)
     peaks = load_peaks(devices[0].device_kind, allow_missing=allow_cpu)
     enable_compile_cache(root)
-    import jax
-
-    key = run_key(seed)
-    t = time.perf_counter()
-    g, order, info = build_graph(config, key)
-    info["build_s"] = time.perf_counter() - t
-    info["start_s"] = t - T0
-    from repro.graph.csr import CSRGraph
-
-    ctx = SimpleNamespace(
-        seed=seed, key=jax.random.fold_in(key, 3), config=config, mix=mix,
-        graph=CSRGraph(g.indptr, g.indices, g.weights), order=order, info=info,
-        spec=make_spec(mix["program"]), law=reference.Law.of(mix["program"]),
-    )
-    log(f"graph: {json.dumps(info)}", file=sys.stderr)
+    ctx = context(placement, config, mix, seed, devices)
+    log(f"graph: {json.dumps(ctx.info)}", file=sys.stderr)
     tracer = Tracer(trace)
     try:
         if mix["kind"] == "closed":
@@ -560,9 +559,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
 
     # the reference runs once the window has closed and the peak is read
     t_ref = time.perf_counter()
-    host = reference.HostGraph(*graphgen.host_csr(g))
-    del g, ctx.graph
-    numbers = (closed_compare if mix["kind"] == "closed" else open_compare)(ctx, out, host)
+    numbers = judge(ctx, out)
     ok, rows = reference.verdict(numbers, mix["correct"])
     log(f"reference: {time.perf_counter() - t_ref:.3f} s, {numbers['hops']} hops, "
         f"z {json.dumps(numbers['z'])}", file=sys.stderr)

@@ -32,21 +32,13 @@ def main(argv=None) -> int:
     bench, cell, config, mix = R.load_spec(args.workload)
     if mix["kind"] != "open":
         raise SystemExit(f"{args.workload} is not an open cell")
+    placement = R.placement_of(config, mix)
     R.import_program()
-    R.check_device(int(cell["chips"]), allow_cpu=False)
+    devices = R.check_device(int(cell["chips"]), allow_cpu=False)
     R.enable_compile_cache(R.ROOT)
-    import jax
-
-    from repro.graph.csr import CSRGraph
-
-    key = R.run_key(args.seed)
-    g, order, info = R.build_graph(config, key)
+    ctx = R.context(placement, config, mix, args.seed, devices)
     for rate in args.rates:
-        ctx = R.SimpleNamespace(
-            seed=args.seed, key=jax.random.fold_in(key, 3), config=config,
-            mix={**mix, "rate": rate}, graph=CSRGraph(g.indptr, g.indices, g.weights),
-            order=order, info=info, spec=R.make_spec(mix["program"]), law=None,
-        )
+        ctx.mix = {**mix, "rate": rate}
         out = R.open_window(ctx, args.seconds, R.Tracer(False))
         lat = out["latency_ms"]
         q = max(1, lat.size // 4)

@@ -4,14 +4,17 @@
 
 They cover the device CSR builder against the program's host builder, the
 traffic generator's determinism, the trace reduction on a synthetic trace,
-the refusal of a run without a chip, the controls (each reference law with a
-broken guarantee fails the comparison) and whole runs with the timed path
-broken underneath (each fault makes ``correct`` false).
+the refusal of a run without a chip, parts found by name (a placement and a
+graph kind added as files, an unknown one refused), the controls (each
+reference law with a broken guarantee fails the comparison), the reference's
+row view against the whole graph, and whole runs with the timed path broken
+underneath (each fault makes ``correct`` false).
 """
 from __future__ import annotations
 
 import json
 import os
+import re
 import shutil
 import subprocess
 import sys
@@ -30,8 +33,11 @@ import jax.numpy as jnp  # noqa: E402
 
 import graphgen  # noqa: E402
 import load  # noqa: E402
+import parts  # noqa: E402
 import reference  # noqa: E402
 import tracefile  # noqa: E402
+
+rmat = parts.load("graphs", "rmat")
 
 RMAT = dict(edge_factor=8, a=0.57, b=0.19, c=0.19)
 SMALL_GRAPH = {"kind": "rmat", "scale": 9, "structure_seed": 0, **RMAT}
@@ -53,7 +59,7 @@ def _env_cpu():
 def test_device_csr_equals_csr_from_edges(scale):
     from repro.graph import csr_from_edges
 
-    src, dst, w = graphgen.rmat_draws(jax.random.PRNGKey(3), jax.random.PRNGKey(scale),
+    src, dst, w = rmat.rmat_draws(jax.random.PRNGKey(3), jax.random.PRNGKey(scale),
                                       scale=scale, **RMAT)
     g = graphgen.csr_from_draws(src, dst, w, 1 << scale)
     ref = csr_from_edges(1 << scale, np.asarray(src), np.asarray(dst), np.asarray(w),
@@ -68,8 +74,8 @@ def test_device_csr_equals_csr_from_edges(scale):
 
 def test_run_key_picks_labels_not_structure():
     """Two run keys give isomorphic graphs: same entries, same degree multiset."""
-    a = graphgen.rmat_csr(jax.random.PRNGKey(0), jax.random.PRNGKey(1), scale=9, **RMAT)
-    b = graphgen.rmat_csr(jax.random.PRNGKey(0), jax.random.PRNGKey(2), scale=9, **RMAT)
+    a = rmat.rmat_csr(jax.random.PRNGKey(0), jax.random.PRNGKey(1), scale=9, **RMAT)
+    b = rmat.rmat_csr(jax.random.PRNGKey(0), jax.random.PRNGKey(2), scale=9, **RMAT)
     assert a.indices.shape == b.indices.shape
     da, db = np.diff(np.asarray(a.indptr)), np.diff(np.asarray(b.indptr))
     np.testing.assert_array_equal(np.sort(da), np.sort(db))
@@ -192,13 +198,115 @@ def test_run_fails_without_the_program(tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# Parts found by name
+# ---------------------------------------------------------------------------
+
+ADDED_PLACEMENT = '''"""A placement for the tests: the in-HBM one, with no service."""
+import parts
+
+memory = parts.load("placements", "memory")
+
+
+def build(config, key, devices):
+    return memory.build(config, key, devices)
+
+
+def launch(placed, starts, key, **kwargs):
+    return memory.launch(placed, starts, key, **kwargs)
+
+
+def host_graph(placed, walks):
+    return memory.host_graph(placed, walks)
+'''
+
+ADDED_KIND = '''"""A graph kind for the tests: R-MAT at structure seed 7."""
+import parts
+
+rmat = parts.load("graphs", "rmat")
+
+
+def build(spec, key):
+    return rmat.build({**spec, "structure_seed": 7}, key)
+'''
+
+#: runs cells of the checkout in the working directory, one result line each
+DRIVE = '''import json, sys
+sys.path.insert(0, "bench")
+import run
+for cell, mix in json.loads(sys.argv[1]).items():
+    try:
+        res = run.run(cell, int(sys.argv[2]), 1.5, False, allow_cpu=True,
+                      overrides={"mix": mix}, log=lambda *a, **k: print(*a, file=sys.stderr))
+    except ValueError as e:
+        res = {"error": str(e)}
+    print(json.dumps(res), flush=True)
+'''
+
+
+def test_added_placement_and_graph_kind_need_no_edit(tmp_path):
+    """A checkout with a placement, a graph kind, a configuration and cells
+    added as files, and every file it had left as it was, runs them."""
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path / "BENCHMARK.json")
+    shutil.copytree(BENCH, tmp_path / "bench", ignore=shutil.ignore_patterns("__pycache__"))
+    (tmp_path / "src").symlink_to(ROOT / "src")
+    (tmp_path / "bench" / "placements" / "wrapped.py").write_text(ADDED_PLACEMENT)
+    (tmp_path / "bench" / "graphs" / "rmat7.py").write_text(ADDED_KIND)
+    config = json.loads((BENCH / "configs" / "lj-walks.json").read_text())
+    config.update(name="added", placement="wrapped", backend="reference",
+                  graph={**SMALL_GRAPH, "kind": "rmat7"})
+    (tmp_path / "bench" / "configs" / "added.json").write_text(json.dumps(config))
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": "added", "source": "a test", "reduced": [], "why": "a test",
+                             "file": "bench/configs/added.json"})
+    bench["workloads"] += [
+        {"name": f"added.{mix}", "config": "added", "traffic": mix, "chips": 1, "why": "a test"}
+        for mix in ("deepwalk-corpus", "ppr-steady")]
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    cells = {"added.deepwalk-corpus": SMALL["lj-walks.deepwalk"],
+             "added.ppr-steady": SMALL["lj-ppr.steady"]}
+    p = subprocess.run([sys.executable, "-c", DRIVE, json.dumps(cells), str(SEED)],
+                       cwd=tmp_path, env=_env_cpu(), capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-3000:]
+    closed, open_ = (json.loads(line) for line in p.stdout.strip().splitlines()[-2:])
+    assert closed["correct"], closed["compared"]
+    [graph] = [json.loads(line[len("graph: "):]) for line in p.stderr.splitlines()
+               if line.startswith("graph: ")]
+    entries = {seed: rmat.rmat_csr(jax.random.PRNGKey(seed), jax.random.PRNGKey(0),
+                                   scale=9, **RMAT).indices.shape[0] for seed in (0, 7)}
+    assert graph["entries"] == entries[7] != entries[0]
+    assert graph["bytes_per_device"] == graph["bytes"]
+    # a placement with no service is refused an open mix, by name
+    assert "defines no service" in open_["error"] and "wrapped.py" in open_["error"]
+
+
+@pytest.mark.parametrize("folder", ["placements", "graphs"])
+def test_unknown_part_fails_before_any_device_work(monkeypatch, folder):
+    import run as R
+
+    def device_work(*a, **k):
+        raise AssertionError("device work began")
+
+    monkeypatch.setattr(R, "check_device", device_work)
+    config = ({"placement": "nowhere"} if folder == "placements"
+              else {"graph": {**SMALL_GRAPH, "kind": "nowhere"}})
+    with pytest.raises(FileNotFoundError, match=re.escape(str(BENCH / folder / "nowhere.py"))):
+        R.run("lj-walks.deepwalk", SEED, 1.5, False, allow_cpu=True,
+              overrides={"config": config}, log=lambda *a, **k: None)
+
+
+def test_part_names_stay_in_their_folder():
+    with pytest.raises(ValueError):
+        parts.path("placements", "../run")
+
+
+# ---------------------------------------------------------------------------
 # The comparison: sound laws pass, controls fail
 # ---------------------------------------------------------------------------
 
 
 @pytest.fixture(scope="module")
 def host_graph():
-    g = graphgen.rmat_csr(jax.random.PRNGKey(0), jax.random.PRNGKey(5), scale=15, **RMAT)
+    g = rmat.rmat_csr(jax.random.PRNGKey(0), jax.random.PRNGKey(5), scale=15, **RMAT)
     return reference.HostGraph(*graphgen.host_csr(g))
 
 
@@ -229,6 +337,42 @@ def test_sound_law_passes_and_control_fails(host_graph, name):
     numbers = reference.compare(host_graph, control, walks, starts, np.random.default_rng(3))
     assert numbers["bad_rows"] == numbers["bad_hops"] == 0
     assert numbers["max_abs_z"] > 3 * 6.0, numbers["z"]
+
+
+def _row_view(host, walks):
+    """The rows ``compare`` reads for ``walks``, cut out of the whole graph."""
+    held = reference.row_vertices(walks, host.n)
+    lengths = host.deg[held]
+    take = np.repeat(host.start[held] - (np.cumsum(lengths) - lengths), lengths)
+    take += np.arange(take.size)
+    return reference.HostGraph(host.start, host.indices[take], host.weights[take],
+                               held=held, max_weight=host.max_weight)
+
+
+@pytest.mark.parametrize("walks_from", ["sound", "control", "altered"])
+@pytest.mark.parametrize("name", sorted(PROGRAMS))
+def test_row_view_compares_as_the_whole_graph(host_graph, name, walks_from):
+    program, broken, depth = PROGRAMS[name]
+    starts = _starts(host_graph, 300, 4)
+    law = reference.Law.of(program, broken=broken if walks_from == "control" else None)
+    walks = reference.walks_from_law(host_graph, law, starts, depth, np.random.default_rng(5))
+    if walks_from == "altered":  # off-edge hops, one a row
+        walks[:, depth // 2] = (walks[:, depth // 2] + 7919) % host_graph.n
+    held = np.unique(walks)
+    other = np.setdiff1d(np.nonzero(host_graph.deg)[0], held)[:1]
+    # the heaviest entry sits in a row the view does not hold
+    weights = host_graph.weights.copy()
+    weights[host_graph.start[other]] = 3.0
+    whole = reference.HostGraph(host_graph.start, host_graph.indices, weights)
+    rows = _row_view(whole, walks)
+    np.testing.assert_array_equal(rows.held, held)
+    assert rows.keys.size == whole.deg[held].sum() < whole.keys.size
+    want = reference.compare(whole, law, walks, starts, np.random.default_rng(6))
+    got = reference.compare(rows, law, walks, starts, np.random.default_rng(6))
+    assert got == want
+    assert want["bad_hops"] > 0 if walks_from == "altered" else want["bad_hops"] == 0
+    with pytest.raises(KeyError):
+        rows.row_start(other)
 
 
 # ---------------------------------------------------------------------------
